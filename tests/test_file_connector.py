@@ -26,6 +26,19 @@ def test_native_library_builds():
     assert os.path.exists(native.lib_path())
 
 
+def test_native_build_failure_is_an_error(tmp_path, monkeypatch):
+    # the library is built from source on first use; a source that does not
+    # compile must fail the caller, not hand back None
+    bad = tmp_path / "pagefile.cpp"
+    bad.write_text("this is not C++\n")
+    monkeypatch.setattr(native, "_SRC", str(bad))
+    monkeypatch.setattr(native, "_SO", str(tmp_path / "libpagefile.so"))
+    monkeypatch.setattr(native, "_lib", None)
+    with pytest.raises(RuntimeError, match="failed to build"):
+        native.load()
+    assert not os.path.exists(tmp_path / "libpagefile.so")
+
+
 def test_native_bitmap_roundtrip():
     import ctypes
 

@@ -1,8 +1,8 @@
 """Hash-vs-sort equivalence for the TRINO_TPU_HASH_IMPL paths.
 
 The open-addressing kernels (ops/pallas_kernels.hash_insert/hash_probe) run
-here in interpret mode on the CPU test mesh — the identical programs compile
-for real TPU lanes.  Every test drives the same inputs through both the
+here in interpret mode on the CPU test mesh — the only mode they run in: the
+v5e compiler refuses both (tests/test_tpu_compile.py).  Every test drives the same inputs through both the
 lexsort implementation and the Pallas hash implementation and asserts the
 operator-level contracts agree: same group partitions, same join probe
 ranges, bit-identical query output.
@@ -18,14 +18,9 @@ from trino_tpu.exec import kernels as K
 from trino_tpu.exec import syncguard as SG
 from trino_tpu.ops import pallas_kernels as PK
 
-pytestmark = pytest.mark.skipif(
-    not PK.pallas_available(), reason="pallas not importable")
-
-
 @pytest.fixture(autouse=True)
 def _fresh_state(monkeypatch):
-    # isolate the auto-mode failure latch and the impl knob per test
-    monkeypatch.setitem(K._HASH_IMPL_STATE, "failed", False)
+    # isolate the impl knob per test
     monkeypatch.delenv("TRINO_TPU_HASH_IMPL", raising=False)
     monkeypatch.delenv("TRINO_TPU_HASH_INTERPRET", raising=False)
 

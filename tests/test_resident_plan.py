@@ -322,7 +322,6 @@ _CHILD = textwrap.dedent("""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from trino_tpu.execution.plan_compiler import _AXIS
-    from trino_tpu.parallel.compat import shard_map
 
     assert jax.process_count() == 2, jax.process_count()
     assert jax.device_count() == 8, jax.device_count()
@@ -336,7 +335,7 @@ _CHILD = textwrap.dedent("""
     g = jax.make_array_from_single_device_arrays(
         (8 * per,), NamedSharding(mesh, P(_AXIS)), shards)
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda x: jax.lax.all_gather(x, _AXIS, tiled=True),
         mesh=mesh, in_specs=P(_AXIS), out_specs=P(), check_vma=False))
     rep = np.asarray(fn(g).addressable_shards[0].data)

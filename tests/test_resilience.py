@@ -337,6 +337,26 @@ def test_worker_boot_failure_raises_with_stderr():
     assert "TRINO_TPU_TEST_BOOT_FAIL" in msg  # the captured stderr
 
 
+def test_worker_without_a_device_is_a_classified_boot_error():
+    """One process for each chip: a worker whose JAX backend cannot be
+    opened (on a TPU host: the coordinator or an earlier worker holds the
+    chip) fails its spawn at once with NO_NODES_AVAILABLE and the reason —
+    it neither boots and fails every task nor waits out the boot timeout."""
+    from trino_tpu.spi.errors import NO_NODES_AVAILABLE, TrinoError
+
+    env = dict(_ENV)
+    env["JAX_PLATFORMS"] = "no_such_platform"
+    t0 = time.monotonic()
+    with pytest.raises(TrinoError) as ei:
+        WorkerProcess(env_overrides=env, boot_timeout_s=60.0)
+    assert time.monotonic() - t0 < 60.0
+    assert ei.value.code is NO_NODES_AVAILABLE
+    msg = str(ei.value)
+    assert "cannot open its accelerator" in msg
+    assert "one process at a time" in msg
+    assert "no_such_platform" in msg  # JAX's own words, from the stderr
+
+
 def test_worker_status_endpoint_reports_all_tasks():
     """GET /v1/status returns node state + EVERY task's classified state in
     one payload — the one-poll-per-worker sweep's data source."""

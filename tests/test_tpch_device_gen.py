@@ -1,7 +1,7 @@
 """Device-side TPC-H generation must be bit-identical to the host generator.
 
 The bench stages orders/lineitem via trino_tpu.connectors.tpch.
-generate_table_device (columns born in accelerator memory, no tunnel
+generate_table_device (columns born in accelerator memory, no host
 transfer); correctness of every oracle-diffed query depends on both
 generators producing the same values from the same splitmix64 arithmetic.
 """

@@ -1,0 +1,187 @@
+"""Ask the chip's compiler before asking the chip.
+
+The TPU compiler is installed here and compiles for a chip that is described
+and not attached (on-chip-measurement guide, section 2): these tests lower
+and compile, for ONE described v5e device and from ``ShapeDtypeStruct``s at
+real widths, the programs TPC-H Q1/Q3/Q6 launch on the served path — the
+Pallas segment-sum kernel, the sort-route grouping and join programs, and
+``static_grouped_agg`` with the TPU branch forced — and hold the two
+open-addressing hash kernels as strict xfails with the compiler's refusal.
+Nothing runs; a compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load libtpu, and under xdist every worker imports
+every test file.  Keep these tests in this one file for the same reason.
+The 64-bit sort programs take minutes to compile at the 2^20-row bucket the
+engine uses at SF10 (CHANGES.md, PR 22, has the seconds), so tier-1 holds
+them at 2^12 and a ``slow`` twin holds the real bucket.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from trino_tpu.exec import join_exec as JX
+from trino_tpu.exec import kernels as K
+from trino_tpu.ops import pallas_kernels as PK
+from trino_tpu.parallel.static_agg import AggSpec, static_grouped_agg
+
+VMEM_REFUSAL = "Cannot store scalars to VMEM"
+
+BUCKETS = [
+    pytest.param(1 << 12, id="2^12"),
+    pytest.param(1 << 20, id="2^20", marks=pytest.mark.slow),
+]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler here: skip, visibly
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """Sharding on one described device, with the persistent compile cache
+    off around the compiles: an entry written without a chip cannot be read
+    back without one, and the next compile would warn and compile again."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def shape(one_chip):
+    def of(n, dtype):
+        n = n if isinstance(n, tuple) else (n,)
+        return jax.ShapeDtypeStruct(n, dtype, sharding=one_chip)
+
+    return of
+
+
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    """Code that asks jax.default_backend() sees the CPU here; steer it to
+    the branch the chip takes — from the test, not from a program option."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("TRINO_TPU_HASH_IMPL", raising=False)
+    monkeypatch.delenv("TRINO_TPU_HASH_INTERPRET", raising=False)
+
+
+def test_segment_sum_kernel_compiles(shape):
+    # largest G grouped_reduce routes to the kernel (cap <= 64), 2^20 rows
+    n = 1 << 20
+    tile = (n // 128, 128)
+    with jax.enable_x64(False):
+        compiled = PK._build(64, n // 1024, False).lower(
+            shape(tile, jnp.float32), shape(tile, jnp.int32),
+            shape(tile, jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _insert(shape):
+    n = 32 * 1024
+    return PK._build_insert(2, 65536, 32, False).lower(
+        shape((2, n), jnp.uint32), shape((1, n), jnp.uint32),
+        shape((1, n), jnp.bool_))
+
+
+def _probe(shape):
+    n, s = 32 * 1024, 65536
+    return PK._build_probe(2, s, 32, False).lower(
+        shape((2, s), jnp.uint32), shape((1, s), jnp.int32),
+        shape((2, n), jnp.uint32), shape((1, n), jnp.uint32),
+        shape((1, n), jnp.bool_))
+
+
+@pytest.mark.xfail(strict=True, raises=ValueError, reason=VMEM_REFUSAL)
+@pytest.mark.parametrize("lower", [_insert, _probe],
+                         ids=["hash_insert", "hash_probe"])
+def test_hash_kernels_compile(shape, lower):
+    """The join index at 32k rows (P=2 hash planes, S=65536 slots).  The day
+    this XPASSes, the kernels compile: give kernels.hash_kernels_selected a
+    TPU branch and measure it (ROADMAP S5)."""
+    with jax.enable_x64(False):
+        try:
+            lower(shape).compile()
+        except ValueError as e:
+            # a different refusal is news, not the expected failure
+            assert VMEM_REFUSAL in str(e), e
+            raise
+
+
+def test_auto_resolves_to_sort_on_tpu(tpu_backend, monkeypatch):
+    assert not K.hash_kernels_selected(1 << 16)
+    monkeypatch.setenv("TRINO_TPU_HASH_IMPL", "pallas")
+    assert K.hash_kernels_selected(1 << 16)
+    assert not K.hash_interpret()  # forced pallas would compile for real
+
+
+@pytest.mark.parametrize("rows", BUCKETS)
+def test_group_ids_two_bigint_keys(shape, rows):
+    K._group_ids_fn(2, (False, False), True).lower(
+        shape(rows, jnp.int64), shape(rows, jnp.int64),
+        shape(rows, jnp.bool_)).compile()
+
+
+@pytest.mark.parametrize("rows", BUCKETS)
+def test_group_ids_nullable_double_key(shape, rows):
+    K._group_ids_fn(1, (True,), True).lower(
+        shape(rows, jnp.float64), shape(rows, jnp.bool_),
+        shape(rows, jnp.bool_)).compile()
+
+
+@pytest.mark.parametrize("rows", BUCKETS)
+def test_join_build(shape, rows):
+    # one BIGINT key with a live mask, key range wanted (the dense-table
+    # probe of Q3's orders build)
+    JX._build_fn(1, (False,), True, True).lower(
+        shape(rows, jnp.int64), shape(rows, jnp.bool_)).compile()
+
+
+@pytest.mark.parametrize("rows", BUCKETS)
+def test_join_probe_ranges(shape, rows):
+    JX._ranges_fn(1, (False,), True, (False,)).lower(
+        shape(rows, jnp.uint64), shape(rows, jnp.int64),
+        shape(rows, jnp.bool_)).compile()
+
+
+@pytest.mark.parametrize("rows", BUCKETS)
+def test_static_grouped_agg_as_the_fused_stage_calls_it(
+        shape, tpu_backend, rows):
+    """stage_compiler._agg_merge: cap 8192, every key carries a validity
+    lane, the batch's live mask rides in as row_mask."""
+    specs = [AggSpec("sum", np.dtype("float64")),
+             AggSpec("min", np.dtype("int64")),
+             AggSpec("count_star", np.dtype("int64"))]
+
+    def partial_agg(k0, k1, v0, v1, d0, d1, live):
+        r = static_grouped_agg(
+            [k0, k1], [v0, v1],
+            [(specs[0], d0, None), (specs[1], d1, v1), (specs[2], None, None)],
+            8192, row_mask=live)
+        return r.keys, r.values, r.slot_used, r.num_groups
+
+    args = (shape(rows, jnp.int64), shape(rows, jnp.int64),
+            shape(rows, jnp.bool_), shape(rows, jnp.bool_),
+            shape(rows, jnp.float64), shape(rows, jnp.int64),
+            shape(rows, jnp.bool_))
+    lowered = jax.jit(partial_agg).lower(*args)
+    # the selection the chip makes: the sort route, no pallas call inside
+    assert "tpu_custom_call" not in lowered.as_text()
+    lowered.compile()

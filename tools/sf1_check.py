@@ -1,4 +1,4 @@
-"""SF1 correctness net on the real device: oracle-diff a TPC-H subset at
+"""SF1 correctness net on the default JAX device: oracle-diff a TPC-H subset at
 scale factor 1 (6M lineitem rows) — the scale where shape-bucket cliffs,
 collective edges and masked aggregation paths actually engage (round-4
 VERDICT item #8; run: python tools/sf1_check.py [q,q,...])."""
@@ -18,18 +18,16 @@ def main() -> None:
     sf = float(os.environ.get("SF", "1"))
     import jax
 
+    from trino_tpu.caching.executable_cache import init_compile_cache
     from trino_tpu.connectors.catalog import default_catalog
     from trino_tpu.connectors.tpch_queries import QUERIES
     from trino_tpu.runner import Session, StandaloneQueryRunner
     from trino_tpu.testing.oracle import SqliteOracle, assert_same_rows
 
-    try:
-        jax.config.update("jax_compilation_cache_dir", os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            ".jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:
-        pass
+    init_compile_cache()
+    d = jax.devices()[0]
+    print(f"device: platform={d.platform} kind={d.device_kind!r} "
+          f"count={len(jax.devices())}", flush=True)
 
     catalog = default_catalog(scale_factor=sf)
     runner = StandaloneQueryRunner(catalog, session=Session())

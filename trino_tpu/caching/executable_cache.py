@@ -16,9 +16,9 @@ evictable entries in one process-wide registry:
 - **observable**: hits/misses/evictions per cache and in aggregate, via
   the lint-clean ``trino_cache_exec_*`` metrics and the
   ``system.runtime.caches`` table (caching/__init__.py cache_rows()).
-- **persistent across restarts**, two ways.  (1) Setting
-  ``TRINO_TPU_COMPILE_CACHE_DIR`` enables JAX's on-disk compilation cache
-  (:func:`init_compile_cache`), so an XLA compile performed by any past
+- **persistent across restarts**, two ways.  (1) JAX's on-disk compilation
+  cache, placed by :func:`init_compile_cache` (``JAX_COMPILATION_CACHE_DIR``
+  or ``<checkout>/.jax_cache``), so an XLA compile performed by any past
   process is a disk load, not a recompile.  (2) JSON-serializable memo
   keys are journaled to ``exec_warm.json`` next to the query journal
   (telemetry/journal.py dir) at query end; :func:`warm_at_boot` — called
@@ -223,26 +223,26 @@ def clear_all() -> None:
 # persistence: the XLA disk compile cache + the warm-key journal
 
 
-def init_compile_cache() -> Optional[str]:
-    """Point JAX's persistent compilation cache at
-    ``TRINO_TPU_COMPILE_CACHE_DIR`` (unset = leave JAX defaults alone).
-    Returns the directory when enabled.  Idempotent; called from runner
-    construction and worker boot so compiles survive process restarts."""
-    cache_dir = os.environ.get("TRINO_TPU_COMPILE_CACHE_DIR")
-    if not cache_dir:
-        return None
-    try:
-        import jax
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception:  # knob name varies across jax versions
-            pass
-    except Exception:  # noqa: BLE001 — cache trouble must not block queries
-        return None
-    return cache_dir
+
+def init_compile_cache() -> str:
+    """THE compile-cache rule, for every entry point (runners, worker boot,
+    bench.py, tools/sf1_check.py, chip_smoke.py): where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already keeps its persistent
+    cache there and no directory is set in code; otherwise the cache lives
+    at the fixed ``<checkout>/.jax_cache`` — the path is part of the cache
+    key, so never a temp name, pid or time.  Every compile is kept (a cold
+    64-bit program costs minutes on the chip's compiler).  Returns the
+    directory in effect.  Idempotent."""
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(_CHECKOUT, ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return jax.config.jax_compilation_cache_dir
 
 
 def warm_file_path() -> str:

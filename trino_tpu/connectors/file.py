@@ -6,7 +6,7 @@ page file per written fragment.  Pages are the engine's serde frames
 (execution/serde.py), so the same wire format serves the exchange, the
 spiller, and storage.  The IO hot path — frame scanning and reads — goes
 through the native C++ library (native/pagefile.cpp via ctypes,
-trino_tpu/native.py) when built, with a pure-Python fallback.
+trino_tpu/native.py), built from source on first use.
 
 Splits map 1:1 to page files, so multi-task scans parallelize over files.
 """
@@ -38,33 +38,27 @@ __all__ = ["FileConnector"]
 
 
 def _read_frames(path: str) -> list[bytes]:
-    """All serde frames of a page file; native scan+read when available."""
+    """All serde frames of a page file, through the native scan + read."""
+    import ctypes
+
     lib = native.load()
-    if lib is not None:
-        import ctypes
-
-        cap = 4096
-        while True:
-            out = (ctypes.c_int64 * (2 * cap))()
-            n = lib.ttp_scan_frames(path.encode(), out, cap)
-            if n < 0:
-                raise IOError(f"corrupt page file: {path}")
-            if n <= cap:
-                break
-            cap = n
-        frames = []
-        for i in range(n):
-            off, length = out[2 * i], out[2 * i + 1]
-            buf = (ctypes.c_uint8 * length)()
-            if lib.ttp_read_frame(path.encode(), off, length, buf) != length:
-                raise IOError(f"short read: {path}")
-            frames.append(bytes(buf))
-        return frames
-    # pure-Python fallback
-    from ..execution.serde import iter_frames
-
-    with open(path, "rb") as f:
-        return list(iter_frames(f))
+    cap = 4096
+    while True:
+        out = (ctypes.c_int64 * (2 * cap))()
+        n = lib.ttp_scan_frames(path.encode(), out, cap)
+        if n < 0:
+            raise IOError(f"corrupt page file: {path}")
+        if n <= cap:
+            break
+        cap = n
+    frames = []
+    for i in range(n):
+        off, length = out[2 * i], out[2 * i + 1]
+        buf = (ctypes.c_uint8 * length)()
+        if lib.ttp_read_frame(path.encode(), off, length, buf) != length:
+            raise IOError(f"short read: {path}")
+        frames.append(bytes(buf))
+    return frames
 
 
 class _FilePageSource(ConnectorPageSource):

@@ -26,7 +26,6 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from ..parallel.compat import shard_map
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -118,7 +117,7 @@ def _shuffle_program(n_dev: int, n_cols: int, dtypes: tuple,
 
     n_in = n_cols + sum(valid_flags) + n_keys + 1
     n_out = n_cols + sum(valid_flags) + 1
-    return mesh, jax.jit(shard_map(
+    return mesh, jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple([P(_AXIS)] * n_in),
         out_specs=tuple([P(_AXIS)] * n_out),
@@ -175,7 +174,7 @@ def _sort_by_dest_program(n_dev: int, n_cols: int, valid_flags: tuple,
 
     n_in = n_cols + sum(valid_flags) + n_keys + 1
     n_out = n_cols + sum(valid_flags) + 1
-    return mesh, jax.jit(shard_map(
+    return mesh, jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple([P(_AXIS)] * n_in),
         out_specs=tuple([P(_AXIS)] * n_out),
@@ -223,7 +222,7 @@ def _tiled_all_to_all_program(n_dev: int, n_cols: int, valid_flags: tuple,
 
     n_in = n_cols + sum(valid_flags) + 1
     n_out = n_cols + sum(valid_flags) + 1
-    return mesh, jax.jit(shard_map(
+    return mesh, jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple([P(_AXIS)] * n_in),
         out_specs=tuple([P(_AXIS)] * n_out),

@@ -127,6 +127,9 @@ class DistributedQueryRunner:
         sysconn = self.catalog._connectors.get("system")
         if sysconn is not None and hasattr(sysconn, "attach"):
             sysconn.attach(self)
+        from ..caching import executable_cache
+
+        executable_cache.init_compile_cache()
 
     # ------------------------------------------------------------------ plan
     def create_plan(self, sql: str) -> PlanNode:

@@ -66,7 +66,6 @@ from ..exec import kernels as K
 from ..exec import syncguard as SG
 from ..exec.operators import Operator
 from ..exec.stats import FusedStageStats, ResidentPlanStats
-from ..parallel.compat import shard_map
 from ..planner import plan as PL
 from ..spi.batch import ColumnBatch
 from ..spi.errors import PAGE_TRANSPORT_TIMEOUT, TrinoError
@@ -302,7 +301,7 @@ def _build_prep_program(n_dev: int, n_payload: int):
         return tuple(outs)
 
     n_in = 2 + 2 * n_payload
-    return mesh, jax.jit(shard_map(
+    return mesh, jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple([P(_AXIS)] * n_in),
         out_specs=tuple([P()] * (n_in + 1)),

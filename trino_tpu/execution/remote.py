@@ -279,10 +279,22 @@ class WorkerProcess:
             reader.join(timeout=5)
             why = ("timed out after "
                    f"{boot_timeout_s}s" if line is None else f"got {line!r}")
+            from .worker import NO_DEVICE
+
+            tail = self.stderr_tail()
+            if NO_DEVICE in tail:
+                raise TrinoError(
+                    NO_NODES_AVAILABLE,
+                    "worker failed to boot: it cannot open its accelerator. "
+                    "A chip belongs to one process at a time, and this "
+                    "process or an earlier worker already holds it (nothing "
+                    "pins one worker per chip yet). On a TPU host run the "
+                    "in-process DistributedQueryRunner; worker processes "
+                    "need env_overrides={'JAX_PLATFORMS': 'cpu'} (README, "
+                    f"'Running'). stderr: {tail!r}")
             raise TrinoError(
                 REMOTE_HOST_GONE,
-                f"worker failed to boot ({why}); stderr: "
-                f"{self.stderr_tail()!r}")
+                f"worker failed to boot ({why}); stderr: {tail!r}")
         self.port = int(line.split()[1])
         self.url = f"http://127.0.0.1:{self.port}"
 

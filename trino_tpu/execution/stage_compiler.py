@@ -54,7 +54,6 @@ from ..exec import syncguard as SG
 from ..exec.operators import Operator
 from ..exec.stats import FusedStageStats
 from ..ops.expr import compile_expression
-from ..parallel.compat import shard_map
 from ..parallel.static_agg import AggSpec, combine_partials, static_grouped_agg
 from ..planner import plan as PL
 from ..spi.batch import Column, ColumnBatch
@@ -563,7 +562,7 @@ def _merge_program(n_dev: int, cap: int, key_dtypes: tuple, dict_flags: tuple,
     n_in = 2 * nk + n_states + sum(1 for s in state_sig if s[2]) + 1 + 2 * n_dict
     n_out = 2 * nk + len(final_sig) \
         + sum(1 for f in final_sig if f[0] not in ("count",)) + 1
-    return mesh, jax.jit(shard_map(
+    return mesh, jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple([P(_AXIS)] * n_in),
         out_specs=tuple([P(_AXIS)] * n_out),

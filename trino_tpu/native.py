@@ -2,9 +2,10 @@
 
 pybind11 isn't in the image, so the native library (native/pagefile.cpp —
 zlib page framing, validity bitmaps, page-file scanning) binds through
-ctypes.  ``load()`` builds the shared object on first use with the baked-in
-toolchain and caches it next to the source; every caller must handle
-``None`` (pure-Python fallback paths stay correct without the library).
+ctypes.  The shared object is a build product, never a tracked file:
+``load()`` compiles it from the source on first use with the baked-in
+toolchain (and again when the source is newer) and keeps it next to the
+source.  A failed build is an error for the caller, not a ``None``.
 """
 
 from __future__ import annotations
@@ -22,42 +23,46 @@ _SO = os.path.join(_ROOT, "native", "libpagefile.so")
 
 _lock = threading.Lock()
 _lib = None
-_tried = False
 
 
 def lib_path() -> str:
     return _SO
 
 
-def _build() -> bool:
+def _build() -> None:
+    """Compile to a private name and rename into place, so concurrent
+    first users (xdist workers, worker processes) never load a half-written
+    object."""
+    tmp = f"{_SO}.{os.getpid()}.tmp"
+    errors = []
     for cc in ("c++", "g++"):
         try:
             proc = subprocess.run(
-                [cc, "-O3", "-shared", "-fPIC", "-o", _SO, _SRC, "-lz"],
+                [cc, "-O3", "-shared", "-fPIC", "-o", tmp, _SRC, "-lz"],
                 capture_output=True, text=True, timeout=120)
-            if proc.returncode == 0:
-                return True
-        except (OSError, subprocess.TimeoutExpired):
+        except (OSError, subprocess.TimeoutExpired) as e:
+            errors.append(f"{cc}: {e}")
             continue
-    return False
+        if proc.returncode == 0:
+            os.replace(tmp, _SO)
+            return
+        errors.append(f"{cc}: rc={proc.returncode} {proc.stderr[-2000:]}")
+    raise RuntimeError(
+        f"native page-file library failed to build from {_SRC}: "
+        + "; ".join(errors))
 
 
 def load():
-    """The loaded CDLL with typed signatures, or None if unavailable."""
-    global _lib, _tried
+    """The loaded CDLL with typed signatures; raises when the library
+    cannot be built or loaded."""
+    global _lib
     with _lock:
-        if _lib is not None or _tried:
+        if _lib is not None:
             return _lib
-        _tried = True
         if not os.path.exists(_SO) or (
-                os.path.exists(_SRC)
-                and os.path.getmtime(_SRC) > os.path.getmtime(_SO)):
-            if not os.path.exists(_SRC) or not _build():
-                return None
-        try:
-            lib = ctypes.CDLL(_SO)
-        except OSError:
-            return None
+                os.path.getmtime(_SRC) > os.path.getmtime(_SO)):
+            _build()
+        lib = ctypes.CDLL(_SO)
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i64 = ctypes.c_int64
         lib.ttp_deflate.argtypes = [u8p, i64, u8p, i64, ctypes.c_int]

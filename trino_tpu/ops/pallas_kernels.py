@@ -24,27 +24,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# jax.enable_x64 was removed in jax 0.4.x; the experimental spelling is the
-# one that exists here (the engine traces these kernels in 32-bit mode
-# because Mosaic rejects the stray i64 weak types x64 mode produces)
-from jax.experimental import enable_x64 as _enable_x64
+# the engine traces these kernels in 32-bit mode because Mosaic rejects the
+# stray i64 weak types x64 mode produces (jax 0.9: ``jax.enable_x64`` is the
+# context manager; the ``jax.experimental`` spelling is gone)
+from jax import enable_x64 as _enable_x64
 
-__all__ = ["masked_segment_sum_f32", "pallas_available",
-           "hash_insert", "hash_probe"]
+__all__ = ["masked_segment_sum_f32", "hash_insert", "hash_probe"]
 
 _BLOCK = 1024  # rows per grid step (8 sublanes x 128 lanes)
 _LANES = 128
 _HBLOCK = 1024  # rows per grid step for the open-addressing kernels
-
-
-def pallas_available() -> bool:
-    try:
-        from jax.experimental import pallas as pl  # noqa: F401
-        from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-        return True
-    except Exception:
-        return False
 
 
 def _segment_sum_kernel(G: int, vals_ref, gid_ref, live_ref, out_ref):

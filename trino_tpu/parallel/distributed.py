@@ -22,7 +22,6 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from .compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .static_agg import AggSpec, combine_partials, static_grouped_agg
@@ -119,7 +118,7 @@ def distributed_grouped_agg(
         overflow = jnp.maximum(part.num_groups, fin.num_groups).reshape(1)
         return tuple(fin.keys), tuple(fin.values), fin.slot_used, overflow
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_program,
         mesh=mesh,
         in_specs=tuple([P(axis)] * (nk + len(agg_specs) + 1)),
@@ -142,7 +141,7 @@ def broadcast_gather(mesh: Mesh, axis: str):
         return jax.lax.all_gather(x, axis, axis=0, tiled=True)
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             program, mesh=mesh, in_specs=P(axis), out_specs=P(), check_vma=False
         )
     )

@@ -116,12 +116,12 @@ def static_grouped_agg(
     dropped by reduction identity values.
     """
     n = keys[0].shape[0]
-    pairs = list(zip(keys, key_valids))
-    if n and _K._use_hash_impl(n, _K._plane_count(pairs)):
+    if _K.hash_kernels_selected(n):
         # hash route: the insert kernel hands every ORIGINAL row its dense
         # group id, so perm stays identity and the segment scatters below
         # work unsorted; the count stays a device scalar (still zero syncs)
-        row_gid, num_groups = _K.hash_row_gids(pairs, live=row_mask)
+        row_gid, num_groups = _K.hash_row_gids(
+            list(zip(keys, key_valids)), live=row_mask)
         S = _K.bucket(2 * max(n, 1))
         perm = jnp.arange(n)
         live = row_mask if row_mask is not None else jnp.ones(n, jnp.bool_)

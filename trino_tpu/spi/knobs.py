@@ -98,9 +98,6 @@ _DECLARATIONS = (
          "killer; unset disables the cap."),
     Knob("TRINO_TPU_COALESCE_TARGET_ROWS", "int", "65536",
          "Scan-ingest batch coalescing target row count."),
-    Knob("TRINO_TPU_COMPILE_CACHE_DIR", "path", "",
-         "Directory for JAX's persistent on-disk compile cache; unset "
-         "leaves the on-disk cache off."),
     Knob("TRINO_TPU_DRAIN_TIMEOUT_S", "float", "300",
          "Graceful-drain budget: a SHUTTING_DOWN worker abandons "
          "unfinished tasks and exits with code 9 past this."),

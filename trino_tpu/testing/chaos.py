@@ -63,6 +63,12 @@ _ENV = {
     "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
 }
 
+# carried in every result this module returns: the harnesses here start
+# worker/coordinator processes, and a chip belongs to one process, so those
+# children are forced onto the CPU backend — nothing here is a device number
+CPU_HARNESS = ("CPU harness: spawned worker/coordinator processes run under "
+               "JAX_PLATFORMS=cpu")
+
 _TABLES = ["customer", "orders", "lineitem"]
 
 # Sustained mix: scans, multi-key aggregation, filtered join — all
@@ -357,6 +363,7 @@ def run_fte_chaos(n_scenarios: int = 12, base_seed: int = 1515,
             totals[k] = totals.get(k, 0) + v
     n_queries = sum(len(r["outcomes"]) for r in scenarios)
     return {
+        "harness": CPU_HARNESS,
         "n_scenarios": n_scenarios,
         "base_seed": base_seed,
         "n_queries": n_queries,
@@ -486,7 +493,8 @@ def run_coordinator_kill_drill(stall_s: float = 300.0,
         proc.kill()
         raise TimeoutError("coordinator child never wrote its port")
 
-    record: dict = {"sql": _DRILL_SQL, "workdir": work}
+    record: dict = {"sql": _DRILL_SQL, "workdir": work,
+                    "harness": CPU_HARNESS}
     proc2 = None
     proc1, port1 = _boot({"CHAOS_STALL_S": str(stall_s)})
     try:
@@ -688,7 +696,8 @@ def run_ha_takeover_drill(stall_s: float = 300.0,
         proc.kill()
         raise TimeoutError(f"HA child {node} never wrote its port")
 
-    record: dict = {"sql": _DRILL_SQL, "workdir": work}
+    record: dict = {"sql": _DRILL_SQL, "workdir": work,
+                    "harness": CPU_HARNESS}
     proc_a = proc_b = None
     try:
         proc_a, port_a = _boot("coordA", {"CHAOS_STALL_S": str(stall_s)})
@@ -816,6 +825,7 @@ def run_chaos(n_scenarios: int = 25, base_seed: int = 1009,
                         if o["retried"]]
     n_queries = sum(len(r["outcomes"]) for r in scenarios)
     return {
+        "harness": CPU_HARNESS,
         "n_scenarios": n_scenarios,
         "base_seed": base_seed,
         "n_queries": n_queries,

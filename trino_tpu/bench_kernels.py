@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .caching.executable_cache import program
 from .parallel.static_agg import AggSpec, static_grouped_agg
 from .parallel.distributed import distributed_grouped_agg, make_mesh
 
@@ -85,7 +86,7 @@ def _q1_project(b: Q1Batch):
     return keys, datas, mask
 
 
-@jax.jit
+@program("bench_kernels.q1_step")
 def q1_step(b: Q1Batch):
     """Single-chip fused Q1: one jitted program, 8 group slots."""
     keys, datas, mask = _q1_project(b)

@@ -42,8 +42,11 @@ from collections import OrderedDict
 from functools import lru_cache
 from typing import Any, Callable, Optional
 
+from ..telemetry import profiler
+
 __all__ = [
-    "jit_memo", "register_external", "enabled", "default_maxsize",
+    "jit_memo", "program", "program_name", "register_external", "enabled",
+    "default_maxsize",
     "registry_stats", "aggregate_stats", "clear_all", "warm_at_boot",
     "flush_warm_keys", "init_compile_cache", "warm_file_path",
     "reset_warm_state_for_test",
@@ -175,6 +178,113 @@ def jit_memo(name: str, maxsize: Optional[int] = None):
         return cache
 
     return deco
+
+
+PROGRAM_PREFIX = "trino_"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_COMPILE_TLS = threading.local()
+_COMPILE_LISTENING = False
+
+
+def program_name(site: str) -> str:
+    """``kernels.compact`` -> ``trino_kernels_compact``: the name a site's
+    program carries wherever JAX and the profiler show one
+    (``jit_trino_kernels_compact`` on the device plane's ``XLA Modules``
+    line, ``PjitFunction(trino_kernels_compact)`` on the host plane,
+    ``jit(trino_kernels_compact)`` in compile logs and events)."""
+    return PROGRAM_PREFIX + site.replace(".", "_")
+
+
+class _Program:
+    """A jitted function under its stable name.  Calling it records one
+    ``launch`` event in the flight recorder (the host's time inside the
+    call: argument handling, dispatch and, where the runtime's queue is
+    full, the wait for room); every other attribute (``lower``, ``trace``,
+    ``clear_cache`` ...) is the jitted function's own."""
+
+    def __init__(self, name: str, jitted):
+        self.name = name
+        self.jitted = jitted
+
+    def __call__(self, *args, **kwargs):
+        if not profiler.enabled():
+            return self.jitted(*args, **kwargs)
+        t0 = profiler.now()
+        try:
+            return self.jitted(*args, **kwargs)
+        finally:
+            profiler.event(profiler.LAUNCH, self.name, t0)
+
+    def __getattr__(self, attr):
+        return getattr(self.jitted, attr)
+
+
+def program(site: str, fn: Optional[Callable] = None, **jit_kwargs):
+    """THE way the engine jits: ``@program("kernels.compact")`` over the
+    function to trace, or ``program("join.expand", fn, donate_argnums=...)``.
+    ``site`` is unique per call site (tools/lint_program_names.py), dotted
+    like the ``jit_memo`` names, and never holds a shape; a factory whose
+    key changes the program's role may append a short static suffix.  The
+    traced function is renamed ``trino_<site>`` before ``jax.jit`` sees it,
+    so traces, compile logs and ``jax.monitoring`` events name the program
+    instead of ``fn``/``run``/``prog``."""
+    if fn is None:
+        return lambda f: program(site, f, **jit_kwargs)
+    import jax
+
+    name = program_name(site)
+    try:
+        fn.__name__ = fn.__qualname__ = name
+    except AttributeError:
+        # a bound method or partial takes no name: trace through a
+        # function that does (runs while tracing only)
+        inner = fn
+
+        def fn(*args, **kwargs):
+            return inner(*args, **kwargs)
+
+        fn.__name__ = fn.__qualname__ = name
+    _listen_for_compiles()
+    return _Program(name, jax.jit(fn, **jit_kwargs))
+
+
+def _listen_for_compiles() -> None:
+    """Register, once, the ``jax.monitoring`` listeners behind the flight
+    recorder's ``compile`` events: which program this thread's query had to
+    get, how long that took, and whether the persistent cache served it
+    (JAX fires the duration event around compile-or-load, and the hit
+    event inside it, on the same thread)."""
+    global _COMPILE_LISTENING
+    with _REGISTRY_LOCK:
+        if _COMPILE_LISTENING:
+            return
+        _COMPILE_LISTENING = True
+    import jax.monitoring as mon
+
+    mon.register_event_listener(_on_cache_event)
+    mon.register_event_duration_secs_listener(_on_compile)
+
+
+def _on_cache_event(event: str, **kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _COMPILE_TLS.hit = True
+
+
+def _on_compile(event: str, secs: float, **kw) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    hit = getattr(_COMPILE_TLS, "hit", False)
+    _COMPILE_TLS.hit = False
+    if not profiler.enabled():
+        return
+    name = str(kw.get("fun_name", "?"))
+    # the listener hears of a compile when it is over: the ring gets the
+    # whole interval, an open profiler session a marker at its end
+    t1 = profiler.now()
+    profiler.event(profiler.COMPILE, name, t1 - secs, t1,
+                   seconds=secs, cache_hit=hit)
+    profiler.annotate(profiler.COMPILE, name, seconds=secs, cache_hit=hit)
 
 
 def register_external(name: str, stats_fn: Callable[[], dict]) -> None:

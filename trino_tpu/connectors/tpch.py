@@ -30,6 +30,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from ..caching.executable_cache import program
 from ..spi.batch import Column, ColumnBatch
 from ..spi.connector import (
     ColumnSchema,
@@ -826,7 +827,7 @@ class _DeviceTpchGen:
         clerk_vocab = _fmt_keyed("Clerk", np.arange(1, max_clerk + 1))
         self.conn._dict_cache[("orders", "o_clerk")] = clerk_vocab
 
-        @jax.jit
+        @program("tpch.orders")
         def prog(status_t, prio_t, comment_t):
             okeys = jnp.arange(1, n + 1, dtype=jnp.uint64)
             orderdates = _randint(okeys, 72, _START, _END_ORDER)
@@ -880,7 +881,7 @@ class _DeviceTpchGen:
         sm_tab, _ = self._code_table("lineitem", "l_shipmode", _SHIPMODES)
         cm_tab, _ = self._code_table("lineitem", "l_comment", _comment_vocab())
 
-        @jax.jit
+        @program("tpch.lineitem")
         def prog(rf_t, ls_t, si_t, sm_t, cm_t):
             okeys1 = jnp.arange(1, n_orders + 1, dtype=jnp.uint64)
             nlines = _lines_per_order(okeys1)

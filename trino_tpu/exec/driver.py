@@ -100,12 +100,16 @@ class Driver:
         n = len(ops)
         timed = self.stats is not None
         st = self.stats.operators if timed else None
-        # profiler: one wall-clock read + one tuple store per successful
-        # page move (no device syncs, no locks).  At TRINO_TPU_PROFILE=full
-        # the produced page is blocked-on first, so the enclosing event
+        # ONE clock for OperatorStats.wall_s and the flight recorder: the
+        # recorder's ``now()``, read once before and once after each call
+        # (no device syncs, no locks), then an add for the stats and a
+        # tuple store for the recorder.  At TRINO_TPU_PROFILE=full the
+        # produced page is blocked-on first, so the enclosing event
         # charges true device time instead of async dispatch time.
         prof = profiler.enabled()
         prof_full = prof and profiler.is_full()
+        clocked = timed or prof
+        now = profiler.now
         names = self._names
         any_progress = False
         while not ops[-1].is_finished():
@@ -118,25 +122,26 @@ class Driver:
                     progressed = True
                     continue
                 if not cur.is_finished() and nxt.needs_input():
-                    t0 = time.perf_counter() if timed else 0.0
-                    p0 = time.time() if prof else 0.0
+                    t0 = now() if clocked else 0.0
                     page = cur.get_output()
+                    t1 = now() if clocked else 0.0
                     if timed:
-                        st[i].wall_s += time.perf_counter() - t0
+                        st[i].wall_s += t1 - t0
                     if page is not None:
                         if prof:
                             if prof_full:
                                 profiler.sync_batch(page)
-                            profiler.event(profiler.OPERATOR, names[i], p0,
-                                           rows=page.num_rows)
-                        t0 = time.perf_counter() if timed else 0.0
-                        p0 = time.time() if prof else 0.0
+                                t1 = now()
+                            profiler.event(profiler.OPERATOR, names[i], t0,
+                                           t1, rows=page.num_rows)
+                        t0 = now() if clocked else 0.0
                         nxt.add_input(page)
+                        t1 = now() if clocked else 0.0
                         if timed:
-                            st[i + 1].wall_s += time.perf_counter() - t0
+                            st[i + 1].wall_s += t1 - t0
                         if prof:
                             profiler.event(profiler.OPERATOR, names[i + 1],
-                                           p0, rows=page.num_rows)
+                                           t0, t1, rows=page.num_rows)
                         self._emit(i, page)
                         progressed = True
                 if cur.is_finished() and not nxt.input_done:
@@ -151,16 +156,16 @@ class Driver:
                         check_error_scalars([
                             e for op in ops
                             for e in getattr(op, "pending_errors", ())])
-                    t0 = time.perf_counter() if timed else 0.0
-                    p0 = time.time() if prof else 0.0
+                    t0 = now() if clocked else 0.0
                     nxt.finish_input()
+                    t1 = now() if clocked else 0.0
                     if timed:
-                        st[i + 1].wall_s += time.perf_counter() - t0
+                        st[i + 1].wall_s += t1 - t0
                     if prof:
                         # finish is where blocking operators (agg flush,
                         # sort, join build seal) do their heavy lifting
                         profiler.event(profiler.OPERATOR,
-                                       names[i + 1] + ".finish", p0)
+                                       names[i + 1] + ".finish", t0, t1)
                     progressed = True
             if ops[-1].is_finished():
                 break

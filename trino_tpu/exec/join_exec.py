@@ -26,7 +26,7 @@ Total blocking host interaction per probe batch: one scalar sync.
 from __future__ import annotations
 
 import threading
-from ..caching.executable_cache import jit_memo
+from ..caching.executable_cache import jit_memo, program
 from typing import Optional, Sequence
 
 import jax
@@ -163,7 +163,7 @@ def _hash_planes(h):
 def _hash_index_fn(S: int, n: int, interpret: bool):
     from ..ops import pallas_kernels as PK
 
-    @jax.jit
+    @program("join.hash_index")
     def fn(sorted_hash):
         live = sorted_hash < jnp.uint64(_SENT_PROBE)
         planes, h32 = _hash_planes(sorted_hash)
@@ -184,7 +184,7 @@ def _hash_index_fn(S: int, n: int, interpret: bool):
 @jit_memo("join._build_fn")
 def _build_fn(num_keys: int, has_valid: tuple, has_live: bool,
               want_range: bool = False):
-    @jax.jit
+    @program("join.build")
     def fn(*flat):
         i = 0
         datas, valids = [], []
@@ -251,7 +251,7 @@ def _dense_build_fn(size: int, has_valid: bool, has_live: bool, lo: int):
     empty slot).  Exactness needs no verify: direct addressing cannot
     collide, and uniqueness was already proven by max_run == 1."""
 
-    @jax.jit
+    @program("join.dense_build")
     def fn(key, *rest):
         i = 0
         valid = rest[i] if has_valid else None
@@ -398,7 +398,7 @@ def _probe_hash(num_keys: int, has_valid: tuple, has_remap: tuple,
 @jit_memo("join._ranges_fn")
 def _ranges_fn(num_keys: int, has_valid: tuple, has_live: bool,
                has_remap: tuple):
-    @jax.jit
+    @program("join.ranges")
     def fn(sorted_hash, *flat):
         h, live = _probe_hash(num_keys, has_valid, has_remap, has_live, flat)
         lo = K.searchsorted(sorted_hash, h, side="left")
@@ -420,7 +420,7 @@ def _hash_ranges_fn(num_keys: int, has_valid: tuple, has_live: bool,
                     has_remap: tuple, S: int, interpret: bool):
     from ..ops import pallas_kernels as PK
 
-    @jax.jit
+    @program("join.hash_ranges")
     def fn(table_planes, slot_gid, group_lo, group_counts, *flat):
         h, live = _probe_hash(num_keys, has_valid, has_remap, has_live, flat)
         ok = h < jnp.uint64(_SENT_PROBE)
@@ -803,9 +803,8 @@ def _make_pair_fn(cap: int, num_keys: int, has_pvalid: tuple,
                     overflow)
         return pairs, ok, matched, max_per_probe, build_id, overflow
 
-    if donate:
-        return jax.jit(fn, donate_argnums=(0, 1))  # lo, counts
-    return jax.jit(fn)
+    return program("join.pairs", fn,
+                   donate_argnums=(0, 1) if donate else ())  # lo, counts
 
 
 def run_pairs(table: DeviceJoinTable, lo, counts, total,
@@ -899,7 +898,7 @@ def run_pairs(table: DeviceJoinTable, lo, counts, total,
 @jit_memo("join._uranges_fn")
 def _uranges_fn(num_keys: int, has_pvalid: tuple, has_remap: tuple,
                 has_live: bool):
-    @jax.jit
+    @program("join.uranges")
     def fn(sorted_hash, perm, max_run, *flat):
         i = 0
         pkeys, pkvalids = [], []
@@ -950,7 +949,7 @@ def _dense_uranges_fn(size: int, lo: int, has_pvalid: bool, has_remap: bool,
     """Program A over a direct-address build: ONE gather per probe row —
     no hashing, no binary search, no verify (direct addressing is exact)."""
 
-    @jax.jit
+    @program("join.dense_uranges")
     def fn(dense, *flat):
         i = 0
         d = flat[i]
@@ -1121,7 +1120,7 @@ def _make_ugather_fn(cap: Optional[int], pair_types, pair_dicts,
                  for d, v in b_out]
         return tuple(p_out), tuple(b_out), ok_c, build_matched, overflow
 
-    return jax.jit(fn)
+    return program("join.unique_gather", fn)
 
 
 def plan_unique_cap(n_lanes: int, count: Optional[int]) -> Optional[int]:
@@ -1310,7 +1309,7 @@ def _make_unique_fn(num_keys: int, has_pvalid: tuple, has_remap: tuple,
                     for d, v in bgather)
         return out, ok_live, build_matched, None
 
-    return jax.jit(fn)
+    return program("join.unique", fn)
 
 
 def run_unique(table: DeviceJoinTable, probe_keys, remaps,

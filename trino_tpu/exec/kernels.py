@@ -25,7 +25,7 @@ spec / Trino GroupByHash behavior); equi-join keys never match on NULL.
 from __future__ import annotations
 
 import os
-from ..caching.executable_cache import jit_memo
+from ..caching.executable_cache import jit_memo, program
 from typing import Optional, Sequence
 
 import jax
@@ -118,7 +118,7 @@ def _neq(a, b):
 def _group_ids_fn(num_keys: int, has_valid: tuple[bool, ...], has_live: bool):
     n_valid = sum(has_valid)
 
-    @jax.jit
+    @program("kernels.group_ids")
     def fn(*flat):
         datas = list(flat[:num_keys])
         valids = list(flat[num_keys:num_keys + n_valid])
@@ -303,7 +303,7 @@ def hash_row_gids(keys: Sequence[tuple], live=None,
 
 @jit_memo("kernels._hash_finish_fn")
 def _hash_finish_fn():
-    @jax.jit
+    @program("kernels.hash_finish")
     def fn(row_gid):
         # jnp.argsort is stable: rows within a group keep input order, and
         # dead rows (gid = num_slots, beyond every real id) sort last
@@ -403,7 +403,7 @@ def _small_agg_fn(spec: tuple, num_keys: int, has_valid: tuple,
     in its own group's reduction (IEEE semantics are exactly SQL's)."""
     slots, strides, total = _code_layout(sizes, has_valid)
 
-    @jax.jit
+    @program("kernels.small_agg")
     def fn(*flat):
         i = 0
         codes, valids = [], []
@@ -543,7 +543,7 @@ def _group_ids_codes_fn(num_keys: int, has_valid: tuple, has_live: bool,
     (perm, gid, presence, decoded representative keys)."""
     slots, strides, total = _code_layout(sizes, has_valid)
 
-    @jax.jit
+    @program("kernels.group_ids_codes")
     def fn(*flat):
         i = 0
         codes, valids = [], []
@@ -668,7 +668,7 @@ def _reduce_fn(spec: tuple, cap: int):
     (gid is nondecreasing): XLA scatters serialize on TPU; the scan path is
     log-depth vector work."""
 
-    @jax.jit
+    @program("kernels.reduce")
     def fn(perm, gid, *flat):
         outs = []
         n = perm.shape[0]
@@ -855,7 +855,7 @@ def _finalize_fn(plan: tuple):
       ("count", None, has_valid)                     cast int64, drop valid
     inputs: flat (data [, valid]) per plan entry's source arity."""
 
-    @jax.jit
+    @program("kernels.finalize")
     def fn(*flat):
         outs = []
         i = 0
@@ -1082,7 +1082,7 @@ def grouped_reduce(
 
 @jit_memo("kernels._keys_out_fn")
 def _keys_out_fn(has_valid: tuple, cap: int):
-    @jax.jit
+    @program("kernels.keys_out")
     def fn(perm, gid, *flat):
         # gid is sorted: group g's representative is its FIRST sorted row —
         # a binary-search gather, not a scatter (scatters serialize on TPU)
@@ -1179,7 +1179,7 @@ def _device_sort_fn(num_keys: int, key_meta: tuple, col_has_valid: tuple,
     significant sort column), so a ``live``-masked batch stays valid after
     sorting and ``out_n`` (top-N) keeps the best live rows."""
 
-    @jax.jit
+    @program("kernels.device_sort")
     def fn(*flat):
         i = 0
         keys = []
@@ -1317,7 +1317,7 @@ def hash_combine(datas: Sequence) -> jnp.ndarray:
     return h
 
 
-@jax.jit
+@program("kernels.sorted_hash")
 def _sorted_hash(h):
     perm = jnp.argsort(h)
     return h[perm], perm
@@ -1383,7 +1383,7 @@ def build_join_table(keys: Sequence[tuple], num_rows: Optional[int] = None) -> J
 
 @jit_memo("kernels._probe_ranges_fn")
 def _probe_ranges_fn():
-    @jax.jit
+    @program("kernels.probe_ranges")
     def fn(sorted_hash, probe_hash):
         lo = searchsorted(sorted_hash, probe_hash, side="left")
         hi = searchsorted(sorted_hash, probe_hash, side="right")
@@ -1401,7 +1401,7 @@ def _expand_fn(cap: int):
     varying per-batch match counts reuse a handful of compiled programs;
     slots >= total produce clamped garbage the caller slices off."""
 
-    @jax.jit
+    @program("kernels.expand")
     def fn(lo, counts, perm):
         n = counts.shape[0]
         ends = jnp.cumsum(counts)
@@ -1505,7 +1505,7 @@ def _domain_fn(has_valid: bool, has_live: bool, dict_len: int):
     (valid_count, non-NaN count, min, max, presence-per-dictionary-code).
     Presence uses sort + binary search, not scatter (scatters serialize)."""
 
-    @jax.jit
+    @program("kernels.domain")
     def fn(data, *rest):
         i = 0
         valid = rest[i] if has_valid else None
@@ -1560,7 +1560,7 @@ def _compact_fn(n_cols: int, valid_flags: tuple, has_live_out: bool, cap: int):
     """Gather live rows to the front and slice to ``cap`` lanes (one stable
     bool sort + gathers, all on device)."""
 
-    @jax.jit
+    @program("kernels.compact")
     def fn(live, *flat):
         order = jnp.argsort(~live, stable=True)[:cap]
         out = [x[order] for x in flat]

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..caching.executable_cache import program
 from ..ops.expr import compile_expression
 from ..sql.analyzer import STAT_AGGS
 from ..spi.batch import (Column, ColumnBatch, encoded_exec, pad_to_bucket,
@@ -564,7 +565,7 @@ class FilterProjectOperator(Operator):
             err_code = None if err is None else jnp.max(err)
             return outs, live, err_code
 
-        self._compiled = (jax.jit(run), projs)
+        self._compiled = (program("operators.filter_project", run), projs)
         self._compiled_dicts = dicts
         with FilterProjectOperator._PROGRAM_CACHE_LOCK:
             FilterProjectOperator._PROGRAM_CACHE.setdefault(
@@ -1660,7 +1661,7 @@ def _residual_program(expr: RowExpression, types, dicts):
         data, valid = ce(cols)
         return data if valid is None else (data & valid)
 
-    prog = jax.jit(run)
+    prog = program("operators.join_residual", run)
     with _RESIDUAL_LOCK:
         _RESIDUAL_CACHE.setdefault(key, (prog, list(dicts)))
         if len(_RESIDUAL_CACHE) > 1024:

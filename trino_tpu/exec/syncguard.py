@@ -22,7 +22,10 @@ in tools/lint_host_sync.py flags raw patterns):
 
 The counters roll up into :class:`SyncStats` (merged into QueryStats like
 ScanIngestStats, rendered by EXPLAIN ANALYZE, exported as ``trino.exec.*``
-span attributes).  ``hot_region`` marks a steady-state operator hot loop;
+span attributes).  A transfer that blocks is also a ``host-sync`` span of
+the flight recorder (telemetry/profiler.py) under its tag, as long as the
+wait lasts; one that was ready records nothing.  ``hot_region`` marks a
+steady-state operator hot loop;
 ``forbidden`` mode (tests) raises :class:`SyncViolation` on any blocking
 transfer inside a hot region.
 """
@@ -32,6 +35,8 @@ from __future__ import annotations
 import threading
 from contextlib import contextmanager
 from dataclasses import dataclass, field, fields
+
+from ..telemetry import profiler
 
 __all__ = [
     "SyncStats",
@@ -169,8 +174,14 @@ def fetch(x, tag: str):
     hot regions under test enforcement).  Returns a numpy value."""
     import jax
 
-    count_sync(tag, blocking=not _is_ready(x))
-    return jax.device_get(x)
+    blocking = not _is_ready(x)
+    count_sync(tag, blocking=blocking)
+    if not blocking:
+        return jax.device_get(x)
+    # the wait itself, on the flight recorder (and an open profiler
+    # session): the host stands still here until the device catches up
+    with profiler.span(profiler.HOST_SYNC, tag):
+        return jax.device_get(x)
 
 
 class AsyncScalar:
@@ -199,10 +210,12 @@ class AsyncScalar:
             _STATS.async_polls += 1
             if hit:
                 _STATS.poll_hits += 1
-        if not hit:
-            # the copy is in flight but we must wait: a genuine blocking sync
-            count_sync(self.tag, blocking=True)
-        return jax.device_get(self.value)
+        if hit:
+            return jax.device_get(self.value)
+        # the copy is in flight but we must wait: a genuine blocking sync
+        count_sync(self.tag, blocking=True)
+        with profiler.span(profiler.HOST_SYNC, self.tag):
+            return jax.device_get(self.value)
 
     def get_if_ready(self):
         """Non-blocking: the value if the copy landed, else None."""

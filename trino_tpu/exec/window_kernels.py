@@ -25,7 +25,7 @@ from __future__ import annotations
 
 from typing import Optional, Sequence
 
-from ..caching.executable_cache import jit_memo
+from ..caching.executable_cache import jit_memo, program
 
 import jax
 import jax.numpy as jnp
@@ -131,8 +131,8 @@ def _window_program(
     order_spec: tuple[tuple[bool, bool, bool], ...],  # (has_valid, asc, nf)
     fn_spec: tuple,  # (fn, n_args, arg_valid tuple, offset, frame, dtype_str)
 ):
-    @jax.jit
-    def program(shape_carrier, *flat):
+    @program("window.window")
+    def fn(shape_carrier, *flat):
         i = 0
         part, pvalid = [], []
         for k in range(n_part):
@@ -352,7 +352,7 @@ def _window_program(
             outs.append((out_d, out_v))
         return outs
 
-    return program
+    return fn
 
 
 def _frame_indices(frame, arange, part_start_idx, part_end_idx,
@@ -427,5 +427,5 @@ def compute_windows(
             flat.append(jnp.asarray(d))
             if v is not None:
                 flat.append(jnp.asarray(v))
-    program = _window_program(n_part, part_valid, order_spec, tuple(fn_spec))
-    return program(jnp.zeros((num_rows,), jnp.int8), *flat)
+    prog = _window_program(n_part, part_valid, order_spec, tuple(fn_spec))
+    return prog(jnp.zeros((num_rows,), jnp.int8), *flat)

@@ -20,7 +20,7 @@ FIXED_HASH_DISTRIBUTION) per SURVEY §2.4's collective mapping.
 from __future__ import annotations
 
 import threading
-from ..caching.executable_cache import jit_memo
+from ..caching.executable_cache import jit_memo, program
 from typing import Optional, Sequence
 
 import jax
@@ -117,7 +117,7 @@ def _shuffle_program(n_dev: int, n_cols: int, dtypes: tuple,
 
     n_in = n_cols + sum(valid_flags) + n_keys + 1
     n_out = n_cols + sum(valid_flags) + 1
-    return mesh, jax.jit(jax.shard_map(
+    return mesh, program("collective.shuffle", jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple([P(_AXIS)] * n_in),
         out_specs=tuple([P(_AXIS)] * n_out),
@@ -174,7 +174,7 @@ def _sort_by_dest_program(n_dev: int, n_cols: int, valid_flags: tuple,
 
     n_in = n_cols + sum(valid_flags) + n_keys + 1
     n_out = n_cols + sum(valid_flags) + 1
-    return mesh, jax.jit(jax.shard_map(
+    return mesh, program("collective.sort_by_dest", jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple([P(_AXIS)] * n_in),
         out_specs=tuple([P(_AXIS)] * n_out),
@@ -222,7 +222,7 @@ def _tiled_all_to_all_program(n_dev: int, n_cols: int, valid_flags: tuple,
 
     n_in = n_cols + sum(valid_flags) + 1
     n_out = n_cols + sum(valid_flags) + 1
-    return mesh, jax.jit(jax.shard_map(
+    return mesh, program("collective.tiled_all_to_all", jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple([P(_AXIS)] * n_in),
         out_specs=tuple([P(_AXIS)] * n_out),

@@ -138,16 +138,18 @@ class DistributedQueryRunner:
     def _plan_stmt(self, stmt: ast.Statement) -> PlanNode:
         from ..runner import check_select_access
 
-        with self.tracer.span("trino.planner"):
+        with self.tracer.span("trino.planner") as sp:
+            sp.record(cache_hit=False)
             plan = LogicalPlanner(
                 self.catalog, self.session.default_catalog).plan(stmt)
             plan = optimize(plan, self.catalog)
-        check_select_access(plan, self.access_control, self.session.user)
-        writer_tasks = 1
-        if self.session.scale_writers:
-            writer_tasks = max(1, min(self.session.writer_task_limit,
-                                      self.worker_count))
-        return add_exchanges(plan, writer_tasks=writer_tasks)
+            check_select_access(plan, self.access_control,
+                                self.session.user)
+            writer_tasks = 1
+            if self.session.scale_writers:
+                writer_tasks = max(1, min(self.session.writer_task_limit,
+                                          self.worker_count))
+            return add_exchanges(plan, writer_tasks=writer_tasks)
 
     def create_subplan(self, sql: str) -> SubPlan:
         return fragment_plan(self.create_plan(sql))
@@ -174,12 +176,15 @@ class DistributedQueryRunner:
     def _execute(self, sql: str) -> QueryResult:
         from ..caching import plan_cache, result_cache
         from ..runner import check_ddl_access, check_select_access
+        from ..telemetry import profiler
 
         # Tier A fast path (see runner.py): a hit skips parse → analyze →
         # plan → optimize → add_exchanges; only statements that reached
         # _plan_stmt were ever stored, so non-SELECT texts always miss
-        entry = plan_cache.lookup(sql, self.session, self.catalog,
-                                  flavor="fragmented")
+        with profiler.span(profiler.PLAN, "cache-lookup") as lookup:
+            entry = plan_cache.lookup(sql, self.session, self.catalog,
+                                      flavor="fragmented")
+            lookup.set(cache_hit=entry is not None)
         if entry is not None:
             check_select_access(entry.plan, self.access_control,
                                 self.session.user)
@@ -268,14 +273,20 @@ class DistributedQueryRunner:
 
     def _execute_subplan(self, subplan: SubPlan,
                          stats_sink: Optional[list]) -> QueryResult:
-        if self.session.retry_policy == "TASK":
-            from .fte import run_fte_query
+        from ..telemetry import profiler
 
-            return self._to_result(subplan, run_fte_query(self, subplan,
-                                                          stats_sink))
-        if self.session.retry_policy == "QUERY":
-            return self._run_query_retry(subplan, stats_sink)
-        return self._run_streaming(subplan, stats_sink)
+        # everything between the plan and the answer that is not inside a
+        # task: stage set-up, spawning and joining tasks, result collection
+        with profiler.span(profiler.SCHEDULE, "subplan",
+                           stages=len(subplan.all_fragments())):
+            if self.session.retry_policy == "TASK":
+                from .fte import run_fte_query
+
+                return self._to_result(
+                    subplan, run_fte_query(self, subplan, stats_sink))
+            if self.session.retry_policy == "QUERY":
+                return self._run_query_retry(subplan, stats_sink)
+            return self._run_streaming(subplan, stats_sink)
 
     def _run_query_retry(self, subplan: SubPlan,
                          stats_sink: Optional[list]) -> QueryResult:
@@ -1296,7 +1307,6 @@ class DistributedQueryRunner:
         # every driver/exchange event this thread (and its pipeline group
         # threads, via run_pipelines context inheritance) records attributes
         profiler.set_context(trec.query_id, trec.task_id)
-        pt0 = profiler.now()
         t0 = _time.perf_counter()
         pipelines = None
         state = "FINISHED"
@@ -1354,8 +1364,9 @@ class DistributedQueryRunner:
                 if query_record is not None:
                     rt.add_input(query_record, ingest.scan_rows,
                                  ingest.scan_bytes)
+            # closing the span writes the flight recorder's ``task`` event
+            sp.record(state=state)
         tm.TASK_WALL_SECONDS.record(_time.perf_counter() - t0)
-        profiler.event(profiler.TASK, trec.task_id, pt0, state=state)
         if state == "FAILED":
             tm.TASKS_FAILED.inc()
         rt.task_finished(trec, state, error=err)

@@ -61,7 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..caching.executable_cache import jit_memo
+from ..caching.executable_cache import jit_memo, program
 from ..exec import kernels as K
 from ..exec import syncguard as SG
 from ..exec.operators import Operator
@@ -211,8 +211,10 @@ class _ResidentProgram(_AccumulateProgram):
         else:
             in_types = list(spec.partial.source.output_types)
         self._compile_chain(in_types, [None] * len(in_types))
-        self._fn = jax.jit(self._run, donate_argnums=(0,))
-        self._init_fn = jax.jit(self._initial_state)
+        self._fn = program("resident.accumulate", self._run,
+                           donate_argnums=(0,))
+        self._init_fn = program("resident.initial_state",
+                                self._initial_state)
 
     def __call__(self, state, feed_cols, live, builds, batch_remaps,
                  state_remaps):
@@ -301,7 +303,7 @@ def _build_prep_program(n_dev: int, n_payload: int):
         return tuple(outs)
 
     n_in = 2 + 2 * n_payload
-    return mesh, jax.jit(jax.shard_map(
+    return mesh, program("resident.build_prep", jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple([P(_AXIS)] * n_in),
         out_specs=tuple([P()] * (n_in + 1)),

@@ -42,7 +42,8 @@ import warnings
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
-from ..caching.executable_cache import jit_memo, register_external
+from ..caching.executable_cache import (jit_memo, program,
+                                        register_external)
 
 import jax
 import jax.numpy as jnp
@@ -246,10 +247,11 @@ class _AccumulateProgram:
     def __init__(self, spec: FusedStageSpec, in_types, in_dicts):
         self.spec = spec
         self._compile_chain(in_types, in_dicts)
-        self._fn = jax.jit(self._run, donate_argnums=(0,))
+        self._fn = program("stage.accumulate", self._run,
+                           donate_argnums=(0,))
         # one launch for the whole zero pytree (it is immediately donated to
         # the first accumulate call, so every task needs fresh buffers)
-        self._init_fn = jax.jit(self._initial_state)
+        self._init_fn = program("stage.initial_state", self._initial_state)
 
     def _compile_chain(self, in_types, in_dicts):
         spec = self.spec
@@ -421,7 +423,7 @@ def _ingest_program(n_out: int, miss_valid: tuple, has_live: bool):
     per-column mask fill, collapsed from ~3x #columns eager dispatches per
     batch into a single launch ahead of the accumulate call."""
 
-    @jax.jit
+    @program("stage.ingest")
     def run(cols, live):
         n_in = cols[0][0].shape[0]
         pad = n_out - n_in
@@ -562,7 +564,7 @@ def _merge_program(n_dev: int, cap: int, key_dtypes: tuple, dict_flags: tuple,
     n_in = 2 * nk + n_states + sum(1 for s in state_sig if s[2]) + 1 + 2 * n_dict
     n_out = 2 * nk + len(final_sig) \
         + sum(1 for f in final_sig if f[0] not in ("count",)) + 1
-    return mesh, jax.jit(jax.shard_map(
+    return mesh, program("stage.merge", jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple([P(_AXIS)] * n_in),
         out_specs=tuple([P(_AXIS)] * n_out),

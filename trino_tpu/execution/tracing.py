@@ -5,16 +5,32 @@ decorators, tracing/TrinoAttributes.java span vocabulary, spans per
 query/stage/task propagated into workers) without the OTel SDK dependency:
 spans are plain objects collected per query; an exporter hook receives
 finished root spans (plug an OTLP exporter there in a deployment).  The
-attribute names follow the reference's ``trino.*`` vocabulary."""
+attribute names follow the reference's ``trino.*`` vocabulary.
+
+A span reads no clock of its own: ``Tracer.span`` opens the flight
+recorder's span of the matching kind (telemetry/profiler.py;
+``RECORDER_KIND``) and takes ``start``/``end`` from it, so the six sites
+that open tracer spans are instrumented once, on the recorder's clock."""
 
 from __future__ import annotations
 
 import threading
-import time
 import uuid
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Optional
+
+from ..telemetry import profiler
+
+# tracer span name -> (the flight recorder's kind for the same boundary,
+# the attribute that names the recorder's event: the query or task id;
+# None names it by the tracer's own word, "planner" / "execution")
+RECORDER_KIND = {
+    "trino.query": (profiler.EXECUTE, "query_id"),
+    "trino.planner": (profiler.PLAN, None),
+    "trino.execution": (profiler.SCHEDULE, None),
+    "trino.task": (profiler.TASK, "trino.task.id"),
+}
 
 __all__ = ["Span", "Tracer", "traceparent", "parse_traceparent",
            "annotate_scan_span", "annotate_sync_span",
@@ -151,10 +167,20 @@ class Span:
     trace_id: str = ""
     span_id: str = ""
     parent_id: Optional[str] = None
+    # the flight recorder's span for the same boundary (None for a name
+    # the recorder has no kind for): ``record`` adds to its event
+    recorder: Optional[profiler.span] = field(
+        default=None, repr=False, compare=False)
 
     @property
     def duration_ms(self) -> float:
-        return ((self.end or time.perf_counter()) - self.start) * 1e3
+        return ((self.end or profiler.now()) - self.start) * 1e3
+
+    def record(self, **args) -> "Span":
+        """Attributes for the flight recorder's event of this span."""
+        if self.recorder is not None:
+            self.recorder.set(**args)
+        return self
 
     def set(self, key: str, value) -> "Span":
         self.attributes[key] = value
@@ -172,8 +198,7 @@ class Span:
     def to_dict(self) -> dict:
         """JSON-safe subtree for shipping finished spans across processes
         (worker -> coordinator with task completion).  Durations travel as
-        milliseconds: perf_counter timestamps are not comparable across
-        processes, so absolute start/end stay process-local."""
+        milliseconds; the recorder's own events carry the timestamps."""
         return {
             "name": self.name,
             "attributes": dict(self.attributes),
@@ -204,7 +229,12 @@ class _SpanCtx:
         return self.span
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        self.span.end = time.perf_counter()
+        rec = self.span.recorder
+        if rec is not None:
+            rec.__exit__(exc_type, exc, tb)
+            self.span.end = rec.t1
+        else:
+            self.span.end = profiler.now()
         if exc is not None:
             self.span.set("error", type(exc).__name__)
         self.tracer._pop(self.span)
@@ -238,8 +268,16 @@ class Tracer:
         (trace_id, parent_span_id) pair from ``parse_traceparent``: the
         span becomes a local root carrying the remote identity, so the
         coordinator can re-attach the shipped subtree."""
-        s = Span(name, dict(attributes), time.perf_counter(),
-                 span_id=_new_span_id())
+        if name in RECORDER_KIND:
+            kind, named_by = RECORDER_KIND[name]
+            rec = profiler.span(kind, str(attributes.get(
+                named_by, name.rpartition(".")[2])))
+            rec.__enter__()
+            s = Span(name, dict(attributes), rec.t0,
+                     span_id=_new_span_id(), recorder=rec)
+        else:
+            s = Span(name, dict(attributes), profiler.now(),
+                     span_id=_new_span_id())
         stack = self._stack()
         if parent is not None:
             if not parent.trace_id:

@@ -441,7 +441,6 @@ class TaskServer:
         from ..telemetry import profiler
 
         profiler.set_context(str(desc.get("query_id", "")), t.task_id)
-        pt0 = profiler.now()
         # remote-parented span: the coordinator's traceparent header makes
         # this a local root carrying the query's trace identity; the ctx is
         # entered/exited explicitly so the span can close (and publish to
@@ -618,9 +617,10 @@ class TaskServer:
         except Exception:  # noqa: BLE001
             pass
         try:
+            # closing the span writes the flight recorder's ``task`` event
+            sp.record(state=state)
             ctx.__exit__(None, None, None)
             t.span = sp.to_dict()  # span visible before terminal state read
-            profiler.event(profiler.TASK, t.task_id, pt0, state=state)
             # sweep the ring slice for this task (run_pipelines group
             # threads inherited the context, so their operator events are
             # included) BEFORE the terminal state so a status read that

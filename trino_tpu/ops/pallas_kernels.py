@@ -18,7 +18,7 @@ from __future__ import annotations
 
 from functools import partial
 
-from ..caching.executable_cache import jit_memo
+from ..caching.executable_cache import jit_memo, program
 
 import jax
 import jax.numpy as jnp
@@ -78,7 +78,7 @@ def _build(G: int, n_blocks: int, interpret: bool):
             interpret=interpret,
         )(vals, gid, live)
 
-    return jax.jit(run)
+    return program("pallas.segment_sum", run)
 
 
 def masked_segment_sum_f32(values, gid, live, num_groups: int,
@@ -249,7 +249,7 @@ def _build_insert(P: int, S: int, n_blocks: int, interpret: bool):
             interpret=interpret,
         )(planes, hash32, live)
 
-    return jax.jit(run)
+    return program("pallas.hash_insert", run)
 
 
 @jit_memo("pallas._build_probe")
@@ -273,7 +273,7 @@ def _build_probe(P: int, S: int, n_blocks: int, interpret: bool):
             interpret=interpret,
         )(table, sgid, planes, hash32, live)
 
-    return jax.jit(run)
+    return program("pallas.hash_probe", run)
 
 
 def _pad_rows(planes, hash32, live, n: int):

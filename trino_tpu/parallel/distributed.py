@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..caching.executable_cache import program
 from .static_agg import AggSpec, combine_partials, static_grouped_agg
 
 __all__ = [
@@ -130,18 +131,19 @@ def distributed_grouped_agg(
         ),
         check_vma=False,
     )
-    return jax.jit(sharded)
+    return program("parallel.sharded_aggregate", sharded)
 
 
 def broadcast_gather(mesh: Mesh, axis: str):
     """all_gather of a sharded build side — the broadcast-join distribution
     (BroadcastOutputBuffer.java:56 → one collective)."""
 
-    def program(x):
+    def local(x):
         return jax.lax.all_gather(x, axis, axis=0, tiled=True)
 
-    return jax.jit(
+    return program(
+        "parallel.broadcast_gather",
         jax.shard_map(
-            program, mesh=mesh, in_specs=P(axis), out_specs=P(), check_vma=False
+            local, mesh=mesh, in_specs=P(axis), out_specs=P(), check_vma=False
         )
     )

@@ -21,6 +21,9 @@ from concurrent.futures import ThreadPoolExecutor
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
+from ..telemetry import profiler
+from ..telemetry import runtime as rt
+
 __all__ = ["QueryDispatcher", "TrinoTpuServer"]
 
 _PAGE_ROWS = 4096
@@ -50,6 +53,47 @@ class _Query:
         self.done = threading.Event()
         self.cancelled = False
         self.recovered = False  # rehydrated from the query-state WAL
+        # the protocol's own view of the query, on the flight recorder's
+        # clock: POST received, execution ended, polls answered, and whether
+        # the last page went out (the recorder's ``query`` span, ``stats``)
+        self.t_post = profiler.now()
+        self.t_done: Optional[float] = None
+        self.polls = 0
+        self.served = False
+        self.final_stats: Optional[dict] = None
+
+    def finish(self) -> None:
+        self.t_done = profiler.now()
+        self.done.set()
+
+    def stats(self) -> dict:
+        """The ``stats`` object of every protocol response, as a Trino
+        client prints it.  ``queuedTimeMillis``: POST received until the
+        runner began executing (the recorder's ``execute`` span once the
+        query is over, the QueryRecord's creation before) plus the
+        resource-group wait; ``elapsedTimeMillis``: POST received until the
+        execution ended, or until now; ``processedRows``: rows the scans
+        read (QueryRecord)."""
+        if self.final_stats is not None:
+            return dict(self.final_stats, state=self.state)
+        rec = rt.find_query(self.id)
+        end = self.t_done if self.t_done is not None else profiler.now()
+        began = rec.create_time if rec is not None else end
+        executed = profiler.find(self.id, profiler.EXECUTE) \
+            if self.t_done is not None else []
+        if executed:
+            began = executed[0]["ts"]
+        out = {
+            "state": self.state,
+            "queuedTimeMillis": int(round(
+                max(began - self.t_post, 0.0) * 1e3
+                + (rec.queued_ms if rec is not None else 0.0))),
+            "elapsedTimeMillis": int(round((end - self.t_post) * 1e3)),
+            "processedRows": rec.input_rows if rec is not None else 0,
+        }
+        if self.t_done is not None:
+            self.final_stats = out
+        return dict(out)
 
 
 class QueryDispatcher:
@@ -138,18 +182,18 @@ class QueryDispatcher:
     def _run(self, q: _Query) -> None:
         if q.cancelled:
             q.state = "CANCELED"
-            q.done.set()
+            q.finish()
             return
         try:
             self._await_memory(q)
         except Exception as e:
             q.error = f"{type(e).__name__}: {e}"
             q.state = "FAILED"
-            q.done.set()
+            q.finish()
             return
         if q.cancelled:
             q.state = "CANCELED"
-            q.done.set()
+            q.finish()
             return
         q.state = "RUNNING"
         try:
@@ -160,7 +204,7 @@ class QueryDispatcher:
         except Exception as e:  # surfaced through the protocol, not the log
             q.error = f"{type(e).__name__}: {e}"
             q.state = "FAILED"
-        q.done.set()
+        q.finish()
 
     def _resume(self, q: _Query, pq) -> None:
         """Run one crash-recovered query to completion under its original
@@ -168,7 +212,7 @@ class QueryDispatcher:
         the same nextUri and sees the query finish."""
         if q.cancelled:
             q.state = "CANCELED"
-            q.done.set()
+            q.finish()
             return
         q.state = "RUNNING"
         try:
@@ -176,7 +220,7 @@ class QueryDispatcher:
         except Exception as e:
             q.error = f"{type(e).__name__}: {e}"
             q.state = "FAILED"
-        q.done.set()
+        q.finish()
 
     def _deliver(self, q: _Query, result) -> None:
         if q.cancelled:
@@ -259,7 +303,7 @@ class _Handler(BaseHTTPRequestHandler):
     def _query_payload(self, q: _Query, token: int) -> dict:
         out = {
             "id": q.id,
-            "stats": {"state": q.state},
+            "stats": q.stats(),
         }
         if q.state in ("QUEUED", "RUNNING"):
             out["nextUri"] = f"/v1/statement/{q.id}/{token}"
@@ -278,6 +322,21 @@ class _Handler(BaseHTTPRequestHandler):
             out["nextUri"] = f"/v1/statement/{q.id}/{token + 1}"
         return out
 
+    def _respond(self, q: _Query, token: int) -> None:
+        """Send one protocol response; the one without a ``nextUri`` is
+        the last page, and closes the recorder's ``query`` span: POST
+        received to here, over all the polls in between (what the client
+        waited for, seen from the server)."""
+        payload = self._query_payload(q, token)
+        self._send(200, payload)
+        if "nextUri" in payload:
+            return
+        with self.dispatcher._lock:
+            first, q.served = not q.served, True
+        if first:
+            profiler.query_event(q.id, q.t_post, profiler.now(),
+                                 state=q.state, polls=q.polls)
+
     def do_POST(self):
         if self.path.rstrip("/") != "/v1/statement":
             self._send(404, {"error": {"message": "not found"}})
@@ -286,7 +345,7 @@ class _Handler(BaseHTTPRequestHandler):
         sql = self.rfile.read(length).decode("utf-8")
         qid = (self.headers.get("X-Trino-Tpu-Query-Id") or "").strip() or None
         q = self.dispatcher.submit(sql, qid=qid)
-        self._send(200, self._query_payload(q, 0))
+        self._respond(q, 0)
 
     def _cluster_metrics(self) -> str:
         """One Prometheus exposition for the whole cluster: the coordinator
@@ -361,7 +420,8 @@ class _Handler(BaseHTTPRequestHandler):
             return
         # brief server-side wait cuts client poll round trips
         q.done.wait(timeout=0.5)
-        self._send(200, self._query_payload(q, int(parts[3])))
+        q.polls += 1
+        self._respond(q, int(parts[3]))
 
     def do_DELETE(self):
         parts = self.path.strip("/").split("/")

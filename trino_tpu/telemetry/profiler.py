@@ -11,6 +11,20 @@ Recording is one ``time.time()`` call plus a tuple store into the ring —
 no contended locks, no device syncs — so the default level keeps the
 SyncGuard zero-hot-sync invariant (tests/test_profiler.py asserts it).
 
+The recorder is the program's ONE span system (PR 26): the layer
+boundaries — ``query`` (server/protocol.py), ``execute``, ``plan``,
+``schedule``, ``task`` (the runners, through execution/tracing.py's
+``Tracer.span``), ``operator`` (exec/driver.py), ``host-sync``
+(exec/syncguard.py), ``launch`` and ``compile``
+(caching/executable_cache.py ``program``) — are all events here, on
+``now()``.  The coarse kinds (``ANNOTATED``) also enter a
+``jax.profiler.TraceAnnotation("trino.<kind>[:<name>]")``, so an open
+profiler session shows the program's rows beside the device's on the
+session's own clock; ``operator``, ``launch`` and ``query`` stay
+ring-only.  ``events_since``/``dropped_since`` hand every query's events
+from an instant on to a reader that lays them over a device trace
+(benchmark/harness/program_spans.py finds the offset between the clocks).
+
 Levels (``TRINO_TPU_PROFILE``):
 
 - ``off``/``0``  — recording disabled entirely.
@@ -38,13 +52,15 @@ import os
 import threading
 import time
 import weakref
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from typing import Optional
 
 __all__ = [
     "OPERATOR", "FUSED", "RESIDENT", "EXCHANGE", "STAGE", "SPILL",
-    "SPECULATION", "TASK", "ADAPTIVE", "RECOVERY",
-    "level", "enabled", "is_full", "set_level", "event", "instant",
+    "SPECULATION", "TASK", "ADAPTIVE", "RECOVERY", "QUERY", "EXECUTE",
+    "PLAN", "SCHEDULE", "HOST_SYNC", "LAUNCH", "COMPILE", "ANNOTATED",
+    "level", "enabled", "is_full", "set_level", "event", "instant", "span",
+    "annotate", "query_event", "events_since", "dropped_since", "find",
     "now", "set_context", "capture_context", "apply_context", "sync_batch",
     "collect", "harvest", "add_remote_events", "take_task_events",
     "events_for", "chrome_trace", "reset_for_test",
@@ -61,6 +77,19 @@ SPECULATION = "speculation"
 TASK = "task"
 ADAPTIVE = "adaptive"
 RECOVERY = "recovery"
+# the layer boundaries (PR 26), coarsest first
+QUERY = "query"          # POST received -> last page served (server)
+EXECUTE = "execute"      # inside the runner's execute(): the clock anchor
+PLAN = "plan"            # _plan_stmt, and the plan-cache lookup
+SCHEDULE = "schedule"    # _execute_subplan: stage set-up, result collection
+HOST_SYNC = "host-sync"  # a device->host transfer that blocked: the wait
+LAUNCH = "launch"        # host time inside one call of a compiled program
+COMPILE = "compile"      # a program compiled, or loaded from the disk cache
+
+# kinds that also enter a jax.profiler.TraceAnnotation (a few dozen a
+# query); operator and launch are too many for a trace viewer, and query
+# is held open by no one thread
+ANNOTATED = frozenset({EXECUTE, PLAN, SCHEDULE, TASK, HOST_SYNC, COMPILE})
 
 _OFF, _DEFAULT, _FULL = 0, 1, 2
 
@@ -77,6 +106,7 @@ def _level_from_env() -> int:
 _LEVEL = _level_from_env()
 _CAP = int(os.environ.get("TRINO_TPU_PROFILE_RING", "4096"))
 _MAX_RINGS = 512       # dead-thread rings retained beyond this are pruned
+_DROPPED_KEPT = 1024   # overwritten events a ring remembers the times of
 _MAX_PROFILES = 64     # finished-query profiles retained
 
 
@@ -106,7 +136,7 @@ class _Ring:
     no lock; the registry lock is taken once, at ring creation."""
 
     __slots__ = ("buf", "cap", "idx", "tid", "tname", "thread_ref",
-                 "qid", "task", "overwrites")
+                 "qid", "task", "overwrites", "dropped_ts")
 
     def __init__(self, cap: int):
         t = threading.current_thread()
@@ -119,12 +149,16 @@ class _Ring:
         self.qid = ""
         self.task = ""
         self.overwrites = 0
+        # start times of the newest overwritten events (dropped_since)
+        self.dropped_ts: deque = deque(maxlen=_DROPPED_KEPT)
 
     def push(self, ev: tuple) -> None:
         if len(self.buf) < self.cap:
             self.buf.append(ev)
         else:
-            self.buf[self.idx % self.cap] = ev
+            at = self.idx % self.cap
+            self.dropped_ts.append(self.buf[at][0])
+            self.buf[at] = ev
             self.overwrites += 1
         self.idx += 1
 
@@ -177,6 +211,63 @@ def instant(kind: str, name: str, **args) -> None:
         return
     r = _ring()
     r.push((time.time(), 0.0, kind, name, r.qid, r.task, args or None))
+
+
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, bound at first use
+
+
+def _annotation(kind: str, name: str, **args):
+    """The ``trino.<kind>[:<name>]`` row of an open profiler session (the
+    constructor costs about a microsecond when none is open)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        import jax.profiler
+
+        _ANNOTATION = jax.profiler.TraceAnnotation
+    return _ANNOTATION(f"trino.{kind}:{name}" if name else f"trino.{kind}",
+                       **args)
+
+
+def annotate(kind: str, name: str, **args) -> None:
+    """A marker row in an open profiler session, for what is only known
+    when it is over (a compile: ``jax.monitoring`` reports afterwards)."""
+    if _LEVEL:
+        with _annotation(kind, name, **args):
+            pass
+
+
+class span:
+    """Context-manager form of :func:`event`: one ``now()`` on entry, one
+    on exit, one tuple store.  ``t0``/``t1`` are kept (and set at every
+    level, ``off`` included) for callers that take their own start and end
+    from the recorder's clock (execution/tracing.py ``Span``); ``set`` adds
+    attributes before the exit."""
+
+    __slots__ = ("kind", "name", "args", "t0", "t1", "_ann")
+
+    def __init__(self, kind: str, name: str = "", **args):
+        self.kind = kind
+        self.name = name
+        self.args = args
+        self.t0 = self.t1 = 0.0
+        self._ann = None
+
+    def set(self, **args) -> "span":
+        self.args.update(args)
+        return self
+
+    def __enter__(self) -> "span":
+        if _LEVEL and self.kind in ANNOTATED:
+            self._ann = _annotation(self.kind, self.name)
+            self._ann.__enter__()
+        self.t0 = time.time()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.t1 = time.time()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        event(self.kind, self.name, self.t0, self.t1, **self.args)
 
 
 def set_context(query_id: str, task_id: str = "") -> tuple:
@@ -318,6 +409,71 @@ def events_for(query_id: str) -> list[dict]:
         if stored:
             procs[str(os.getpid())] = "coordinator"
     return stored
+
+
+def query_event(query_id: str, t0: float, t1: float, **args) -> None:
+    """The ``query`` span of the statement protocol: POST received to last
+    page served.  It crosses handler threads, so it is written once, as a
+    complete event, straight into the query's stored profile (the query's
+    ring events were harvested when its execution ended)."""
+    if not _LEVEL or not query_id:
+        return
+    t = threading.current_thread()
+    ev = (t0, t1 - t0, QUERY, query_id, query_id, "", args or None)
+    with _PROFILES_LOCK:
+        p = _store(query_id)
+        p["events"].append(_ev_dict(ev, os.getpid(), t.ident or 0, t.name))
+        p["procs"].setdefault(str(os.getpid()), "coordinator")
+
+
+def find(query_id: str, kind: str) -> list[dict]:
+    """A finished query's stored events of one kind (the statement
+    protocol reads its ``execute`` span for ``stats``)."""
+    with _PROFILES_LOCK:
+        p = _PROFILES.get(query_id)
+        return [e for e in p["events"] if e["kind"] == kind] \
+            if p is not None else []
+
+
+def events_since(t: float) -> list[dict]:
+    """Every event of this process that started at or after instant ``t``
+    (on ``now()``'s clock), whatever its query: the stored profiles and the
+    live rings together, each event once, oldest first.  For a reader that
+    lays the program's spans over a device trace of the same stretch."""
+    with _RINGS_LOCK:
+        rings = list(_RINGS)
+    pid = os.getpid()
+    out: dict = {}
+    with _PROFILES_LOCK:
+        for qid, p in _PROFILES.items():
+            for e in p["events"]:
+                if e["ts"] >= t and e.get("pid", pid) == pid:
+                    out[(e["tid"], e["ts"], e["kind"], e["name"])] = \
+                        e | {"query": qid}
+    for r in rings:
+        for ev in list(r.buf):
+            if ev[0] >= t:
+                out.setdefault((r.tid, ev[0], ev[2], ev[3]),
+                               _ev_dict(ev, pid, r.tid, r.tname)
+                               | {"query": ev[4]})
+    return sorted(out.values(), key=lambda e: e["ts"])
+
+
+def dropped_since(t: float) -> int:
+    """Events that started at or after ``t`` and were overwritten in their
+    ring before any harvest stored them (each ring remembers the start
+    times of its newest ``_DROPPED_KEPT`` overwritten events; a long-lived
+    thread's ring wraps all the time, and what it overwrites is old)."""
+    with _RINGS_LOCK:
+        rings = list(_RINGS)
+    lost = [(r.tid, ts) for r in rings for ts in list(r.dropped_ts)
+            if ts >= t]
+    if not lost:
+        return 0
+    with _PROFILES_LOCK:
+        kept = {(e["tid"], e["ts"]) for p in _PROFILES.values()
+                for e in p["events"] if e["ts"] >= t}
+    return sum(1 for key in lost if key not in kept)
 
 
 def chrome_trace(query_id: str) -> Optional[dict]:

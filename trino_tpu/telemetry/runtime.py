@@ -169,6 +169,16 @@ def queries() -> list:
         return list(_QUERIES)
 
 
+def find_query(query_id: str) -> Optional[QueryRecord]:
+    """The newest record under ``query_id`` (the statement protocol's
+    ``stats``), or None."""
+    with _LOCK:
+        for rec in reversed(_QUERIES):
+            if rec.query_id == query_id:
+                return rec
+    return None
+
+
 def tasks() -> list:
     with _LOCK:
         return list(_TASKS)

@@ -54,6 +54,17 @@ def collect_encoding_stats(pipelines: Sequence[Sequence[Operator]]
     return total
 
 
+def _take_trace_attrs(op: Operator) -> dict:
+    """What ``op``'s last add_input / finish_input decided (today: the
+    aggregation's reduction path and compaction), moved onto the recorder's
+    event of that call so it is reported once."""
+    attrs = op.trace_attrs
+    if attrs is None:
+        return {}
+    op.trace_attrs = None
+    return attrs
+
+
 class Driver:
     def __init__(self, operators: Sequence[Operator],
                  stats: Optional[PipelineStats] = None):
@@ -141,7 +152,8 @@ class Driver:
                             st[i + 1].wall_s += t1 - t0
                         if prof:
                             profiler.event(profiler.OPERATOR, names[i + 1],
-                                           t0, t1, rows=page.num_rows)
+                                           t0, t1, rows=page.num_rows,
+                                           **_take_trace_attrs(nxt))
                         self._emit(i, page)
                         progressed = True
                 if cur.is_finished() and not nxt.input_done:
@@ -165,7 +177,8 @@ class Driver:
                         # finish is where blocking operators (agg flush,
                         # sort, join build seal) do their heavy lifting
                         profiler.event(profiler.OPERATOR,
-                                       names[i + 1] + ".finish", t0, t1)
+                                       names[i + 1] + ".finish", t0, t1,
+                                       **_take_trace_attrs(nxt))
                     progressed = True
             if ops[-1].is_finished():
                 break

@@ -397,6 +397,12 @@ def _small_agg_fn(spec: tuple, num_keys: int, has_valid: tuple,
     rows on v5e vs ~500ms per column for argsort+gather+cumsum — random
     gathers are the TPU's weak point, dense reductions its strength).
 
+    Takes UNCOMPACTED input by design: ``live`` folds into the fused group
+    id, so a dead lane costs one compare per group and nothing else, and the
+    aggregation operator hands its concatenated, padded, sparsely-live
+    bucket straight in (no count sync, no kernels.compact) while
+    groups x reductions stays under its measured crossover.
+
     spec: (fn, data_idx, valid_idx, dtype_str, pre) per aggregate over the
     deduped flat operand list; num_keys may be 0 (global aggregate, one
     group).  Float sums need no NaN/Inf rescue here: a NaN only ever lands
@@ -1558,7 +1564,12 @@ def _device_domain(data, valid, live, dict_len: int):
 @jit_memo("kernels._compact_fn")
 def _compact_fn(n_cols: int, valid_flags: tuple, has_live_out: bool, cap: int):
     """Gather live rows to the front and slice to ``cap`` lanes (one stable
-    bool sort + gathers, all on device)."""
+    bool sort + gathers, all on device).  The sort is O(lanes log lanes) and
+    each gather costs about what a pass over the lanes does, so only callers
+    whose next step is at least a sort of the same lanes come here:
+    operators._maybe_compact_device, for SortOperator and the aggregation's
+    sorting paths.  An O(lanes) reduction is cheaper over the dead lanes
+    than this program is (190-230 ms for 2^25 lanes on a v5e, PERF.md)."""
 
     @program("kernels.compact")
     def fn(live, *flat):
@@ -1574,9 +1585,12 @@ def _compact_fn(n_cols: int, valid_flags: tuple, has_live_out: bool, cap: int):
 def compact_device_batch(batch, live_count: int):
     """Compact a live-masked device batch down to bucket(live_count) lanes.
     Dead lanes beyond the bucket are dropped; the (padded) tail keeps a live
-    mask.  Used by blocking operators whose cost is O(lanes log lanes): a
-    join output riding a fat probe shape with few survivors would otherwise
-    drag its dead lanes through every downstream sort."""
+    mask.  Called by operators._maybe_compact_device alone, on behalf of
+    blocking operators about to do O(lanes log lanes) work (SortOperator,
+    the aggregation's group_ids_codes / group_ids_auto / global-DISTINCT
+    paths): a filter or join output riding a fat static shape with few
+    survivors would otherwise drag its dead lanes through that sort.  Not
+    for the masked aggregation, which reads dead lanes for less."""
     from ..spi.batch import Column, ColumnBatch
 
     cap = bucket(max(live_count, 1))

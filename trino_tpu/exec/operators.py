@@ -84,6 +84,9 @@ class Operator:
 
     input_done: bool = False
     _closed: bool = False
+    # what the last add_input / finish_input decided, for the flight
+    # recorder: the driver moves it onto that call's ``operator`` event
+    trace_attrs: Optional[dict] = None
 
     def needs_input(self) -> bool:
         return not self.input_done and not self._closed
@@ -750,6 +753,12 @@ class RenameOperator(Operator):
 
 _COMPACT_FACTOR = 4  # compact when live rows < lanes/4
 _COMPACT_MIN_LANES = 1 << 16  # below this a count sync costs more than it saves
+# the two costs _masked_reads_dead_lanes_cheaper weighs, as read on a v5e by
+# tools/compaction_crossover.py (PERF.md section 6, PR 27; wall seconds)
+_COUNT_SYNC_S = 1.0e-3                # the blocking exec.compact-count fetch
+_COMPACT_S_PER_LANE = 3.6e-9          # kernels.compact: stable sort + gathers
+_MASKED_S_PER_LANE_REDUCTION = 150e-12       # small_agg: one more state column
+_MASKED_S_PER_LANE_GROUP_REDUCTION = 2.7e-12  # ... in one more group
 
 
 def _sync_free() -> bool:
@@ -761,20 +770,51 @@ def _sync_free() -> bool:
     return os.environ.get("TRINO_TPU_SYNC_FREE", "1") != "0"
 
 
-def _maybe_compact_device(batch: ColumnBatch) -> ColumnBatch:
-    """Shrink a sparsely-live device batch to bucket(live) lanes before
-    O(lanes log lanes) work.  A selective join keeps its probe batch's fat
-    static shape (the sync-free contract of join_exec.run_unique); paying ONE
-    live-count sync here stops those dead lanes from riding through every
-    downstream sort.  Host batches and dense batches pass through."""
+def _compaction_candidate(batch: ColumnBatch) -> bool:
+    """A device batch with a live mask and enough lanes that a count sync
+    can pay for itself.  Static: reads no device value."""
     live = batch.live
-    if live is None or isinstance(live, np.ndarray):
+    return (live is not None and not isinstance(live, np.ndarray)
+            and batch.num_rows >= _COMPACT_MIN_LANES)
+
+
+def _masked_reads_dead_lanes_cheaper(lanes: int, space: int,
+                                     reductions: int) -> bool:
+    """Should the masked aggregation take ``lanes`` uncompacted lanes?  It
+    costs O(lanes x group space x reductions) (kernels.small_agg vmaps its
+    masked reductions over the group space) and ignores dead lanes by
+    construction; compacting first costs a count sync, one stable sort of
+    the lanes and a gather per column, and can shrink the reduction's input
+    at best to nothing.  So: whichever of the two is cheaper over all the
+    lanes, from the measured unit costs above.  On a v5e the masked path
+    wins up to about 1000 groups x reductions at 2^20 to 2^22 lanes, and
+    further on both sides of that (the sync dominates below, the sort grows
+    faster than its lanes above).  Static and host-side: no sync, no look
+    at the data."""
+    masked_s = lanes * reductions * (
+        _MASKED_S_PER_LANE_REDUCTION
+        + space * _MASKED_S_PER_LANE_GROUP_REDUCTION)
+    return masked_s <= _COUNT_SYNC_S + lanes * _COMPACT_S_PER_LANE
+
+
+def _maybe_compact_device(batch: ColumnBatch) -> ColumnBatch:
+    """Shrink a sparsely-live device batch to bucket(live) lanes, for a
+    caller whose next step is super-linear in lanes: SortOperator's device
+    sort, and the aggregation's sorting reductions (group_ids_codes,
+    group_ids_auto, the global DISTINCT route through grouped_reduce) --
+    HashAggregationOperator._compute asks AFTER it has chosen its path.  A
+    selective filter or join keeps its batch's fat static shape (the
+    sync-free contract of join_exec.run_unique); paying ONE live-count sync
+    here stops those dead lanes from riding through the sort.  A reduction
+    that is O(lanes) does not call this: the masked aggregation reads dead
+    lanes for less than the sort that would remove them (see
+    _masked_reads_dead_lanes_cheaper).  Host batches, batches under
+    _COMPACT_MIN_LANES and dense batches come back as they are (``is``)."""
+    if not _compaction_candidate(batch):
         return batch
-    n = batch.num_rows
-    if n < _COMPACT_MIN_LANES:
-        return batch
-    count = int(SG.fetch(jnp.sum(jnp.asarray(live)), "exec.compact-count"))
-    if count * _COMPACT_FACTOR <= n:
+    count = int(SG.fetch(jnp.sum(jnp.asarray(batch.live)),
+                         "exec.compact-count"))
+    if count * _COMPACT_FACTOR <= batch.num_rows:
         return K.compact_device_batch(batch, count)
     return batch
 
@@ -1082,6 +1122,25 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
                 self._buffered_rows = 0
             self.account_memory()
 
+    def _reduction_count(self, inp: ColumnBatch) -> int:
+        """State columns _compute will ask the reduction kernel for, from
+        the aggregate list and the input's types alone: avg is (sum,
+        count), the variance family (sum, sum of squares, count), a
+        long-decimal sum or avg six limb sums and a count."""
+        total = 0
+        for a in self.aggs:
+            t = inp.columns[a.arg].type if a.arg >= 0 else None
+            if (a.fn in ("sum", "avg") and isinstance(t, DecimalType)
+                    and t.precision > 18):
+                total += 7
+            elif a.fn == "avg":
+                total += 2
+            elif a.fn in STAT_AGGS:
+                total += 3
+            else:
+                total += 1
+        return total
+
     def _agg_spec(self, a: AggCall, inp: ColumnBatch, out_t: Type):
         """kernel (fn, data, valid, dtype, distinct) for one AggCall."""
         if a.fn == "count" and a.arg < 0:
@@ -1252,15 +1311,14 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
             fast = self._rle_fast_path()
             if fast is not None:
                 return fast
-        inp = _maybe_compact_device(_concat_device(self._batches))
-        live = inp.live  # None = all rows real
-        n = inp.num_rows
+        inp = _concat_device(self._batches)
 
-        presence = None
         # masked-reduction fast path: small dictionary-code group space and
         # no DISTINCT -> no sort, no gather, no num_groups sync (kernels.
         # small_grouped_aggregate); live folds via the fused gid, so specs
-        # skip the fold_live below
+        # skip the fold_live below.  Everything read here is static (key
+        # dictionaries, validity, the aggregate list): compaction changes
+        # none of it, so the path is chosen BEFORE anyone pays for one.
         key_cols = [inp.columns[i] for i in self.group_keys]
         space = K.small_codes_group_space(key_cols) if nk else 1
         if nk and space is not None:
@@ -1269,8 +1327,31 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
             self.encoding_stats.code_group_batches += 1
         use_masked = (space is not None and space <= K.MASKED_AGG_LIMIT
                       and not any(a.distinct for a in self.aggs)
-                      and (nk or live is not None
+                      and (nk or inp.live is not None
                            or any(a.arg >= 0 for a in self.aggs)))
+        path = ("masked" if use_masked
+                else "codes-sort" if nk and space is not None else "sort")
+        # compaction is a cost of the paths that sort: argsort, lexsort /
+        # hash, grouped_reduce.  The masked path is O(lanes) and ignores
+        # dead lanes by construction; it takes them as they come -- no
+        # count sync, no sort, no gather -- unless groups x reductions is
+        # so large that sorting them away first is the cheaper way round
+        skip = (use_masked and _compaction_candidate(inp)
+                and _masked_reads_dead_lanes_cheaper(
+                    inp.num_rows, space, self._reduction_count(inp)))
+        compaction = "skipped" if skip else "none"
+        if not skip:
+            padded, inp = inp, _maybe_compact_device(inp)
+            if inp is not padded:
+                compaction = "compacted"
+                key_cols = [inp.columns[i] for i in self.group_keys]
+        self.encoding_stats.count_aggregation(path, compaction)
+        self.trace_attrs = {"path": path, "compaction": compaction,
+                            "lanes": inp.num_rows}
+        live = inp.live  # None = all rows real
+        n = inp.num_rows
+
+        presence = None
         if nk and not use_masked:
             keys = [(c.data, c.valid) for c in key_cols]
             if space is not None:

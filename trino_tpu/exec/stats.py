@@ -312,8 +312,10 @@ class EncodingStats:
     """Counters for compressed execution (TRINO_TPU_ENCODED_EXEC): batches
     by encoding, bytes saved vs a flat representation, lazy columns that
     were filtered away before their thunk ever ran, and dictionary codes
-    surviving exchanges.  One instance per encoding-aware operator;
-    ``merge`` folds them into the query-level roll-up."""
+    surviving exchanges; and, per aggregation computed, the reduction path
+    it took and what became of compaction in front of it.  One instance
+    per encoding-aware operator; ``merge`` folds them into the query-level
+    roll-up."""
 
     rle_batches: int = 0        # batches carrying >=1 RLE column
     dict_batches: int = 0       # batches carrying >=1 dictionary column
@@ -325,6 +327,26 @@ class EncodingStats:
     code_group_batches: int = 0  # group-bys that ran on int32 codes
     code_join_batches: int = 0   # joins probed in code space
     exchange_code_pages: int = 0  # pages whose codes crossed a shuffle
+    # HashAggregationOperator._compute, once per flush / finish / merge:
+    agg_masked: int = 0      # O(lanes) masked reductions (kernels.small_agg)
+    agg_codes_sort: int = 0  # argsort of fused dictionary codes
+    agg_sort: int = 0        # lexsort / hash group ids, or global DISTINCT
+    agg_compacted: int = 0   # a sorting path compacted its sparse input
+    agg_compaction_skipped: int = 0  # masked path took the dead lanes as-is
+
+    def count_aggregation(self, path: str, compaction: str) -> None:
+        """path: masked | codes-sort | sort; compaction: compacted |
+        skipped | none (not a candidate, or counted and found dense)."""
+        if path == "masked":
+            self.agg_masked += 1
+        elif path == "codes-sort":
+            self.agg_codes_sort += 1
+        else:
+            self.agg_sort += 1
+        if compaction == "compacted":
+            self.agg_compacted += 1
+        elif compaction == "skipped":
+            self.agg_compaction_skipped += 1
 
     def merge(self, other: "EncodingStats") -> None:
         self.rle_batches += other.rle_batches
@@ -337,13 +359,19 @@ class EncodingStats:
         self.code_group_batches += other.code_group_batches
         self.code_join_batches += other.code_join_batches
         self.exchange_code_pages += other.exchange_code_pages
+        self.agg_masked += other.agg_masked
+        self.agg_codes_sort += other.agg_codes_sort
+        self.agg_sort += other.agg_sort
+        self.agg_compacted += other.agg_compacted
+        self.agg_compaction_skipped += other.agg_compaction_skipped
 
     @property
     def any(self) -> bool:
         return any((self.rle_batches, self.dict_batches, self.lazy_columns,
                     self.bytes_saved, self.lazy_skipped_bytes,
                     self.rle_agg_rows, self.code_group_batches,
-                    self.code_join_batches, self.exchange_code_pages))
+                    self.code_join_batches, self.exchange_code_pages,
+                    self.agg_masked, self.agg_codes_sort, self.agg_sort))
 
     def text(self) -> str:
         never = self.lazy_columns - self.lazy_materialized
@@ -356,7 +384,11 @@ class EncodingStats:
             f"{self.rle_agg_rows} RLE-agg rows, "
             f"{self.code_group_batches} code group-bys / "
             f"{self.code_join_batches} code joins, "
-            f"{self.exchange_code_pages} code pages through exchange"
+            f"{self.exchange_code_pages} code pages through exchange; "
+            f"aggregations: {self.agg_masked} masked / "
+            f"{self.agg_codes_sort} codes-sort / {self.agg_sort} sort, "
+            f"{self.agg_compacted} compacted, "
+            f"{self.agg_compaction_skipped} compaction skipped"
         )
 
 

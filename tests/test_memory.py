@@ -125,11 +125,20 @@ def test_query_larger_than_pool_completes():
         "order by l_orderkey limit 5").rows()
 
 
-def test_partitioned_state_spill_agg():
-    """Q1-style aggregation at a forced tiny disk budget: the operator
-    pre-aggregates to mergeable states, hash-partitions them to spill
-    files, and merges partition-by-partition at finish — results exact,
-    spill_count > 0 (reference: SpillableHashAggregationBuilder.java)."""
+@pytest.mark.parametrize("keys, spills_states", [
+    # the Q1 shape (6 groups) streams into a running masked-aggregation
+    # state since PR 30: nothing is buffered, so there is nothing to spill
+    ("l_returnflag, l_linestatus", False),
+    # 3 x 2 x 7 x 4 = 168 groups, over MASKED_AGG_LIMIT: the codes-sort
+    # path buffers its input, and the 1-byte budget spills its states
+    ("l_returnflag, l_linestatus, l_shipmode, l_shipinstruct", True),
+])
+def test_partitioned_state_spill_agg(keys, spills_states):
+    """Q1-style aggregation at a forced tiny disk budget: an operator that
+    buffers pre-aggregates to mergeable states, hash-partitions them to
+    spill files, and merges partition-by-partition at finish — results
+    exact, spill_count > 0 (reference: SpillableHashAggregationBuilder
+    .java); one that streams holds only its state and never spills."""
     import trino_tpu.exec.operators as OPS
     from trino_tpu.connectors.catalog import default_catalog
     from trino_tpu.runner import StandaloneQueryRunner
@@ -146,16 +155,20 @@ def test_partitioned_state_spill_agg():
     runner = StandaloneQueryRunner(default_catalog(scale_factor=0.05),
                                    session=session)
     baseline = StandaloneQueryRunner(default_catalog(scale_factor=0.05))
-    sql = ("select l_returnflag, l_linestatus, sum(l_quantity), "
+    nk = len(keys.split(","))
+    sql = (f"select {keys}, sum(l_quantity), "
            "avg(l_extendedprice), count(*), min(l_discount), "
-           "max(l_shipdate) from lineitem "
-           "group by l_returnflag, l_linestatus order by 1, 2")
+           f"max(l_shipdate) from lineitem group by {keys} "
+           f"order by {', '.join(str(i + 1) for i in range(nk))}")
     OPS.HashAggregationOperator._spill_states = spy
     try:
         got = runner.execute(sql).rows()
     finally:
         OPS.HashAggregationOperator._spill_states = orig
-    assert spills, "agg never spilled despite the 1-byte budget"
+    if spills_states:
+        assert spills, "agg never spilled despite the 1-byte budget"
+    else:
+        assert not spills, "a streaming aggregation buffered its input"
     want = baseline.execute(sql).rows()
     assert_same_rows(got, want, ordered=True)
 

@@ -185,3 +185,28 @@ def test_static_grouped_agg_as_the_fused_stage_calls_it(
     # the selection the chip makes: the sort route, no pallas call inside
     assert "tpu_custom_call" not in lowered.as_text()
     lowered.compile()
+
+
+@pytest.mark.parametrize("rows", BUCKETS)
+def test_masked_aggregation_fold_with_a_donated_state(shape, rows):
+    """kernels.small_agg_fold as a streaming Q1 PARTIAL task calls it: two
+    dictionary keys (3 x 2 codes), the batch's live mask, DECIMAL sums as
+    int64, an avg's scale-free f64 sum and count, count(*); the state is
+    donated, so the [6]-lane state columns (stacked by dtype) alias in and
+    out."""
+    spec = (("sum", 0, -1, "<i8", None), ("sum", 1, -1, "<i8", None),
+            ("sum", 0, -1, "<f8", ("scale", 2)), ("count", 0, -1, "<i8", None),
+            ("min", 2, -1, "<i4", None), ("count_star", -1, -1, "<i8", None))
+    layout = K.small_agg_state_layout(spec)
+    state = tuple(shape(dims, np.dtype(d))
+                  for dims, d in K.small_agg_state_shapes(layout, 6))
+    assert len(state) == 3                    # int64, float64, int32 stacks
+    compiled = K._small_agg_fold_fn(
+        spec, 2, (False, False), True, (3, 2), True).lower(
+        state, shape(rows, jnp.int32), shape(rows, jnp.int32),
+        shape(rows, jnp.bool_), shape(rows, jnp.int64),
+        shape(rows, jnp.int64), shape(rows, jnp.int32)).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes > 0
+    K._small_agg_zero_fn(layout, 6, True).lower().compile()
+    K._small_agg_state_out_fn(spec, (3, 2),
+                              (False, False)).lower(*state).compile()

@@ -668,15 +668,6 @@ def _dict_token(d):
     return tok
 
 
-def _donate_ok() -> bool:
-    """Buffer donation saves HBM on real accelerators; the CPU backend warns
-    about unusable donations, so only donate off-CPU."""
-    try:
-        return jax.default_backend() != "cpu"
-    except Exception:
-        return False
-
-
 def _make_pair_fn(cap: int, num_keys: int, has_pvalid: tuple,
                   has_remap: tuple, pair_types, pair_dicts,
                   n_probe_cols: int, n_build_cols: int,
@@ -829,7 +820,7 @@ def run_pairs(table: DeviceJoinTable, lo, counts, total,
     follow (the provable-cap path)."""
     if cap is None:
         cap = K.bucket(max(int(total), 1))
-    donate = donate and _donate_ok()
+    donate = donate and K.donate_ok()
     has_pvalid = tuple(v is not None for _, v in probe_keys)
     has_remap = tuple(r is not None for r in remaps)
     pcol_has_valid = tuple(v is not None for _, v in probe_cols)

@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import os
 from ..caching.executable_cache import jit_memo, program
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -388,6 +388,165 @@ def _decode_codes(r, sizes, slots, strides, has_valid):
     return keys_out
 
 
+def _small_agg_reduce(spec: tuple, num_keys: int, has_valid: tuple,
+                      has_live: bool, sizes: tuple, flat):
+    """Traced: the masked reduction itself, shared by kernels.small_agg, the
+    per-batch fold and an operator program that filters and folds in one
+    launch.  ``flat`` is key codes (+ validity), the live mask when
+    ``has_live``, then the deduped aggregate operands.  Returns one
+    ``[group space]`` array per state column, in small_agg_state_layout's
+    order: rows-per-group, then per spec entry the value and, for every
+    aggregate that can be NULL, the count of rows that contributed."""
+    slots, strides, total = _code_layout(sizes, has_valid)
+    i = 0
+    codes, valids = [], []
+    for k in range(num_keys):
+        codes.append(flat[i])
+        i += 1
+        if has_valid[k]:
+            valids.append(flat[i])
+            i += 1
+        else:
+            valids.append(None)
+    live = flat[i] if has_live else None
+    i += 1 if has_live else 0
+    aggs_flat = flat[i:]
+    if num_keys:
+        fused = _fuse_codes(codes, valids, live, sizes, strides, total)
+    else:
+        shape_src = live if live is not None else aggs_flat[0]
+        fused = jnp.zeros(shape_src.shape, jnp.int32)
+        if live is not None:
+            fused = jnp.where(live, fused, total)
+
+    # a batch's lanes fit 31 bits: count in the chip's native width and
+    # widen the [group space] result (64-bit lanes are emulated)
+    count_dtype = jnp.int32 if fused.shape[0] < (1 << 31) else jnp.int64
+
+    def count(mask):
+        return jnp.sum(mask, dtype=count_dtype).astype(jnp.int64)
+
+    def one_group(g):
+        m = fused == g
+        outs = []
+        outs.append(count(m))  # rows-per-group
+        for fname, data_idx, valid_idx, dtype_str, pre in spec:
+            dtype = jnp.dtype(dtype_str)
+            if fname == "count_star":
+                outs.append(count(m))
+                continue
+            x = aggs_flat[data_idx]
+            if pre is not None:
+                if pre[0] == "scale":
+                    x = x.astype(jnp.float64) / (10.0 ** pre[1])
+                elif pre[0] == "square":
+                    x64 = x.astype(jnp.float64)
+                    x = x64 * x64
+            v = aggs_flat[valid_idx] if valid_idx >= 0 else None
+            mv = m if v is None else (m & v)
+            if fname == "count":
+                outs.append(count(mv))
+            elif fname == "sum":
+                outs.append(jnp.sum(
+                    jnp.where(mv, x.astype(dtype), jnp.zeros((), dtype))))
+                outs.append(count(mv))  # any-valid
+            elif fname in ("min", "max", "any_value"):
+                is_min = fname != "max"  # any_value: min is as good as any
+                sent = _sentinel("min" if is_min else "max", x.dtype)
+                masked = jnp.where(mv, x, sent)
+                outs.append(jnp.min(masked) if is_min else jnp.max(masked))
+                outs.append(count(mv))
+            else:
+                raise NotImplementedError(f"masked aggregate {fname}")
+        return tuple(outs)
+
+    return jax.vmap(one_group)(jnp.arange(total, dtype=jnp.int32))
+
+
+def small_agg_state_layout(spec: tuple) -> tuple:
+    """(merge, dtype) per state column _small_agg_reduce returns for
+    ``spec``: how two states of disjoint row sets combine (``add``, ``min``
+    or ``max``) and the column's dtype.  The state of a streaming masked
+    aggregation is exactly these columns (held stacked by dtype:
+    small_agg_state_shapes), so finalization reads a merged state the way
+    it reads one reduction."""
+    layout = [("add", "<i8")]  # rows-per-group
+    for fname, _data_idx, _valid_idx, dtype_str, _pre in spec:
+        if fname in ("count", "count_star"):
+            layout.append(("add", "<i8"))
+            continue
+        merge = "add" if fname == "sum" else (
+            "max" if fname == "max" else "min")
+        layout.append((merge, np.dtype(dtype_str).str))
+        layout.append(("add", "<i8"))  # any-valid count
+    return tuple(layout)
+
+
+def small_agg_state_shapes(layout: tuple, total: int) -> tuple:
+    """((rows, total), dtype) of the arrays a state of ``layout`` is held
+    in: its columns stacked by dtype, in order of first appearance.  A
+    launch pays for every buffer it takes and returns (on a v5e some tenths
+    of a millisecond each when freshly allocated: PERF.md section 6,
+    PR 30), so twenty [6]-lane columns travel as two or three arrays."""
+    rows: dict = {}
+    for _merge, dtype_str in layout:
+        rows[dtype_str] = rows.get(dtype_str, 0) + 1
+    return tuple(((n, total), d) for d, n in rows.items())
+
+
+def _pack_state(layout: tuple, cols) -> tuple:
+    """Traced: state columns -> the stacked arrays (small_agg_state_shapes'
+    order)."""
+    by_dtype: dict = {}
+    for (_merge, dtype_str), c in zip(layout, cols, strict=True):
+        if c.dtype != jnp.dtype(dtype_str):
+            raise TrinoError(
+                GENERIC_INTERNAL_ERROR,
+                f"masked aggregation state column is {dtype_str}, its "
+                f"reduction came out {c.dtype}")
+        by_dtype.setdefault(dtype_str, []).append(c)
+    return tuple(jnp.stack(cs) for cs in by_dtype.values())
+
+
+def _unpack_state(layout: tuple, packed) -> list:
+    """Traced: the stacked arrays -> state columns in ``layout``'s order."""
+    rows = {d: iter(stack) for d, stack in zip(
+        dict.fromkeys(d for _, d in layout), packed, strict=True)}
+    return [next(rows[d]) for _, d in layout]
+
+
+def _merge_state(layout: tuple, state, cols) -> tuple:
+    """Traced: fold one reduction's columns into the running (packed)
+    state."""
+    return _pack_state(layout, [
+        old + new if merge == "add"
+        else jnp.minimum(old, new) if merge == "min"
+        else jnp.maximum(old, new)
+        for (merge, _), old, new in zip(layout, _unpack_state(layout, state),
+                                        cols, strict=True)])
+
+
+def _small_agg_results(spec: tuple, cols, sizes: tuple, has_valid: tuple):
+    """Traced: (results, presence, keys_out) from the state columns."""
+    slots, strides, total = _code_layout(sizes, has_valid)
+    presence = cols[0] > 0
+    results = []
+    ci = 1
+    for fname, _data_idx, _valid_idx, _dtype_str, _pre in spec:
+        if fname in ("count", "count_star"):
+            results.append((cols[ci], None))
+            ci += 1
+        else:
+            # the any-contributor flag applies even without a column
+            # validity mask: an empty (or fully dead) group's
+            # sum/min/max is NULL, not the fill value
+            results.append((cols[ci], cols[ci + 1] > 0))
+            ci += 2
+    keys_out = _decode_codes(jnp.arange(total, dtype=jnp.int32),
+                             sizes, slots, strides, has_valid)
+    return results, presence, keys_out
+
+
 @jit_memo("kernels._small_agg_fn")
 def _small_agg_fn(spec: tuple, num_keys: int, has_valid: tuple,
                   has_live: bool, sizes: tuple):
@@ -407,102 +566,61 @@ def _small_agg_fn(spec: tuple, num_keys: int, has_valid: tuple,
     deduped flat operand list; num_keys may be 0 (global aggregate, one
     group).  Float sums need no NaN/Inf rescue here: a NaN only ever lands
     in its own group's reduction (IEEE semantics are exactly SQL's)."""
-    slots, strides, total = _code_layout(sizes, has_valid)
 
     @program("kernels.small_agg")
     def fn(*flat):
-        i = 0
-        codes, valids = [], []
-        for k in range(num_keys):
-            codes.append(flat[i])
-            i += 1
-            if has_valid[k]:
-                valids.append(flat[i])
-                i += 1
-            else:
-                valids.append(None)
-        live = flat[i] if has_live else None
-        i += 1 if has_live else 0
-        aggs_flat = flat[i:]
-        if num_keys:
-            fused = _fuse_codes(codes, valids, live, sizes, strides, total)
-        else:
-            shape_src = live if live is not None else aggs_flat[0]
-            fused = jnp.zeros(shape_src.shape, jnp.int32)
-            if live is not None:
-                fused = jnp.where(live, fused, total)
-
-        def one_group(g):
-            m = fused == g
-            outs = []
-            outs.append(jnp.sum(m))  # rows-per-group (presence)
-            for fname, data_idx, valid_idx, dtype_str, pre in spec:
-                dtype = jnp.dtype(dtype_str)
-                if fname == "count_star":
-                    outs.append(jnp.sum(m).astype(jnp.int64))
-                    continue
-                x = aggs_flat[data_idx]
-                if pre is not None:
-                    if pre[0] == "scale":
-                        x = x.astype(jnp.float64) / (10.0 ** pre[1])
-                    elif pre[0] == "square":
-                        x64 = x.astype(jnp.float64)
-                        x = x64 * x64
-                v = aggs_flat[valid_idx] if valid_idx >= 0 else None
-                mv = m if v is None else (m & v)
-                if fname == "count":
-                    outs.append(jnp.sum(mv).astype(jnp.int64))
-                elif fname == "sum":
-                    outs.append(jnp.sum(
-                        jnp.where(mv, x.astype(dtype), jnp.zeros((), dtype))))
-                    outs.append(jnp.sum(mv))  # any-valid flag
-                elif fname in ("min", "max", "any_value"):
-                    is_min = fname != "max"  # any_value: min is as good as any
-                    sent = _sentinel("min" if is_min else "max", x.dtype)
-                    masked = jnp.where(mv, x, sent)
-                    outs.append(jnp.min(masked) if is_min else jnp.max(masked))
-                    outs.append(jnp.sum(mv))
-                else:
-                    raise NotImplementedError(f"masked aggregate {fname}")
-            return tuple(outs)
-
-        cols = jax.vmap(one_group)(jnp.arange(total, dtype=jnp.int32))
-        rows_per_group = cols[0]
-        presence = rows_per_group > 0
-        results = []
-        ci = 1
-        for fname, data_idx, valid_idx, dtype_str, pre in spec:
-            if fname in ("count", "count_star"):
-                results.append((cols[ci], None))
-                ci += 1
-            else:
-                # the any-contributor flag applies even without a column
-                # validity mask: an empty (or fully dead) group's
-                # sum/min/max is NULL, not the fill value
-                results.append((cols[ci], cols[ci + 1] > 0))
-                ci += 2
-        keys_out = _decode_codes(jnp.arange(total, dtype=jnp.int32),
-                                 sizes, slots, strides, has_valid)
-        return results, presence, keys_out
+        cols = _small_agg_reduce(spec, num_keys, has_valid, has_live,
+                                 sizes, flat)
+        return _small_agg_results(spec, cols, sizes, has_valid)
 
     return fn
 
 
-def small_grouped_aggregate(key_cols, live, aggs: Sequence[tuple]):
-    """aggs: [(fn, data|None, valid|None, out_dtype, distinct[, pre]), ...]
-    (same shape as grouped_reduce's input; distinct unsupported — caller
-    falls back).  Returns (results, presence|None, keys_out, num_groups):
-    ONE program, zero host syncs, static group count."""
-    num_keys = len(key_cols)
-    has_valid = tuple(c.valid is not None for c in key_cols)
-    sizes = tuple(len(c.dictionary) for c in key_cols)
+class MaskedOperands(NamedTuple):
+    """What one masked reduction reads, in the shape its programs key on:
+    the static ``spec`` / key layout, and the ``flat`` operand list (key
+    codes and validity, the live mask when ``has_live``, then the deduped
+    aggregate columns).  ``flat`` holds whatever the caller's columns held:
+    arrays, tracers, or shapes when only the layout is asked for."""
+
+    spec: tuple
+    num_keys: int
+    has_valid: tuple
+    has_live: bool
+    sizes: tuple
+    flat: list
+
+    @property
+    def static(self) -> tuple:
+        return self[:5]
+
+    @property
+    def layout(self) -> tuple:
+        return small_agg_state_layout(self.spec)
+
+    @property
+    def space(self) -> int:
+        return _code_layout(self.sizes, self.has_valid)[2]
+
+    @property
+    def state_shapes(self) -> tuple:
+        return small_agg_state_shapes(self.layout, self.space)
+
+
+def small_agg_operands(key_cols, live, aggs: Sequence[tuple]
+                       ) -> MaskedOperands:
+    """Operands of the masked programs from key columns, a live mask and
+    aggs [(fn, data|None, valid|None, out_dtype, distinct[, pre]), ...]
+    (grouped_reduce's input shape; distinct unsupported -- the caller falls
+    back).  Operands are DEDUPED by object identity, so aggregates over the
+    same column or mask share one."""
     flat: list = []
     for c in key_cols:
-        flat.append(jnp.asarray(c.data))
+        flat.append(c.data)
         if c.valid is not None:
-            flat.append(jnp.asarray(c.valid))
+            flat.append(c.valid)
     if live is not None:
-        flat.append(jnp.asarray(live))
+        flat.append(live)
     base = len(flat)
     flat_ids: dict = {}
     spec = []
@@ -513,7 +631,7 @@ def small_grouped_aggregate(key_cols, live, aggs: Sequence[tuple]):
         k = id(arr)
         if k not in flat_ids:
             flat_ids[k] = len(flat) - base
-            flat.append(jnp.asarray(arr))
+            flat.append(arr)
         return flat_ids[k]
 
     for entry in aggs:
@@ -527,15 +645,107 @@ def small_grouped_aggregate(key_cols, live, aggs: Sequence[tuple]):
             continue
         spec.append((fn_name, idx_of(data), idx_of(valid),
                      np.dtype(dtype).str, pre))
-    results, presence, keys_out = _small_agg_fn(
-        tuple(spec), num_keys, has_valid, live is not None, sizes)(*flat)
-    total = 1
-    for s, hv in zip(sizes, has_valid):
-        total *= s + (1 if hv else 0)
-    if num_keys == 0:
+    return MaskedOperands(
+        tuple(spec), len(key_cols),
+        tuple(c.valid is not None for c in key_cols), live is not None,
+        tuple(len(c.dictionary) for c in key_cols), flat)
+
+
+def small_grouped_aggregate(key_cols, live, aggs: Sequence[tuple]):
+    """Returns (results, presence|None, keys_out, num_groups) for
+    small_agg_operands' arguments: ONE program, zero host syncs, static
+    group count."""
+    ops = small_agg_operands(key_cols, live, aggs)
+    results, presence, keys_out = _small_agg_fn(*ops.static)(*ops.flat)
+    if ops.num_keys == 0:
         presence = None  # a global aggregate always emits its one row
-        total = 1
-    return results, presence, keys_out, total
+    return results, presence, keys_out, ops.space
+
+
+def small_agg_fold_body(spec: tuple, num_keys: int, has_valid: tuple,
+                        has_live: bool, sizes: tuple):
+    """``(state, *flat) -> state``: reduce one batch and merge it into the
+    running state -- the traceable body of kernels.small_agg_fold, which an
+    operator's program may trace behind its own work instead."""
+    layout = small_agg_state_layout(spec)
+
+    def fold(state, *flat):
+        cols = _small_agg_reduce(spec, num_keys, has_valid, has_live,
+                                 sizes, flat)
+        return _merge_state(layout, state, cols)
+
+    return fold
+
+
+def donate_ok() -> bool:
+    """Buffer donation saves HBM (and a copy) on real accelerators; the CPU
+    backend warns about unusable donations, so only donate off-CPU."""
+    return jax.default_backend() != "cpu"
+
+
+@jit_memo("kernels._small_agg_fold_fn")
+def _small_agg_fold_fn(spec: tuple, num_keys: int, has_valid: tuple,
+                       has_live: bool, sizes: tuple, donate: bool):
+    """The per-batch program of a streaming masked aggregation that has no
+    filter/project to share a launch with: the state is donated, the batch
+    is read once, nothing is buffered, sorted or synced."""
+    return program("kernels.small_agg_fold",
+                   small_agg_fold_body(spec, num_keys, has_valid, has_live,
+                                       sizes),
+                   donate_argnums=(0,) if donate else ())
+
+
+def small_agg_fold(state: tuple, ops: MaskedOperands) -> tuple:
+    """Reduce ``ops`` and merge the result into ``state`` (donated): ONE
+    program, zero host syncs.  Returns the new state."""
+    return _small_agg_fold_fn(*ops.static, donate_ok())(state, *ops.flat)
+
+
+@jit_memo("kernels._small_agg_zero_fn")
+def _small_agg_zero_fn(layout: tuple, total: int, with_error: bool):
+    @program("kernels.small_agg_zero")
+    def fn():
+        state = _pack_state(layout, [
+            jnp.full((total,),
+                     0 if merge == "add" else _sentinel(merge, dtype_str),
+                     jnp.dtype(dtype_str))
+            for merge, dtype_str in layout])
+        return state + ((jnp.zeros((), jnp.int32),) if with_error else ())
+
+    return fn
+
+
+def small_agg_zero_state(ops: MaskedOperands, with_error: bool = False
+                         ) -> tuple:
+    """A fresh identity state for ``ops``' layout over its static group
+    space, in one small launch (the state is donated to the first fold, so
+    every stream needs buffers of its own): 0 under ``add``, the
+    reduction's own sentinel under ``min``/``max``; ``with_error`` appends
+    the int32 error scalar a fused filter/project carries along."""
+    return _small_agg_zero_fn(ops.layout, ops.space, with_error)()
+
+
+@jit_memo("kernels._small_agg_state_out_fn")
+def _small_agg_state_out_fn(spec: tuple, sizes: tuple, has_valid: tuple):
+    layout = small_agg_state_layout(spec)
+
+    @program("kernels.small_agg_state_out")
+    def fn(*state):
+        return _small_agg_results(spec, _unpack_state(layout, state), sizes,
+                                  has_valid)
+
+    return fn
+
+
+def small_agg_state_out(state: Sequence, ops: MaskedOperands):
+    """What small_grouped_aggregate returns, from the final state of a
+    stream over ``ops``' layout (one launch, once per stream)."""
+    results, presence, keys_out = _small_agg_state_out_fn(
+        tuple((s[0], -1, -1, s[3], None) for s in ops.spec), ops.sizes,
+        ops.has_valid)(*state)
+    if ops.num_keys == 0:
+        presence = None
+    return results, presence, keys_out, ops.space
 
 
 @jit_memo("kernels._group_ids_codes_fn")

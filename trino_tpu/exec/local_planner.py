@@ -51,6 +51,7 @@ from .operators import (
     UnionSourceOperator,
     ValuesOperator,
     WindowOperator,
+    plan_aggregation_feed,
     plan_lazy_scan,
 )
 
@@ -99,6 +100,7 @@ class LocalPlanner:
                 q for p in self.pipelines for q in self._parallelize(p)]
         for p in self.pipelines:
             plan_lazy_scan(p)
+            plan_aggregation_feed(p)
             for op in p:
                 if isinstance(op, BufferedInputMixin):
                     op.attach_memory(self.memory)

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import os
 import threading
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +59,7 @@ __all__ = [
     "UnionSourceOperator",
     "FilterProjectOperator",
     "plan_lazy_scan",
+    "plan_aggregation_feed",
     "HashAggregationOperator",
     "JoinBridge",
     "JoinBuildSink",
@@ -462,6 +463,46 @@ def _to_cols(batch: ColumnBatch):
     return [(c.data, c.valid) for c in batch.columns]
 
 
+class _FilterProjectProgram:
+    """One compiled filter+project: ``run`` is the named program, ``body``
+    the function it traces -- ``(cols, live) -> (outs, live, err_code)`` --
+    which a consumer that folds the outputs away (the streaming masked
+    aggregation) traces inside a program of its own instead, reading only
+    the ``needed`` input channels (None = all of them)."""
+
+    __slots__ = ("run", "projs", "body", "needed", "out_meta", "views",
+                 "consumers")
+
+    def __init__(self, run, projs, body, needed, out_meta):
+        self.run = run
+        self.projs = projs
+        self.body = body
+        self.needed = needed
+        self.out_meta = out_meta   # (type, dictionary) per output column
+        self.views: dict = {}      # input structure -> (shapes out, raises)
+        self.consumers: dict = {}  # consumer's static key -> its program
+
+    def view(self, sig: tuple, cols, live) -> tuple:
+        """(the body's output batch for inputs of structure ``sig`` as
+        shapes, whether the body can raise) -- abstractly (jax.eval_shape:
+        one trace, nothing runs).  The error scalar is None when nothing
+        that can raise was traced."""
+        hit = self.views.get(sig)
+        if hit is None:
+            outs, out_live, err = jax.eval_shape(self.body, cols, live)
+            hit = self.views[sig] = (self.output_batch(outs, out_live),
+                                     err is not None)
+        return hit
+
+    def output_batch(self, outs, live) -> ColumnBatch:
+        """The body's ``outs`` and ``live`` -- arrays, tracers or shapes --
+        as the batch the operator would have handed on."""
+        return ColumnBatch(
+            [str(i) for i in range(len(outs))],
+            [Column(t, d, v, dictionary)
+             for (d, v), (t, dictionary) in zip(outs, self.out_meta)], live)
+
+
 class FilterProjectOperator(Operator):
     """Fused filter+project compiled to ONE jitted XLA program per
     (expression set, shape bucket): the predicate ANDs into the batch's
@@ -493,6 +534,10 @@ class FilterProjectOperator(Operator):
         # error-capable op (division, overflow...); drained by the runner
         self.pending_errors: list = []
         self.encoding_stats = EncodingStats()
+        # the aggregation directly behind this operator, when local planning
+        # found one that may fold this operator's work into its own
+        # per-batch program (plan_aggregation_feed)
+        self.consumer: Optional["HashAggregationOperator"] = None
 
     def _compile(self, batch: ColumnBatch):
         dicts = [c.dictionary for c in batch.columns]
@@ -535,7 +580,7 @@ class FilterProjectOperator(Operator):
                 reduce_error_lanes,
             )
 
-            n = cols[0][0].shape[0]
+            n = next(d for d, _ in cols if d is not None).shape[0]
             with expr_error_scope() as errs:
                 if pred is not None:
                     with expr_condition_mask(live):
@@ -568,7 +613,18 @@ class FilterProjectOperator(Operator):
             err_code = None if err is None else jnp.max(err)
             return outs, live, err_code
 
-        self._compiled = (program("operators.filter_project", run), projs)
+        needed = None
+        if self.projections is not None:
+            needed = frozenset(
+                referenced_inputs(self.predicate)
+                if self.predicate is not None else ()).union(
+                *(referenced_inputs(e) for e in self.projections))
+        out_meta = (list(zip(types, dicts)) if projs is None else
+                    [(t, ce.dictionary)
+                     for t, ce in zip(self.output_types, projs)])
+        self._compiled = _FilterProjectProgram(
+            program("operators.filter_project", run), projs, run, needed,
+            out_meta)
         self._compiled_dicts = dicts
         with FilterProjectOperator._PROGRAM_CACHE_LOCK:
             FilterProjectOperator._PROGRAM_CACHE.setdefault(
@@ -632,8 +688,9 @@ class FilterProjectOperator(Operator):
                 dtype = (np.int32 if c.dictionary is not None
                          else c.type.storage_dtype)
                 cols_in.append((jnp.zeros(n, dtype), None))
-        run, projs = self._compile(batch)
-        outs, live, err_code = run(cols_in, batch.live)
+        prog = self._compile(batch)
+        projs = prog.projs
+        outs, live, err_code = prog.run(cols_in, batch.live)
         if err_code is not None:
             self.pending_errors.append(err_code)
         cols = []
@@ -654,7 +711,16 @@ class FilterProjectOperator(Operator):
         self._pending = ColumnBatch(self.output_names, cols, live)
         return True
 
-    def _observe_encoded(self, batch: ColumnBatch, needed: set[int]) -> None:
+    def observe_encoded(self, batch: ColumnBatch, needed) -> None:
+        """Count ``batch``'s encodings as a program reading the ``needed``
+        channels (None = all) meets them."""
+        if encoded_exec() and any(c.encoding in ("RLE", "LAZY")
+                                  for c in batch.columns):
+            self._observe_encoded(
+                batch, needed if needed is not None
+                else range(batch.num_columns))
+
+    def _observe_encoded(self, batch: ColumnBatch, needed) -> None:
         es = self.encoding_stats
         saved = 0
         n_rle = n_dict = 0
@@ -673,17 +739,42 @@ class FilterProjectOperator(Operator):
         if saved > 0:
             es.bytes_saved += saved
 
+    def program_inputs(self, batch: ColumnBatch):
+        """(program, input structure, cols, live) for a consumer that traces
+        this operator's body inside its own program: channels the body does
+        not read are ``(None, None)`` -- never expanded, staged or copied.
+        ``batch`` is bucket-shaped (pad_to_bucket)."""
+        prog = self._compile(batch)
+        needed = prog.needed
+        cols = []
+        for i, c in enumerate(batch.columns):
+            if needed is not None and i not in needed:
+                cols.append((None, None))
+            elif c.encoding == "RLE":
+                cols.append((K.rle_fill(c.rle_value, len(c)), c.valid))
+            else:  # touching .data materializes LAZY exactly once
+                cols.append((c.data, c.valid))
+        sig = (batch.num_rows, tuple((d is None, v is None) for d, v in cols),
+               batch.live is None)
+        return prog, sig, cols, batch.live
+
     def add_input(self, batch: ColumnBatch) -> None:
         if batch.num_columns == 0:
             self._pending = batch.rename(self.output_names)
+            return
+        if self.consumer is not None and self.consumer.absorbs(batch):
+            # the aggregation behind filters, projects and folds this batch
+            # in one program of its own: hand the input through as it came
+            self._pending = batch
             return
         if (encoded_exec()
                 and any(c.encoding in ("RLE", "LAZY") for c in batch.columns)
                 and self._add_input_encoded(batch)):
             return
         batch = pad_to_bucket(batch)
-        run, projs = self._compile(batch)
-        outs, live, err_code = run(_to_cols(batch), batch.live)
+        prog = self._compile(batch)
+        projs = prog.projs
+        outs, live, err_code = prog.run(_to_cols(batch), batch.live)
         if err_code is not None:
             # device scalar; checked in ONE batched fetch at pipeline end
             # (run_pipelines -> ops.expr.check_error_scalars)
@@ -726,6 +817,24 @@ def plan_lazy_scan(pipeline: Sequence[Operator]) -> None:
                 needed |= referenced_inputs(e)
     scan.lazy_channels = frozenset(
         i for i in range(len(scan.columns)) if i not in needed)
+
+
+def plan_aggregation_feed(pipeline: Sequence[Operator]) -> None:
+    """A FilterProjectOperator directly in front of an aggregation that may
+    stream (not FINAL, no DISTINCT) is made known to it as its ``feed``: on
+    the first batch the aggregation decides whether it streams, and if it
+    does its one program per batch also evaluates the feed's predicate and
+    projections (HashAggregationOperator.absorbs); if not, both run as they
+    did.  Called once per pipeline at local-planning time, after
+    intra-task parallelism has put its exchanges in: with one between the
+    two operators nothing is adjacent and the aggregation, if it streams,
+    folds with a launch of its own."""
+    for fp, agg in zip(pipeline, pipeline[1:]):
+        if (isinstance(fp, FilterProjectOperator)
+                and isinstance(agg, HashAggregationOperator)
+                and agg.step != "FINAL"
+                and not any(a.distinct for a in agg.aggs)):
+            fp.consumer, agg.feed = agg, fp
 
 
 class RenameOperator(Operator):
@@ -910,7 +1019,15 @@ def _concat_device(batches: Sequence[ColumnBatch]) -> ColumnBatch:
     """Concatenate (possibly masked) batches on device, padded to the
     total's power-of-two bucket.  Dead/padding rows are carried in ``live``
     so the result has a cache-friendly static shape — this is how blocking
-    operators materialize input without leaving the device."""
+    operators materialize input without leaving the device.  A single
+    batch that carries a ``live`` mask is bucket-shaped as far as anyone
+    downstream cares (pad_to_bucket's rule: a masked aggregation's page has
+    its static group space for lanes) and comes back as it is, instead of
+    three eager operations a column to pad six rows to eight."""
+    if (len(batches) == 1 and batches[0].live is not None
+            and not isinstance(batches[0].live, np.ndarray)
+            and all(c.encoding != "RLE" for c in batches[0].columns)):
+        return batches[0]
     names = batches[0].names
     total = sum(b.num_rows for b in batches)
     cap = K.bucket(total)
@@ -952,17 +1069,249 @@ def _concat_device(batches: Sequence[ColumnBatch]) -> ColumnBatch:
     return ColumnBatch(names, out_cols, live)
 
 
+def _agg_spec(a: AggCall, inp: ColumnBatch, out_t: Type):
+    """kernel (fn, data, valid, dtype, distinct) for one AggCall."""
+    if a.fn == "count" and a.arg < 0:
+        return ("count_star", None, None, np.int64, False)
+    col = inp.columns[a.arg]
+    data, valid = col.data, col.valid
+    if a.fn == "avg":
+        # decomposes into sum+count; dtype promotes to f64 on device
+        return ("avg", data, valid, np.float64, a.distinct)
+    if a.fn in STAT_AGGS:
+        # decomposes into (sum, sum-of-squares, count) states
+        return (a.fn, data, valid, np.float64, a.distinct)
+    if a.fn == "sum":
+        if out_t == DOUBLE:
+            dtype = np.float64
+        elif out_t.name == "real":
+            dtype = np.float32  # f32 lanes: the pallas fast path
+        else:
+            dtype = np.int64
+        return ("sum", data, valid, dtype, a.distinct)
+    if a.fn == "count":
+        return ("count", data, valid, np.int64, a.distinct)
+    return (a.fn, data, valid, data.dtype, a.distinct)
+
+
+def _is_long_decimal_agg(a: AggCall, inp: ColumnBatch) -> bool:
+    """An exact wide-decimal SUM/AVG: int64 limb-plane sums on device (an
+    eager gather through the dictionary's limb tables), bignum
+    recombination per group on the host."""
+    if a.fn not in ("sum", "avg") or a.arg < 0:
+        return False
+    t = inp.columns[a.arg].type
+    return isinstance(t, DecimalType) and t.precision > 18
+
+
+def _aggregation_specs(aggs: Sequence[AggCall], step: str, inp: ColumnBatch,
+                       use_masked: bool) -> tuple:
+    """(specs, (avg_slots, stat_slots, ld_slots)): the reduction kernels'
+    aggs list for ``aggs`` over ``inp``, and which AggCall expanded into
+    which run of state columns.  Reads only what the columns hold (arrays,
+    tracers inside a program, or shapes when only the layout is asked
+    for), so the buffered path, the per-batch fold and the fused
+    filter/project program build the same specs from the same code."""
+    live = inp.live
+
+    def fold_live(valid):
+        """Dead rows never contribute: fold ``live`` into validity.
+        The masked path folds live via the fused group id instead."""
+        if use_masked or live is None:
+            return valid
+        if valid is None:
+            return live
+        return jnp.asarray(valid) & jnp.asarray(live)
+
+    # kernel specs; avg expands to (sum, count) state pairs, the variance
+    # family to (sum, sumsq, count) triples.  FINAL merges partial
+    # states: count -> sum of counts, others same fn.
+    specs, avg_slots, stat_slots, ld_slots = [], {}, {}, {}
+
+    for idx, a in enumerate(aggs):
+        if _is_long_decimal_agg(a, inp):
+            ld_col = inp.columns[a.arg]
+            # exact wide-decimal SUM/AVG: int64 limb-plane sums on
+            # device, bignum recombination per group on host
+            # (kernels.decimal_limb_tables; Int128Math.java's role)
+            if a.distinct:
+                raise NotImplementedError(
+                    "DISTINCT long-decimal aggregate")
+            ld_slots[idx] = a.fn
+            valid_f = fold_live(ld_col.valid)
+            codes_dev = jnp.asarray(ld_col.data)
+            for tab in K.decimal_limb_tables(ld_col.dictionary):
+                specs.append(("sum", jnp.asarray(tab)[codes_dev],
+                              valid_f, np.int64, False))
+            specs.append(("count", ld_col.data, valid_f, np.int64,
+                          False))
+            continue
+        if step == "FINAL":
+            c = inp.columns[a.arg]
+            data, valid = c.data, fold_live(c.valid)
+            if a.fn == "avg":
+                avg_slots[idx] = len(specs)
+                c2 = inp.columns[a.arg + 1]
+                specs.append(("sum", data, valid, np.float64, False))
+                specs.append(("sum", c2.data, fold_live(None), np.int64, False))
+            elif a.fn in STAT_AGGS:
+                stat_slots[idx] = len(specs)
+                c2 = inp.columns[a.arg + 1]
+                c3 = inp.columns[a.arg + 2]
+                specs.append(("sum", data, valid, np.float64, False))
+                specs.append(("sum", c2.data, fold_live(c2.valid), np.float64, False))
+                specs.append(("sum", c3.data, fold_live(None), np.int64, False))
+            elif a.fn in ("count", "count_star"):
+                specs.append(("sum", data, fold_live(None), np.int64, False))
+            else:
+                specs.append((a.fn, data, valid, data.dtype, False))
+            continue
+        s = _agg_spec(a, inp, a.type)
+        s = (s[0], s[1], fold_live(s[2]), s[3], s[4])
+        if s[0] == "avg":
+            avg_slots[idx] = len(specs)
+            scale = 0
+            if a.arg >= 0 and isinstance(inp.columns[a.arg].type, DecimalType):
+                scale = inp.columns[a.arg].type.scale
+            # scale-free f64 sum state; the division happens INSIDE the
+            # compiled reduce program (pre tag), never as an eager
+            # full-size op with a launch (and a compile) of its own
+            specs.append(("sum", s[1], s[2], np.float64, s[4],
+                          ("scale", scale)))
+            specs.append(("count", s[1], s[2], np.int64, s[4]))
+        elif s[0] in STAT_AGGS:
+            stat_slots[idx] = len(specs)
+            specs.append(("sum", s[1], s[2], np.float64, False))
+            specs.append(("sum", s[1], s[2], np.float64, False,
+                          ("square",)))
+            specs.append(("count", s[1], s[2], np.int64, False))
+        else:
+            specs.append(s)
+    return specs, (avg_slots, stat_slots, ld_slots)
+
+
+def _masked_operands(group_keys: Sequence[int], aggs: Sequence[AggCall],
+                     step: str, inp: ColumnBatch) -> tuple:
+    """(kernels.MaskedOperands, slots) of the masked reduction of ``inp``."""
+    specs, slots = _aggregation_specs(aggs, step, inp, True)
+    return K.small_agg_operands([inp.columns[i] for i in group_keys],
+                                inp.live, specs), slots
+
+
+class _ColumnMeta(NamedTuple):
+    """What finalization still reads of an input column once its values
+    are folded away."""
+
+    type: Type
+    dictionary: Optional[np.ndarray]
+
+
+class _MaskedStream:
+    """The running state of a streaming masked aggregation, for one
+    generation of input dictionaries: the device-resident ``state``
+    (kernels.small_agg_state_layout's columns stacked by dtype, then the
+    feed's error scalar when its body can raise), and the statics that
+    finalization and the
+    next batch's check read.  ``feed`` is the (program, input structure) of
+    the filter/project whose body ``program`` traces in front of the fold;
+    both None when the aggregation folds with a launch of its own."""
+
+    __slots__ = ("ops", "layout", "shapes", "columns", "slots", "compaction",
+                 "lanes", "state", "feed", "program")
+
+    def __init__(self, ops, columns, slots, compaction, state,
+                 feed=None, program=None):
+        self.ops = ops._replace(flat=[])  # the layout, not the batch
+        self.layout = ops.layout
+        self.shapes = ops.state_shapes
+        self.columns = columns
+        self.slots = slots
+        self.compaction = compaction
+        self.lanes = 0
+        self.state = state
+        self.feed = feed
+        self.program = program
+
+    @property
+    def has_error(self) -> bool:
+        return len(self.state) > len(self.shapes)
+
+    def continues(self, ops, columns, channels, has_error: bool) -> bool:
+        """Can a batch with these operands and columns merge into this
+        state?  Same group space and state columns, and the very same
+        dictionary objects behind every channel finalization decodes."""
+        mine = self.ops
+        return (mine.sizes == ops.sizes and mine.has_valid == ops.has_valid
+                and self.layout == ops.layout
+                and self.has_error == has_error
+                and all(self.columns[i].dictionary is columns[i].dictionary
+                        for i in channels))
+
+    def attrs(self) -> dict:
+        return {"path": "masked", "compaction": self.compaction,
+                "lanes": self.lanes, "mode": "streamed",
+                "fused": self.feed is not None}
+
+
+def _filter_project_agg_program(prog: _FilterProjectProgram,
+                                group_keys: tuple, aggs: tuple, step: str):
+    """ONE program per batch for filter/project + masked aggregation:
+    ``(state, cols, live) -> state`` traces the filter/project's own body
+    (predicate, projections, error lanes), builds the aggregation's specs
+    over its outputs the way the unfused operator does over a batch, and
+    folds them into the donated state; the body's error scalar rides in
+    the state as a running max.  Kept with the filter/project's compiled
+    program, so it lives and dies with the dictionaries it was built for."""
+    donate = K.donate_ok()
+    key = (group_keys, aggs, step, donate)
+    with FilterProjectOperator._PROGRAM_CACHE_LOCK:
+        hit = prog.consumers.get(key)
+        if hit is not None:
+            return hit
+
+        def run(state, cols, live):
+            outs, live, err_code = prog.body(cols, live)
+            ops, _ = _masked_operands(group_keys, aggs, step,
+                                      prog.output_batch(outs, live))
+            n = len(ops.state_shapes)
+            merged = K.small_agg_fold_body(*ops.static)(state[:n], *ops.flat)
+            if err_code is not None:
+                merged += (jnp.maximum(state[n], err_code),)
+            return merged
+
+        hit = prog.consumers[key] = program(
+            "operators.filter_project_agg", run,
+            donate_argnums=(0,) if donate else ())
+    return hit
+
+
 class HashAggregationOperator(BufferedInputMixin, Operator):
     """Grouped aggregation: accumulate batches, then sort-based segment
     reduction (replaces operator/HashAggregationOperator.java:53 +
     FlatHash.java:42 with the kernels in exec/kernels.py).
 
-    PARTIAL steps flush early: when the buffered input exceeds
-    ``flush_rows``, the accumulated batches are pre-aggregated and emitted
-    immediately (states are mergeable by FINAL), so a worker's memory stays
-    bounded by the flush window rather than its whole input — the
-    InMemoryHashAggregationBuilder partial-flush behavior
-    (operator/aggregation/builder/InMemoryHashAggregationBuilder.java)."""
+    Which way input is consumed is decided once, from static properties of
+    the first batch (``_streams``):
+
+    - **streamed** -- a PARTIAL or SINGLE aggregation that will take the
+      masked path (small dictionary-code group space or a global aggregate,
+      no DISTINCT, no long decimal, under the masked/compaction crossover)
+      buffers nothing: each batch is reduced and merged into a small
+      device-resident state by ONE program (kernels.small_agg_fold), the
+      state goes through finalization once at finish and one page leaves.
+      With a FilterProjectOperator directly in front (``feed``, set by
+      plan_aggregation_feed) that one program also evaluates the predicate
+      and the projections (operators.filter_project_agg), and the filter
+      operator hands its input through untouched.
+    - **buffered** -- everything else (the paths that sort, DISTINCT, long
+      decimals, RLE folds, every FINAL step) accumulates ``_batches`` and
+      reduces them at finish, as before.  PARTIAL steps with group keys
+      flush early: when the buffered input exceeds ``flush_rows``, the
+      accumulated batches are pre-aggregated and emitted immediately
+      (states are mergeable by FINAL), so a worker's memory stays bounded
+      by the flush window rather than its whole input — the
+      InMemoryHashAggregationBuilder partial-flush behavior
+      (operator/aggregation/builder/InMemoryHashAggregationBuilder.java)."""
 
     FLUSH_ROWS = 1 << 20
     SPILL_PARTITIONS = 16
@@ -981,6 +1330,20 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
         self._result: Optional[ColumnBatch] = None
         self._emitted = False
         self.encoding_stats = EncodingStats()
+        # streaming masked aggregation: None until the first batch decides
+        self._streamed: Optional[bool] = None
+        self._stream: Optional[_MaskedStream] = None
+        # SINGLE step: partial-state pages of streams a dictionary change
+        # sealed, merged by a FINAL-step twin at finish
+        self._sealed: list[ColumnBatch] = []
+        # the FilterProjectOperator in front (plan_aggregation_feed), whose
+        # body the per-batch program traces when this aggregation streams
+        self.feed: Optional[FilterProjectOperator] = None
+        self._fused: Optional[bool] = None
+        self.pending_errors: list = []
+        # input channels whose dictionaries finalization decodes through
+        self._channels = sorted(set(self.group_keys).union(
+            a.arg for a in self.aggs if a.arg >= 0))
         # partitioned state spill (SpillableHashAggregationBuilder.java):
         # one spill file per hash partition of pre-aggregated states
         self._state_spillers: Optional[list] = None
@@ -1045,19 +1408,29 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
         if self.step == "FINAL":
             return ColumnBatch.concat(self._batches)
         p_names, p_types, _ = self._ensure_spill_layout()
-        tmp = HashAggregationOperator(
-            self.group_keys, self.aggs, p_names,
-            self._partial_types(), "PARTIAL")
+        tmp = self._partial_twin(self._batches[0].columns)
         tmp._batches = self._batches
         return tmp._compute().compact()
 
-    def _partial_types(self) -> list:
-        """Concrete partial-state types (keys from the buffered input)."""
+    def _partial_twin(self, columns) -> "HashAggregationOperator":
+        """This aggregation as a PARTIAL step over the same input: what it
+        emits are mergeable states (key types from ``columns``)."""
         p_names, p_types, _ = self._ensure_spill_layout()
-        inp = self._batches[0]
         nk = len(self.group_keys)
-        key_types = [inp.columns[c].type for c in self.group_keys]
-        return key_types + [t for t in p_types[nk:]]
+        key_types = [columns[c].type for c in self.group_keys]
+        return HashAggregationOperator(
+            self.group_keys, self.aggs, p_names, key_types + p_types[nk:],
+            "PARTIAL")
+
+    def _merge_states(self, pages: list[ColumnBatch]) -> ColumnBatch:
+        """Partial-state pages -> this aggregation's output, by a FINAL-step
+        twin (dictionaries unify in its _concat_device)."""
+        _, _, f_calls = self._ensure_spill_layout()
+        merger = HashAggregationOperator(
+            list(range(len(self.group_keys))), f_calls, self.output_names,
+            self.output_types, "FINAL")
+        merger._batches = pages
+        return merger._compute()
 
     def _spill_states(self) -> None:
         from .spill import Spiller
@@ -1087,19 +1460,13 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
     def _merge_spilled(self) -> list[ColumnBatch]:
         """Per-partition merge of spilled states (merge-on-unspill): memory
         is bounded by one partition's states at a time."""
-        _, _, f_calls = self._ensure_spill_layout()
-        nk = len(self.group_keys)
         outs: list[ColumnBatch] = []
         for sp in self._state_spillers:
             batches = list(sp.read_back())
             sp.close()
             if not batches:
                 continue
-            merger = HashAggregationOperator(
-                list(range(nk)), f_calls, self.output_names,
-                self.output_types, "FINAL")
-            merger._batches = batches
-            out = merger._compute()
+            out = self._merge_states(batches)
             if out.num_rows:
                 outs.append(out)
         self._state_spillers = None
@@ -1111,16 +1478,177 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
         return self.step == "PARTIAL" and bool(self.group_keys)
 
     def add_input(self, batch: ColumnBatch) -> None:
-        if batch.num_rows:
-            self._batches.append(batch)
-            self._buffered_rows += batch.num_rows
-            if self._can_flush() and self._buffered_rows >= self.FLUSH_ROWS:
-                out = self._compute()
-                if out.num_rows:
-                    self._flushed.append(out)
-                self._batches = []
-                self._buffered_rows = 0
-            self.account_memory()
+        if self._fused:
+            self._fold_fused(batch)  # the feed's input, handed through
+            return
+        if not batch.num_rows:
+            return
+        if self._streamed is None:
+            self._streamed = self._streams(batch)
+        if self._streamed:
+            self._fold(batch)
+            return
+        self._batches.append(batch)
+        self._buffered_rows += batch.num_rows
+        if self._can_flush() and self._buffered_rows >= self.FLUSH_ROWS:
+            out = self._compute()
+            if out.num_rows:
+                self._flushed.append(out)
+            self._batches = []
+            self._buffered_rows = 0
+        self.account_memory()
+
+    # -- streaming masked aggregation --------------------------------------
+    def _streams(self, inp: ColumnBatch) -> bool:
+        """Fold each batch into a running state instead of buffering it?
+        Decided once, from what is static in the first batch (``inp`` may
+        hold shapes only): the step, the masked path's own conditions, no
+        long-decimal aggregate (its limb gather is eager, its finalize on
+        the host), no RLE fold, and groups x reductions under the crossover
+        past which the masked path counts and compacts first."""
+        if self.step == "FINAL":
+            # a few tiny pages: one launch at finish beats one per page
+            return False
+        use_masked, space, _ = self._choose_path(inp)
+        if not use_masked or any(_is_long_decimal_agg(a, inp)
+                                 for a in self.aggs):
+            return False
+        if encoded_exec() and self._rle_eligible([inp]):
+            return False
+        return _masked_reads_dead_lanes_cheaper(
+            inp.num_rows, space, self._reduction_count(inp))
+
+    def absorbs(self, batch: ColumnBatch) -> bool:
+        """Asked by the filter/project in front (``feed``) for each of its
+        input batches: will this aggregation run the feed's body inside its
+        own per-batch program?  Decided on the first batch with rows, from
+        the shapes the body would produce (nothing runs)."""
+        if self._fused is None and self._streamed is None and batch.num_rows:
+            prog, sig, cols, live = self.feed.program_inputs(
+                pad_to_bucket(batch))
+            self._fused = self._streams(prog.view(sig, cols, live)[0])
+            if self._fused:
+                self._streamed = True
+                self.encoding_stats.agg_fused_feed += 1
+        return bool(self._fused)
+
+    def _open_stream(self, inp: ColumnBatch, ops, slots, has_error=False,
+                     feed=None, program=None) -> _MaskedStream:
+        """The stream the batch ``inp`` (arrays or shapes) folds into: the
+        running one if its state can take the batch, else a fresh one --
+        after sealing the old, whose dictionaries this batch left behind."""
+        old = self._stream
+        columns = [_ColumnMeta(c.type, c.dictionary) for c in inp.columns]
+        if old is not None and old.continues(ops, columns, self._channels,
+                                             has_error):
+            state = old.state
+        else:
+            if old is not None:
+                self._seal()
+            state = K.small_agg_zero_state(ops, has_error)
+            if self._mem is not None:
+                self._mem.update(self, sum(
+                    rows * lanes * np.dtype(d).itemsize
+                    for (rows, lanes), d in ops.state_shapes))
+        self._stream = _MaskedStream(
+            ops, columns, slots,
+            "skipped" if _compaction_candidate(inp) else "none", state,
+            feed, program)
+        return self._stream
+
+    def _fold(self, batch: ColumnBatch) -> None:
+        """One batch into the running state, with a launch of its own."""
+        batch = pad_to_bucket(batch)
+        cols, live = batch.columns, batch.live
+        for i in self._channels:
+            c = cols[i]
+            if c.encoding == "RLE":  # one scalar crosses, not the run
+                if cols is batch.columns:
+                    cols = list(cols)
+                cols[i] = Column(c.type, K.rle_fill(c.rle_value, len(c)),
+                                 c.valid, c.dictionary)
+        if live is None and not self._channels:
+            live = np.ones(batch.num_rows, np.bool_)  # count(*): the lanes
+        if cols is not batch.columns or live is not batch.live:
+            batch = ColumnBatch(batch.names, cols, live)
+        ops, slots = _masked_operands(self.group_keys, self.aggs, self.step,
+                                      batch)
+        st = self._stream
+        if st is None or not st.continues(ops, cols, self._channels, False):
+            st = self._open_stream(batch, ops, slots)
+        st.lanes = batch.num_rows
+        st.state = K.small_agg_fold(st.state, ops)
+        self.encoding_stats.agg_streamed_batches += 1
+        self.trace_attrs = st.attrs()
+
+    def _fold_fused(self, batch: ColumnBatch) -> None:
+        """One of the feed's input batches through predicate, projections
+        and the fold into the running state: one launch."""
+        if not batch.num_rows:
+            return
+        fp = self.feed
+        batch = pad_to_bucket(batch)
+        prog, sig, cols, live = fp.program_inputs(batch)
+        fp.observe_encoded(batch, prog.needed)
+        st = self._stream
+        if st is None or st.feed != (prog, sig):
+            view, has_error = prog.view(sig, cols, live)
+            ops, slots = _masked_operands(self.group_keys, self.aggs,
+                                          self.step, view)
+            st = self._open_stream(
+                view, ops, slots, has_error, (prog, sig),
+                _filter_project_agg_program(
+                    prog, tuple(self.group_keys), tuple(self.aggs),
+                    self.step))
+        st.lanes = batch.num_rows
+        st.state = st.program(st.state, cols, live)
+        self.encoding_stats.agg_streamed_batches += 1
+        self.trace_attrs = st.attrs()
+
+    def _emit_stream(self, st: _MaskedStream) -> ColumnBatch:
+        """A stream's state through finalization: one page."""
+        reduced, presence, keys_out, num_groups = K.small_agg_state_out(
+            st.state[:len(st.shapes)], st.ops)
+        if self.group_keys:
+            self.encoding_stats.code_group_batches += 1
+        self.encoding_stats.count_aggregation("masked", st.compaction)
+        return self._emit(reduced, presence, keys_out, num_groups,
+                          st.columns, st.slots)
+
+    def _close_stream(self, as_states: bool) -> ColumnBatch:
+        """Take the running stream out: its error scalar to
+        ``pending_errors``, its state out as a page -- of this step's own
+        output, or (``as_states``, SINGLE) of mergeable partial states."""
+        st, self._stream = self._stream, None
+        if st.has_error:
+            self.pending_errors.append(st.state[-1])
+        self.trace_attrs = st.attrs()
+        if not as_states:
+            return self._emit_stream(st)
+        twin = self._partial_twin(st.columns)
+        twin.encoding_stats = self.encoding_stats
+        return twin._emit_stream(st)
+
+    def _seal(self) -> None:
+        """A batch arrived under other dictionaries than the state's: the
+        state leaves as a partial-state page (PARTIAL: downstream merges it
+        like any other; SINGLE: kept for the FINAL-step merge at finish)."""
+        self.encoding_stats.agg_state_seals += 1
+        if self.step == "PARTIAL":
+            page = self._close_stream(False)
+            if page.num_rows:
+                self._flushed.append(page)
+        else:
+            self._sealed.append(self._close_stream(True))
+
+    def _finish_stream(self) -> None:
+        if self._sealed:
+            self._sealed.append(self._close_stream(True))
+            self._result = self._merge_states(self._sealed)
+            self._sealed = []
+        else:
+            self._result = self._close_stream(False)
+        self.release_memory()
 
     def _reduction_count(self, inp: ColumnBatch) -> int:
         """State columns _compute will ask the reduction kernel for, from
@@ -1129,9 +1657,7 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
         long-decimal sum or avg six limb sums and a count."""
         total = 0
         for a in self.aggs:
-            t = inp.columns[a.arg].type if a.arg >= 0 else None
-            if (a.fn in ("sum", "avg") and isinstance(t, DecimalType)
-                    and t.precision > 18):
+            if _is_long_decimal_agg(a, inp):
                 total += 7
             elif a.fn == "avg":
                 total += 2
@@ -1141,32 +1667,11 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
                 total += 1
         return total
 
-    def _agg_spec(self, a: AggCall, inp: ColumnBatch, out_t: Type):
-        """kernel (fn, data, valid, dtype, distinct) for one AggCall."""
-        if a.fn == "count" and a.arg < 0:
-            return ("count_star", None, None, np.int64, False)
-        col = inp.columns[a.arg]
-        data, valid = col.data, col.valid
-        if a.fn == "avg":
-            # decomposes into sum+count; dtype promotes to f64 on device
-            return ("avg", data, valid, np.float64, a.distinct)
-        if a.fn in STAT_AGGS:
-            # decomposes into (sum, sum-of-squares, count) states
-            return (a.fn, data, valid, np.float64, a.distinct)
-        if a.fn == "sum":
-            if out_t == DOUBLE:
-                dtype = np.float64
-            elif out_t.name == "real":
-                dtype = np.float32  # f32 lanes: the pallas fast path
-            else:
-                dtype = np.int64
-            return ("sum", data, valid, dtype, a.distinct)
-        if a.fn == "count":
-            return ("count", data, valid, np.int64, a.distinct)
-        return (a.fn, data, valid, data.dtype, a.distinct)
-
     def finish_input(self) -> None:
         super().finish_input()
+        if self._stream is not None:
+            self._finish_stream()
+            return
         if self._state_spillers is not None:
             # flush the tail, then merge partition-by-partition (memory
             # bounded by the largest partition, not the whole input)
@@ -1220,30 +1725,29 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
     # value + a live/valid count, without ever expanding the run
     _RLE_AGG_FNS = frozenset(("sum", "count", "count_star", "min", "max"))
 
-    def _rle_fast_path(self) -> Optional[ColumnBatch]:
-        """Global aggregation over RLE inputs: SUM(x) over a constant run
-        is ``value * run_count`` (the RunLengthEncodedBlock shortcut of the
-        reference's aggregation operators) — pure host arithmetic over per-
-        batch scalars, no concat, no device dispatch, no expansion."""
+    def _rle_eligible(self, batches: Sequence[ColumnBatch]) -> bool:
+        """Can ``batches`` fold as RLE runs (_rle_fast_path)?  A global,
+        non-DISTINCT sum/count/min/max whose every argument column is an
+        RLE run with host masks."""
         if (len(self.group_keys) or self.step == "FINAL"
                 or not self.aggs
                 or any(a.distinct for a in self.aggs)
                 or not all(a.fn in self._RLE_AGG_FNS for a in self.aggs)):
-            return None
-        for b in self._batches:
+            return False
+        for b in batches:
             if b.live is not None and not isinstance(b.live, np.ndarray):
-                return None  # counting a device mask would cost a sync
+                return False  # counting a device mask would cost a sync
             for a in self.aggs:
                 if a.arg < 0:
                     continue
                 c = b.columns[a.arg]
                 if c.encoding != "RLE":
-                    return None
+                    return False
                 if c.valid is not None and not isinstance(c.valid, np.ndarray):
-                    return None
+                    return False
                 if c.dictionary is not None and a.fn == "sum":
-                    return None  # dict codes don't sum; min/max do (sorted)
-        first = self._batches[0]
+                    return False  # dict codes don't sum; min/max do (sorted)
+        first = batches[0]
         for a in self.aggs:  # min/max on codes needs ONE shared dictionary
             if a.arg < 0 or first.columns[a.arg].dictionary is None:
                 continue
@@ -1251,8 +1755,16 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
 
             d0 = first.columns[a.arg].dictionary
             if not all(_same_dictionary(b.columns[a.arg].dictionary, d0)
-                       for b in self._batches[1:]):
-                return None
+                       for b in batches[1:]):
+                return False
+        return True
+
+    def _rle_fast_path(self) -> ColumnBatch:
+        """Global aggregation over RLE inputs (``_rle_eligible``): SUM(x)
+        over a constant run is ``value * run_count`` (the
+        RunLengthEncodedBlock shortcut of the reference's aggregation
+        operators) — pure host arithmetic over per-batch scalars, no
+        concat, no device dispatch, no expansion."""
 
         def counted(b: ColumnBatch, c: Column) -> int:
             """Rows of this run that are live AND valid."""
@@ -1303,34 +1815,38 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
         self.encoding_stats.rle_batches += len(self._batches)
         return ColumnBatch(self.output_names, out_cols)
 
-    def _compute(self) -> ColumnBatch:
+    def _choose_path(self, inp: ColumnBatch) -> tuple:
+        """(use_masked, group space, path name) from what is static in
+        ``inp``: key dictionaries, validity, DISTINCT, the aggregate list.
+        Masked-reduction fast path: small dictionary-code group space and
+        no DISTINCT -> no sort, no gather, no num_groups sync
+        (kernels.small_agg); compaction changes none of what is read here,
+        so the path is chosen BEFORE anyone pays for one."""
         nk = len(self.group_keys)
-        if not self.buffered_batches():
-            return self._empty_result(nk)
-        if encoded_exec():
-            fast = self._rle_fast_path()
-            if fast is not None:
-                return fast
-        inp = _concat_device(self._batches)
-
-        # masked-reduction fast path: small dictionary-code group space and
-        # no DISTINCT -> no sort, no gather, no num_groups sync (kernels.
-        # small_grouped_aggregate); live folds via the fused gid, so specs
-        # skip the fold_live below.  Everything read here is static (key
-        # dictionaries, validity, the aggregate list): compaction changes
-        # none of it, so the path is chosen BEFORE anyone pays for one.
         key_cols = [inp.columns[i] for i in self.group_keys]
         space = K.small_codes_group_space(key_cols) if nk else 1
-        if nk and space is not None:
-            # every key is a small dictionary code: the whole group-by runs
-            # in code space (one post-agg gather decodes group keys)
-            self.encoding_stats.code_group_batches += 1
         use_masked = (space is not None and space <= K.MASKED_AGG_LIMIT
                       and not any(a.distinct for a in self.aggs)
                       and (nk or inp.live is not None
                            or any(a.arg >= 0 for a in self.aggs)))
         path = ("masked" if use_masked
                 else "codes-sort" if nk and space is not None else "sort")
+        return use_masked, space, path
+
+    def _compute(self) -> ColumnBatch:
+        """The buffered way: reduce everything in ``_batches`` at once."""
+        nk = len(self.group_keys)
+        if not self.buffered_batches():
+            return self._empty_result(nk)
+        if encoded_exec() and self._rle_eligible(self._batches):
+            return self._rle_fast_path()
+        inp = _concat_device(self._batches)
+        key_cols = [inp.columns[i] for i in self.group_keys]
+        use_masked, space, path = self._choose_path(inp)
+        if nk and space is not None:
+            # every key is a small dictionary code: the whole group-by runs
+            # in code space (one post-agg gather decodes group keys)
+            self.encoding_stats.code_group_batches += 1
         # compaction is a cost of the paths that sort: argsort, lexsort /
         # hash, grouped_reduce.  The masked path is O(lanes) and ignores
         # dead lanes by construction; it takes them as they come -- no
@@ -1347,7 +1863,8 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
                 key_cols = [inp.columns[i] for i in self.group_keys]
         self.encoding_stats.count_aggregation(path, compaction)
         self.trace_attrs = {"path": path, "compaction": compaction,
-                            "lanes": inp.num_rows}
+                            "lanes": inp.num_rows, "mode": "buffered",
+                            "fused": False}
         live = inp.live  # None = all rows real
         n = inp.num_rows
 
@@ -1370,100 +1887,30 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
                     return self._empty_result(nk)
                 keys_out = K.group_keys_out(perm, gid, num_groups, keys)
         elif not nk and not use_masked:
-            key_cols, keys_out = [], []
+            keys_out = []
             perm = jnp.arange(n)
             gid = jnp.zeros(n, jnp.int32)
             num_groups = 1
 
-        def fold_live(valid):
-            """Dead rows never contribute: fold ``live`` into validity.
-            The masked path folds live via the fused group id instead."""
-            if use_masked or live is None:
-                return valid
-            if valid is None:
-                return live
-            return jnp.asarray(valid) & jnp.asarray(live)
-
-        # kernel specs; avg expands to (sum, count) state pairs, the variance
-        # family to (sum, sumsq, count) triples.  FINAL merges partial
-        # states: count -> sum of counts, others same fn.
-        specs, avg_slots, stat_slots, ld_slots = [], {}, {}, {}
-
-        def _long_dec_col(arg: int):
-            if arg < 0:
-                return None
-            c = inp.columns[arg]
-            t = c.type
-            if isinstance(t, DecimalType) and t.precision > 18:
-                return c
-            return None
-
-        for idx, a in enumerate(self.aggs):
-            ld_col = (_long_dec_col(a.arg)
-                      if a.fn in ("sum", "avg") else None)
-            if ld_col is not None:
-                # exact wide-decimal SUM/AVG: int64 limb-plane sums on
-                # device, bignum recombination per group on host
-                # (kernels.decimal_limb_tables; Int128Math.java's role)
-                if a.distinct:
-                    raise NotImplementedError(
-                        "DISTINCT long-decimal aggregate")
-                ld_slots[idx] = a.fn
-                valid_f = fold_live(ld_col.valid)
-                codes_dev = jnp.asarray(ld_col.data)
-                for tab in K.decimal_limb_tables(ld_col.dictionary):
-                    specs.append(("sum", jnp.asarray(tab)[codes_dev],
-                                  valid_f, np.int64, False))
-                specs.append(("count", ld_col.data, valid_f, np.int64,
-                              False))
-                continue
-            if self.step == "FINAL":
-                c = inp.columns[a.arg]
-                data, valid = c.data, fold_live(c.valid)
-                if a.fn == "avg":
-                    avg_slots[idx] = len(specs)
-                    c2 = inp.columns[a.arg + 1]
-                    specs.append(("sum", data, valid, np.float64, False))
-                    specs.append(("sum", c2.data, fold_live(None), np.int64, False))
-                elif a.fn in STAT_AGGS:
-                    stat_slots[idx] = len(specs)
-                    c2 = inp.columns[a.arg + 1]
-                    c3 = inp.columns[a.arg + 2]
-                    specs.append(("sum", data, valid, np.float64, False))
-                    specs.append(("sum", c2.data, fold_live(c2.valid), np.float64, False))
-                    specs.append(("sum", c3.data, fold_live(None), np.int64, False))
-                elif a.fn in ("count", "count_star"):
-                    specs.append(("sum", data, fold_live(None), np.int64, False))
-                else:
-                    specs.append((a.fn, data, valid, data.dtype, False))
-                continue
-            s = self._agg_spec(a, inp, a.type)
-            s = (s[0], s[1], fold_live(s[2]), s[3], s[4])
-            if s[0] == "avg":
-                avg_slots[idx] = len(specs)
-                scale = 0
-                if a.arg >= 0 and isinstance(inp.columns[a.arg].type, DecimalType):
-                    scale = inp.columns[a.arg].type.scale
-                # scale-free f64 sum state; the division happens INSIDE the
-                # compiled reduce program (pre tag), never as an eager
-                # full-size op with a launch (and a compile) of its own
-                specs.append(("sum", s[1], s[2], np.float64, s[4],
-                              ("scale", scale)))
-                specs.append(("count", s[1], s[2], np.int64, s[4]))
-            elif s[0] in STAT_AGGS:
-                stat_slots[idx] = len(specs)
-                specs.append(("sum", s[1], s[2], np.float64, False))
-                specs.append(("sum", s[1], s[2], np.float64, False,
-                              ("square",)))
-                specs.append(("count", s[1], s[2], np.int64, False))
-            else:
-                specs.append(s)
+        specs, slots = _aggregation_specs(self.aggs, self.step, inp,
+                                          use_masked)
         if use_masked:
             reduced, presence, keys_out, num_groups = (
                 K.small_grouped_aggregate(key_cols, live, specs))
         else:
             reduced = (K.grouped_reduce(perm, gid, num_groups, specs)
                        if specs else [])
+        return self._emit(reduced, presence, keys_out, num_groups,
+                          inp.columns, slots)
+
+    def _emit(self, reduced, presence, keys_out, num_groups: int, columns,
+              slots: tuple) -> ColumnBatch:
+        """The output page from the reduced per-group arrays.  ``columns``
+        gives the input's types and dictionaries (their values are not
+        read): a batch's own columns, or a stream's _ColumnMeta."""
+        nk = len(self.group_keys)
+        avg_slots, stat_slots, ld_slots = slots
+        key_cols = [columns[i] for i in self.group_keys]
 
         # finalization (avg division, variance combine, output casts) runs
         # as ONE compiled program over the tiny per-group arrays: zero eager
@@ -1505,7 +1952,7 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
                 counts = np.asarray(pulled[-1])
                 src_scale = 0
                 if a.arg >= 0:
-                    src_t = inp.columns[a.arg].type
+                    src_t = columns[a.arg].type
                     if isinstance(src_t, DecimalType):
                         src_scale = src_t.scale
                 import decimal as _dec
@@ -1583,9 +2030,9 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
                 v = None  # count never NULL
             dict_ = None
             if self.step != "FINAL" and a.arg >= 0:
-                dict_ = inp.columns[a.arg].dictionary
+                dict_ = columns[a.arg].dictionary
             elif self.step == "FINAL" and a.fn in ("min", "max", "any_value"):
-                dict_ = inp.columns[a.arg].dictionary
+                dict_ = columns[a.arg].dictionary
             emit(("copy", np.dtype(t.storage_dtype).str, v is not None),
                  [d] + ([v] if v is not None else []), t, dict_)
             ncols += 1

@@ -327,12 +327,16 @@ class EncodingStats:
     code_group_batches: int = 0  # group-bys that ran on int32 codes
     code_join_batches: int = 0   # joins probed in code space
     exchange_code_pages: int = 0  # pages whose codes crossed a shuffle
-    # HashAggregationOperator._compute, once per flush / finish / merge:
+    # HashAggregationOperator, once per flush / finish / merge / stream:
     agg_masked: int = 0      # O(lanes) masked reductions (kernels.small_agg)
     agg_codes_sort: int = 0  # argsort of fused dictionary codes
     agg_sort: int = 0        # lexsort / hash group ids, or global DISTINCT
     agg_compacted: int = 0   # a sorting path compacted its sparse input
     agg_compaction_skipped: int = 0  # masked path took the dead lanes as-is
+    # the streaming masked aggregation (HashAggregationOperator._streams):
+    agg_streamed_batches: int = 0  # batches folded into a running state
+    agg_fused_feed: int = 0    # aggregations that absorbed their filter/project
+    agg_state_seals: int = 0   # states sealed by a change of dictionaries
 
     def count_aggregation(self, path: str, compaction: str) -> None:
         """path: masked | codes-sort | sort; compaction: compacted |
@@ -364,6 +368,9 @@ class EncodingStats:
         self.agg_sort += other.agg_sort
         self.agg_compacted += other.agg_compacted
         self.agg_compaction_skipped += other.agg_compaction_skipped
+        self.agg_streamed_batches += other.agg_streamed_batches
+        self.agg_fused_feed += other.agg_fused_feed
+        self.agg_state_seals += other.agg_state_seals
 
     @property
     def any(self) -> bool:
@@ -388,7 +395,10 @@ class EncodingStats:
             f"aggregations: {self.agg_masked} masked / "
             f"{self.agg_codes_sort} codes-sort / {self.agg_sort} sort, "
             f"{self.agg_compacted} compacted, "
-            f"{self.agg_compaction_skipped} compaction skipped"
+            f"{self.agg_compaction_skipped} compaction skipped, "
+            f"{self.agg_streamed_batches} batches streamed "
+            f"({self.agg_fused_feed} aggregations fused with their "
+            f"filter/project, {self.agg_state_seals} state seals)"
         )
 
 

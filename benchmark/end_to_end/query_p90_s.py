@@ -1,6 +1,7 @@
 """90th percentile of the same walls.  Listed only for cells whose window
-completes about a hundred queries, so that ten samples lie beyond it; the
-sample count is on an earlier line of every run."""
+completes a hundred queries or more, so that ten samples lie beyond it
+(a 46 s window of Q6 at SF10 holds about 670, 67 beyond); the sample count
+and every wall are on earlier lines of every run."""
 
 from harness import stats
 

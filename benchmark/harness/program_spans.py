@@ -210,6 +210,19 @@ def begin(run):
     return run.program_spans_from
 
 
+def recorded(run) -> tuple | None:
+    """After any window: (events, how many the rings dropped) since
+    ``begin``, on the recorder's own clock — for a reader that wants
+    durations only.  None where the recorder hands out no events.  The
+    recorder keeps a bounded number of finished queries: a long window's
+    events are those of its newest queries."""
+    rec = _recorder()
+    since = getattr(run, "program_spans_from", None)
+    if rec is None or since is None:
+        return None
+    return rec.events_since(since), rec.dropped_since(since)
+
+
 def for_run(run, since) -> list | None:
     """After a traced window: the program's spans inside it, on the trace's
     clock; None (with the reason on an observation line) when the program

@@ -11,6 +11,7 @@ from __future__ import annotations
 import bisect
 import glob
 import os
+import re
 from dataclasses import dataclass, field
 
 TOP = 10
@@ -99,6 +100,28 @@ def attribute(gap: tuple, line: tuple) -> dict:
     return out
 
 
+def program_name(execution: str) -> str:
+    """``jit_trino_kernels_compact(1234)`` -> ``jit_trino_kernels_compact``."""
+    return re.sub(r"\(.*$", "", execution)
+
+
+def named_by_program(ops: list, programs: list) -> list:
+    """Each (start, duration, name) operation renamed ``<program> / <op>``:
+    the program execution (``XLA Modules`` line of the same plane) whose
+    interval holds the operation's start.  An operation that runs in two
+    programs becomes two names; one that no execution holds keeps its own.
+    Durations are untouched, so every sum stays what it was."""
+    runs = sorted(programs)
+    starts = [r[0] for r in runs]
+    out = []
+    for s, d, name in ops:
+        i = bisect.bisect_right(starts, s) - 1
+        if i >= 0 and s < runs[i][0] + runs[i][1]:
+            name = f"{program_name(runs[i][2])} / {name.lstrip('%')}"
+        out.append((s, d, name))
+    return out
+
+
 def top_operations(ops: list, n: int = TOP) -> list:
     """[[name, total seconds], ...], the ``n`` largest totals first."""
     total: dict = {}
@@ -164,8 +187,11 @@ class Trace:
             if self.ops else []
 
     def breakdown(self) -> dict:
+        """``device_ops`` by program: the ledger keeps the front of a name,
+        and an HLO line alone does not say whose it is."""
         ops = self.first_plane_ops()
-        return {"device_ops": top_operations(ops),
+        mine = self.programs.get(sorted(self.ops)[0], []) if self.ops else []
+        return {"device_ops": top_operations(named_by_program(ops, mine)),
                 "idle_gaps": idle_gaps_by_span(ops, self.spans, *self.window)}
 
 

@@ -7,7 +7,12 @@ spread of every metric read from files.
 
 This process never touches JAX (a parent that has holds the chip).  Every
 run's whole output goes to chiprun_out/prove/<cell>/<label>.log, its last
-line to <label>.json, and the summary to summary.json and to stdout.
+line to <label>.json, and the summary to summary.json and to stdout: per set
+and metric every value, the median and both spreads (the contract's
+interquartile one and the driver's, harness/spread.py); from the runs'
+latency lines (harness/tail.py) the pooled p50 / p90 / p99 and the bootstrap
+error of one run's p90 beside the run-to-run spread; the tail split pooled
+over the runs; and the bound each metric would get by the written rule.
 
 The compile cache of a check is cold at first: without ``--keep-cache`` the
 checkout's ``.jax_cache`` is emptied before the first run, so that run's
@@ -32,14 +37,13 @@ import time
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
+if BENCH_DIR not in sys.path:
+    sys.path.insert(0, BENCH_DIR)
+
+from harness import spread, tail  # noqa: E402  (host arithmetic, no JAX)
+
 CACHE = os.path.join(ROOT, ".jax_cache")
 BIG = 2**31 + 11  # the driver's seeds are large: more than 32 signed bits
-
-
-def spread(values: list) -> float:
-    """Interquartile distance over the median (the contract's rule)."""
-    q = statistics.quantiles(values, n=4)
-    return (q[2] - q[0]) / statistics.median(values)
 
 
 def copy_cache(src: str, dst: str) -> int:
@@ -82,7 +86,46 @@ def one_run(manifest: dict, out_dir: str, label: str, cell: str, seed: int,
               {k: v["value"] for k, v in result["metrics"].items()})
               if result else "NO RESULT"), flush=True)
     return {"label": label, "seed": seed, "trace": trace,
-            "rc": p.returncode, "wall_s": wall, "result": result}
+            "rc": p.returncode, "wall_s": wall, "result": result,
+            "latencies": tail.parse_marked(p.stdout, tail.LATENCY_MARK),
+            "tail_split": tail.parse_marked(p.stdout, tail.SPLIT_MARK)}
+
+
+def summarize(cell: str, seconds: float, sets: int, runs: list) -> dict:
+    """``runs``: what ``one_run`` returned, labelled ``set<k>_run<i>``."""
+    summary: dict = {"cell": cell, "seconds": seconds, "sets": {},
+                     "latency": {}, "tail_split": {}}
+    for s in range(sets):
+        mine = [r for r in runs
+                if r["label"].startswith(f"set{s}_") and r["result"]]
+        done = [r["result"] for r in mine]
+        per_metric = {}
+        for name in (done[0]["metrics"] if done else {}):
+            vals = [r["metrics"][name]["value"] for r in done]
+            several = len(vals) >= 2
+            per_metric[name] = {
+                "values": vals, "median": statistics.median(vals),
+                "spread": spread.iqr_spread(vals) if several else None,
+                "driver_spread": spread.driver_spread(vals)
+                if several else None}
+        summary["sets"][f"set{s}"] = per_metric
+        walls = [r["latencies"]["walls_s"] for r in mine if r["latencies"]]
+        if walls:
+            summary["latency"][f"set{s}"] = {
+                "pooled": spread.pooled(walls),
+                "tail": spread.tail_estimate(walls, tail.Q)}
+        summary["tail_split"][f"set{s}"] = tail.pooled_split(
+            [r["tail_split"] for r in mine])
+    summary["bound_rule"] = {}
+    for name in summary["sets"].get("set0", {}):
+        per_set = [m[name]["values"] for m in summary["sets"].values()
+                   if len(m.get(name, {}).get("values", [])) >= 2]
+        if per_set:
+            summary["bound_rule"][name] = spread.bound_rule(per_set)
+    summary["runs"] = [{k: v for k, v in r.items() if k != "result"}
+                       | {"correct": r["result"] and r["result"]["correct"]}
+                       for r in runs]
+    return summary
 
 
 def main(argv=None) -> int:
@@ -120,7 +163,8 @@ def main(argv=None) -> int:
     plan = [] if args.no_cold else [("first", BIG, 0)]
     plan += [(f"set{s}_run{i}", BIG + 1 + 7919 * i, 0)
              for s in range(args.sets) for i in range(args.runs)]
-    plan += [(f"traced{i}", BIG + 1 + 7919 * i, 1)
+    # seeds of their own: every run of a call but a set's twin is a new seed
+    plan += [(f"traced{i}", BIG + 500009 + 7919 * i, 1)
              for i in range(args.traced)]
     runs = []
     started = time.monotonic()
@@ -146,23 +190,11 @@ def main(argv=None) -> int:
         print(f"prove: {copy_cache(CACHE, kept)} cache entries to {kept}",
               flush=True)
 
-    summary: dict = {"cell": args.workload, "seconds": seconds, "sets": {}}
-    for s in range(args.sets):
-        done = [r["result"] for r in runs
-                if r["label"].startswith(f"set{s}_") and r["result"]]
-        per_metric = {}
-        for name in (done[0]["metrics"] if done else {}):
-            vals = [r["metrics"][name]["value"] for r in done]
-            per_metric[name] = {
-                "values": vals, "median": statistics.median(vals),
-                "spread": spread(vals) if len(vals) >= 2 else None}
-        summary["sets"][f"set{s}"] = per_metric
-    summary["runs"] = [{k: v for k, v in r.items() if k != "result"}
-                       | {"correct": r["result"] and r["result"]["correct"]}
-                       for r in runs]
+    summary = summarize(args.workload, seconds, args.sets, runs)
     with open(os.path.join(out_dir, "summary.json"), "w") as f:
         json.dump(summary, f, indent=1)
-    print(json.dumps(summary["sets"], indent=1))
+    print(json.dumps({k: summary[k] for k in (
+        "sets", "latency", "tail_split", "bound_rule")}, indent=1))
     bad = [r["label"] for r in runs
            if r["rc"] != 0 or not r["result"] or not r["result"]["correct"]]
     if bad:

@@ -43,6 +43,7 @@ for p in (ROOT, BENCH_DIR):
         sys.path.insert(0, p)
 
 from harness import check, deploy, load, manifest, observe, stats  # noqa: E402
+from harness import program_spans, tail  # noqa: E402
 from harness import trace as tracing  # noqa: E402
 from harness.client import Client  # noqa: E402
 from harness.deploy import say  # noqa: E402
@@ -165,6 +166,7 @@ def body(args, man, cell) -> int:
             f"{comp['cache_hits']} from the persistent cache)")
 
         mark = log.mark()
+        program_spans.begin(run)
         begun = {n: r.begin(run) if hasattr(r, "begin") else None
                  for n, r in readers.items()}
         if args.trace:
@@ -175,6 +177,9 @@ def body(args, man, cell) -> int:
                                      seconds=args.seconds)
         observe.report(f"window: {window.seconds:.3f}s, {window.attempted} "
                        f"queries; new programs inside it", log.since(mark))
+        # what the program's recorder still holds of the window's queries,
+        # before anything else runs in this process (the tail split, below)
+        recorded = program_spans.recorded(run)
         # counters and memory are read here, before the check moves columns
         run.queries = window.attempted
         run.window_s = window.seconds
@@ -202,6 +207,16 @@ def body(args, man, cell) -> int:
                    for q in TAILS)
         + "; slowest: " + " ".join(
             f"{x:.3f}" for x in sorted(run.latencies, reverse=True)[:5]))
+    # every query's wall, and the tail beside the rest: observations for the
+    # next reader of a tail, after the window and outside every timed region
+    say(tail.marked(tail.LATENCY_MARK,
+                    tail.latency_record(run.latencies, traffic["clients"])))
+    if recorded is None:
+        say("tail split: the program's recorder hands out no events")
+    else:
+        say(tail.marked(tail.SPLIT_MARK, tail.split(
+            [s.seconds for s in window.samples],
+            [v is None for v in verdicts], *recorded)))
     if not right:
         print("benchmark: no query of the window returned a right answer; "
               "no result", file=sys.stderr)

@@ -10,7 +10,7 @@ import sys
 
 import pytest
 
-from harness import manifest
+from harness import manifest, tail
 
 MAN = manifest.Manifest()
 CELLS = {w["traffic"]: w["name"] for w in MAN.doc["workloads"]}
@@ -51,6 +51,21 @@ def test_rehearsal(traffic, trace):
         assert set(result["metrics"]) == names
         assert "samples: n=" in p.stdout
     assert "device: platform=cpu" in p.stdout
+    # every query's wall and the tail split, after the window, never in the
+    # result: as many walls as right answers, every part summing to the wall
+    lat = tail.parse_marked(p.stdout, tail.LATENCY_MARK)
+    assert lat["n"] == len(lat["walls_s"]) == \
+        result["attempted"] - result["failed"]
+    assert all(0 <= i < lat["n"] for i in lat["beyond"])
+    split = tail.parse_marked(p.stdout, tail.SPLIT_MARK)
+    assert "refused" not in split and split["recorder_dropped"] == 0
+    assert 1 <= split["held"] <= lat["n"]
+    rest = split["rest"]
+    assert rest["n"] >= 1 and rest["tasks_ms"] > 0 and rest["plan_ms"] > 0
+    assert sum(rest[k + "_ms"] for k in tail.PARTS) == \
+        pytest.approx(rest["wall_ms"])
+    assert all(rest[k + "_ms"] > -5.0 for k in tail.PARTS)
+    assert "latencies" not in marked[0] and "tail split" not in marked[0]
 
 
 def test_no_tpu_is_a_failure_with_no_result():

@@ -70,7 +70,37 @@ def test_top_operations_and_program_runs():
                  spans=SPANS, window=(0.5, 7.5))
     assert tr.program_runs() == 2
     b = tr.breakdown()
-    assert b["device_ops"][0] == ["a", 1.5]
+    # by program since PR 31: "a" ran in jit_f and in jit_g, "c" in neither
+    assert b["device_ops"] == [["c", 1.0], ["jit_f / a", 1.0],
+                               ["jit_f / b", 1.0], ["jit_g / a", 0.5]]
     assert len(b["idle_gaps"]) <= T.TOP
     # no device plane: nothing to read, no division by zero
     assert T.Trace(window=(0.0, 1.0)).busy_s() == 0.0
+
+
+def test_breakdown_by_program_keeps_every_sum():
+    # "a" runs in two programs and once outside any; names change, seconds
+    # do not
+    programs = [(0.9, 1.7, "jit_trino_f(12)"), (3.9, 0.7, "jit_g(3)"),
+                (9.0, 1.0, "jit_outside(1)")]
+    ops = [(1.0, 1.0, "%a = f32[6] fusion()"), (1.5, 1.0, "b"),
+           (4.0, 0.5, "%a = f32[6] fusion()"), (6.0, 0.9, "%a = f32[6] fusion()")]
+    named = T.named_by_program(ops, programs)
+    assert [n for _, _, n in named] == [
+        "jit_trino_f / a = f32[6] fusion()", "jit_trino_f / b",
+        "jit_g / a = f32[6] fusion()", "%a = f32[6] fusion()"]
+    assert [(s, d) for s, d, _ in named] == [(s, d) for s, d, _ in ops]
+    old, new = T.top_operations(ops), T.top_operations(named)
+    assert sum(v for _, v in new) == pytest.approx(sum(v for _, v in old))
+    assert len(new) == 4 and len(old) == 2
+    tr = T.Trace(ops={"/device:TPU:0": ops},
+                 programs={"/device:TPU:0": programs},
+                 spans=SPANS, window=(0.5, 7.5))
+    b = tr.breakdown()
+    assert b["device_ops"][0] == ["jit_trino_f / a = f32[6] fusion()", 1.0]
+    assert sum(v for _, v in b["device_ops"]) == pytest.approx(3.4)
+    # idle gaps are read from the operations' intervals, not their names
+    assert b["idle_gaps"] == T.idle_gaps_by_span(
+        T.clip(ops, 0.5, 7.5), SPANS, 0.5, 7.5)
+    # no program line (a CPU rehearsal): the names stay
+    assert T.named_by_program(ops, []) == ops

@@ -40,14 +40,14 @@ def test_dense_probe_matches_hash_probe():
     probe = rng.integers(0, 70000, size=1 << 15).astype(np.int64)
     dense_t = JX.build_table(_keys(build))
     assert dense_t.dense is not None
-    ok, bid, cnt, mr = JX.run_unique_ranges(dense_t, _keys(probe), [None])
-    assert mr == 1
+    assert dense_t.unique
+    ok, bid, cnt = JX.run_unique_ranges_device(dense_t, _keys(probe), [None])
     ok = np.asarray(ok)
     bid = np.asarray(bid)
     expected = (probe >= 100) & (probe < 66000)
     np.testing.assert_array_equal(ok, expected)
     np.testing.assert_array_equal(bid[ok], probe[expected] - 100)
-    assert cnt == int(expected.sum())
+    assert cnt.get() == int(expected.sum())
 
 
 def test_dense_probe_respects_live_and_valid():
@@ -57,8 +57,9 @@ def test_dense_probe_respects_live_and_valid():
     probe = np.array([0, 1, 2, 3], dtype=np.int64)
     valid = np.array([True, False, True, True])
     live = np.array([True, True, False, True])
-    ok, bid, cnt, mr = JX.run_unique_ranges(
+    assert t.unique
+    ok, bid, cnt = JX.run_unique_ranges_device(
         t, _keys(probe, valid), [None], live=live)
     np.testing.assert_array_equal(np.asarray(ok),
                                   [True, False, False, True])
-    assert cnt == 2
+    assert cnt.get() == 2

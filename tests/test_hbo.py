@@ -1,10 +1,9 @@
 """History-based optimization: fingerprint invariances, journal round
 trip, second-run planning, fan-out shrink, plan-cache epoch keying, and
-the iterative-vs-legacy TPC-H row-identity oracle (reference: Trino's
-HBO design — io.trino.cost.HistoryBasedPlanStatisticsCalculator — and
+every TPC-H statement's second run, planned from recorded history, against
+its first (reference: Trino's HBO design —
+io.trino.cost.HistoryBasedPlanStatisticsCalculator — and
 AbstractTestQueryFramework.assertQuery)."""
-
-import os
 
 import pytest
 
@@ -12,7 +11,7 @@ from trino_tpu.connectors.catalog import default_catalog
 from trino_tpu.connectors.tpch_queries import QUERIES
 from trino_tpu.planner import history
 from trino_tpu.planner.plan import Filter, Join, Project, TableScan
-from trino_tpu.runner import Session, StandaloneQueryRunner
+from trino_tpu.runner import Session
 from trino_tpu.sql.ir import Call, InputRef, Literal
 from trino_tpu.spi.types import BIGINT, BOOLEAN
 from trino_tpu.telemetry import journal
@@ -274,56 +273,87 @@ def test_history_shrinks_task_fanout(journal_env, monkeypatch):
     assert "hbo_fanout" in rt.queries()[-1].adaptive_decisions
 
 
-# --------------------------------- iterative vs legacy row identity
+# ------------------------- the second run, planned from history (e2e)
+#
+# The benchmark's cells leave TRINO_TPU_HBO at "auto" with a journal, so
+# every timed query there is a repeat planned from what earlier runs
+# recorded.  These run that configuration: history on, a fresh journal, the
+# result tier off (a served result would skip planning and execution).
 
 
 _ORDERED = {1, 2, 3, 5, 7, 8, 9, 10, 11, 12, 13, 14, 16, 18, 21, 22}
 
 
+@pytest.fixture
+def one_chip_env(journal_env, monkeypatch):
+    """What one chip runs: on the suite's 8-device mesh "auto" takes the
+    fused-stage and collective edges, whose sinks bypass the buffers the
+    recorder reads (see test_history_shrinks_task_fanout)."""
+    from trino_tpu.caching import result_cache
+
+    monkeypatch.setenv("TRINO_TPU_FUSED_STAGE", "0")
+    with result_cache.disabled():
+        yield
+
+
 @pytest.fixture(scope="module")
-def oracle_catalog():
+def served_catalog():
+    """One catalog for every runner of the module, as a server's runners
+    share theirs: generating SF0.01 anew costs a second an execution."""
     return default_catalog(scale_factor=0.01)
 
 
-def _mode_rows(catalog, sql, mode, monkeypatch):
-    """Plan-cache keys include TRINO_TPU_OPTIMIZER, so modes can't serve
-    each other's plans; only the result tier must not short-circuit the
-    second leg (jitted-program memos stay warm — they are mode-blind)."""
-    from trino_tpu.caching import result_cache
+def _served_runner(catalog):
+    """The cells' deployment in small: two tasks a stage over SF0.01."""
+    from trino_tpu.execution.distributed_runner import DistributedQueryRunner
 
-    monkeypatch.setenv("TRINO_TPU_OPTIMIZER", mode)
-    monkeypatch.setenv("TRINO_TPU_HBO", "0")
-    with result_cache.disabled():
-        return StandaloneQueryRunner(catalog).execute(sql).rows()
+    return DistributedQueryRunner(
+        catalog, worker_count=2,
+        session=Session(node_count=2, use_collectives=False))
 
 
-def _mode_plan(catalog, sql, mode, monkeypatch):
-    """create_plan plans fresh every call (the plan-cache tier sits in
-    execute()), so no cache bypass is needed here."""
-    monkeypatch.setenv("TRINO_TPU_OPTIMIZER", mode)
-    monkeypatch.setenv("TRINO_TPU_HBO", "0")
-    return StandaloneQueryRunner(catalog).create_plan(sql)
+def _recorded_history():
+    """The provider a plan made NOW would consult; fails unless history is
+    on and holds records — without that a second run is only a rerun."""
+    provider = history.provider_if_enabled()
+    assert provider is not None and provider.table, \
+        "history is off or the journal holds no plan_stats records"
+    return provider
 
 
 @pytest.mark.parametrize("q", sorted(QUERIES))
-def test_iterative_matches_legacy_tpch(q, oracle_catalog, monkeypatch):
-    """Row-identity oracle: every TPC-H query planned by the iterative
-    engine returns exactly what the legacy pipeline returns.
+def test_second_run_from_history_matches_first(q, one_chip_env,
+                                               served_catalog):
+    """Every TPC-H statement, run, then planned again from the statistics
+    its first run recorded and run on a new runner: the same rows."""
+    first = _served_runner(served_catalog).execute(QUERIES[q]).rows()
+    _reset_planning_caches()
+    _recorded_history()
+    second = _served_runner(served_catalog).execute(QUERIES[q]).rows()
+    assert_same_rows(second, first, ordered=q in _ORDERED)
 
-    When both optimizers converge on the *same* optimized plan (13 of 22
-    queries at this writing), executing it twice proves nothing plan
-    equality doesn't already prove — and test_queries runs every query
-    end-to-end under the iterative default.  Rows are compared only for
-    the queries whose plans genuinely diverge; this also keeps ~26
-    redundant TPC-H executions (and their jitted programs) out of the
-    tier-1 suite."""
-    legacy_plan = _mode_plan(oracle_catalog, QUERIES[q], "legacy",
-                             monkeypatch)
-    iterative_plan = _mode_plan(oracle_catalog, QUERIES[q], "iterative",
-                                monkeypatch)
-    if legacy_plan == iterative_plan:
-        return
-    legacy = _mode_rows(oracle_catalog, QUERIES[q], "legacy", monkeypatch)
-    iterative = _mode_rows(oracle_catalog, QUERIES[q], "iterative",
-                           monkeypatch)
-    assert_same_rows(iterative, legacy, ordered=q in _ORDERED)
+
+@pytest.fixture(scope="module")
+def cells_oracle(served_catalog):
+    """sqlite over the three tables the cells' queries read."""
+    from trino_tpu.testing.oracle import SqliteOracle
+
+    oracle = SqliteOracle()
+    oracle.load_connector_tables(
+        served_catalog.connector("tpch"), ("customer", "orders", "lineitem"))
+    return oracle
+
+
+@pytest.mark.parametrize("q", [1, 3, 6], ids=["q1", "q3", "q6"])
+def test_served_repeat_with_history(q, one_chip_env, served_catalog,
+                                    cells_oracle):
+    """The queries the cells send, three times back to back on one runner
+    with the plan cache on: every answer is the oracle's, and from the
+    second on the plan is looked up under the history the first left."""
+    runner = _served_runner(served_catalog)
+    expected = cells_oracle.query(QUERIES[q])
+    for run in range(3):
+        if run:
+            _recorded_history()
+        assert_same_rows(runner.execute(QUERIES[q]).rows(), expected,
+                         ordered=True)

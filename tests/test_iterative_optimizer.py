@@ -4,8 +4,6 @@ estimate regression (reference: the per-rule *Test classes under
 core/trino-main/src/test/.../sql/planner/iterative/rule/ and
 TestMemo.java)."""
 
-import os
-
 import pytest
 
 from trino_tpu.connectors.catalog import default_catalog
@@ -22,17 +20,6 @@ from trino_tpu.planner.plan import (AggCall, Aggregate, CorrelatedJoin,
                                     Union, Values)
 from trino_tpu.sql.ir import Call, InputRef, Literal
 from trino_tpu.spi.types import BIGINT, BOOLEAN
-
-
-@pytest.fixture(autouse=True)
-def _iterative_mode():
-    saved = os.environ.get("TRINO_TPU_OPTIMIZER")
-    os.environ["TRINO_TPU_OPTIMIZER"] = "iterative"
-    yield
-    if saved is None:
-        os.environ.pop("TRINO_TPU_OPTIMIZER", None)
-    else:
-        os.environ["TRINO_TPU_OPTIMIZER"] = saved
 
 
 CATALOG = default_catalog(scale_factor=0.01)

@@ -154,7 +154,7 @@ def dist():
                                   session=Session(node_count=2))
 
 
-def test_default_profiling_keeps_hot_regions_sync_free(dist):
+def test_default_profiling_keeps_hot_regions_free_of_syncs(dist):
     """THE overhead guard: with the flight recorder at its default level,
     a fused-stage query still runs with zero blocking syncs inside
     SyncGuard hot regions (recording is a clock read + a tuple store)."""

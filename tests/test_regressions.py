@@ -174,7 +174,7 @@ def test_correlated_sum_zero_rows_is_null(runner):
 
 def test_keyless_semijoin_residual_only():
     # EXISTS decorrelated to a semi-join with no equi keys (residual only)
-    # crashed probe_join_table with an empty key list (advisor finding)
+    # crashed the probe with an empty key list (advisor finding)
     build = ColumnBatch(["b"], [Column(BIGINT, np.asarray([5, 7], np.int64))])
     bridge = JoinBridge()
     sink = JoinBuildSink(bridge, [], [BIGINT], ["b"])
@@ -214,12 +214,22 @@ def test_float_nan_single_group():
 
 
 def test_float_join_nan_and_negzero_match():
+    from trino_tpu.exec import join_exec as JX
+    from trino_tpu.spi import DOUBLE
+
     build = [(np.asarray([np.nan, -0.0], np.float64), None)]
-    table = K.build_join_table(build)
+    table = JX.build_table(build)
     probe = [(np.asarray([np.nan, 0.0, 3.0], np.float64), None)]
-    pi, bi = K.probe_join_table(table, probe)
-    pairs = sorted(zip(pi.tolist(), bi.tolist()))
-    assert pairs == [(0, 0), (1, 1)]
+    lo, counts, total = JX.probe_ranges_device(table, probe, [None])
+    probe_idx = [(np.arange(3, dtype=np.int64), None)]
+    pairs, ok, _, _, bid, _ = JX.run_pairs(
+        table, lo, counts, int(total.get()), probe, [None], probe_idx,
+        build, [BIGINT, DOUBLE], [None, None], residual=None,
+        need_matched=False)
+    ok = np.asarray(ok)
+    got = sorted(zip(np.asarray(pairs[0][0])[ok].tolist(),
+                     np.asarray(bid)[ok].tolist()))
+    assert got == [(0, 0), (1, 1)]
 
 
 def test_failed_task_aborts_peers_quickly():

@@ -1,11 +1,8 @@
-"""Sync-free probe/expand hot loop: padded-expand equivalence against the
-legacy blocking paths, overflow->retry correctness, capacity planning, the
-deferred-commit OverflowQueue, and SyncGuard enforcement that steady-state
-probe batches perform ZERO blocking host syncs.
-
-Legacy switches kept precisely for these tests:
-  TRINO_TPU_LEGACY_EXPAND=1  kernels.probe_join_table two-fetch expand
-  TRINO_TPU_SYNC_FREE=0      operators.py per-batch blocking total sync
+"""Sync-free probe/expand hot loop: the planner's padded expand against the
+exact-total form and a nested-loop reference, overflow->retry correctness,
+capacity planning, the deferred-commit OverflowQueue, and SyncGuard
+enforcement that steady-state probe batches perform ZERO blocking host
+syncs.
 """
 
 import numpy as np
@@ -40,105 +37,72 @@ def _expected_pairs(build, probe, bvalid=None, pvalid=None):
 
 
 # ---------------------------------------------------------------------------
-# kernels.probe_join_table: padded single-fetch vs legacy two-fetch
-
-
-def test_probe_join_table_padded_vs_legacy(monkeypatch):
-    rng = np.random.default_rng(3)
-    build = rng.integers(0, 50, size=300).astype(np.int64)  # heavy dups
-    bvalid = rng.random(300) > 0.1
-    probe = rng.integers(0, 60, size=257).astype(np.int64)  # some no-match
-    pvalid = rng.random(257) > 0.1
-    table = K.build_join_table([(build, bvalid)])
-
-    pi, bi = K.probe_join_table(table, [(probe, pvalid)])
-    monkeypatch.setenv("TRINO_TPU_LEGACY_EXPAND", "1")
-    pi_l, bi_l = K.probe_join_table(table, [(probe, pvalid)])
-
-    expected = _expected_pairs(build, probe, bvalid, pvalid)
-    assert _pair_set(pi, bi) == expected
-    assert _pair_set(pi_l, bi_l) == expected
-
-
-def test_probe_join_table_zero_match_and_empty(monkeypatch):
-    table = K.build_join_table(_keys(np.arange(10, dtype=np.int64)))
-    for env in ("0", "1"):
-        monkeypatch.setenv("TRINO_TPU_LEGACY_EXPAND", env)
-        # zero matches: every probe key outside the build domain
-        pi, bi = K.probe_join_table(
-            table, _keys(np.array([100, 200], dtype=np.int64)))
-        assert len(pi) == 0 and len(bi) == 0
-        # empty probe
-        pi, bi = K.probe_join_table(
-            table, _keys(np.empty(0, dtype=np.int64)))
-        assert len(pi) == 0 and len(bi) == 0
-
-
-def test_probe_join_table_overflow_retry():
-    # 4 probe rows * 64-duplicate build runs = 256 candidates, far beyond
-    # the speculative bucket(4) * _PAIR_PAD = 32 cap: the padded path must
-    # detect overflow and re-run at the exact bucket, never truncate
-    build = np.repeat(np.arange(2, dtype=np.int64), 64)
-    probe = np.array([0, 1, 0, 1], dtype=np.int64)
-    table = K.build_join_table(_keys(build))
-    before = SG.snapshot()
-    pi, bi = K.probe_join_table(table, _keys(probe))
-    delta = SG.take_delta(before)
-    assert delta.expand_overflows >= 1
-    assert _pair_set(pi, bi) == _expected_pairs(build, probe)
-
-
-# ---------------------------------------------------------------------------
-# join_exec.run_pairs: provable / estimated caps vs the legacy host total
+# join_exec.run_pairs: provable / estimated caps vs the exact host total
 
 
 def _run_pairs_at(table, keys, cap, donate=False, total=None):
+    """One pair program over ``keys``; the probe column it gathers is the
+    probe ROW INDEX, so (pairs[0][0], bid) under ``ok`` are the matched
+    (probe_idx, build_idx) pairs."""
     lo, counts, total_a = JX.probe_ranges_device(table, keys, [None])
     t = total_a if total is None else total
-    probe = keys[0][0]
+    probe_idx = np.arange(len(keys[0][0]), dtype=np.int64)
     pairs, ok, matched, maxc, bid, overflow = JX.run_pairs(
         table, lo, counts, t, keys, [None],
-        [(probe, None)], [(table.key_datas[0], None)],
+        [(probe_idx, None)], [(table.key_datas[0], None)],
         [BIGINT, BIGINT], [None, None],
         residual=None, need_matched=True, cap=cap, donate=donate)
     return pairs, ok, bid, overflow
 
 
-def test_run_pairs_provable_cap_matches_legacy():
-    rng = np.random.default_rng(11)
+def _matched_pairs(pairs, ok, bid):
+    ok = np.asarray(ok)
+    return _pair_set(np.asarray(pairs[0][0])[ok], np.asarray(bid)[ok])
+
+
+def _dup_runs_of_4():
     # dup runs of 4 keep bucket(n_probe * max_run) within PROVABLE_SLACK of
-    # the probe width: the planner must prove the cap and skip the flag
+    # the probe width
+    rng = np.random.default_rng(11)
     build = np.repeat(np.arange(50, dtype=np.int64), 4)
     probe = rng.integers(0, 60, size=128).astype(np.int64)
-    table = JX.build_table(_keys(build))
-    keys = _keys(probe)
-    expected = _expected_pairs(build, probe)
+    return build, None, probe, None
 
-    # legacy: blocking total sync picks the exact bucket
-    lo, counts, total = JX.probe_ranges(table, keys, [None])
-    pairs_l, ok_l, bid_l, _ = _run_pairs_at(table, keys, cap=None, total=total)
-    ok_l = np.asarray(ok_l)
-    # slot -> probe id comes back via the gathered probe column
-    pi_l = np.asarray(pairs_l[0][0])[ok_l]  # probe VALUES, so map via pairs
-    # reconstruct (probe_idx, build_idx) from gathered values + device ids
-    bid_l = np.asarray(bid_l)[ok_l]
 
-    # sync-free: planner cap from build-side stats (max_run), no total sync
-    planner = JX.ExpandPlanner()
-    cap, provable = planner.plan(len(probe), table.max_run)
-    assert provable  # run 4 * 128 probes = 512 lanes <= 8 * bucket(128)
-    pairs_s, ok_s, bid_s, overflow = _run_pairs_at(
+def _null_keys_heavy_dups():
+    # NULL keys on both sides never match, over heavy duplicate runs and
+    # some probe keys outside the build domain
+    rng = np.random.default_rng(3)
+    build = rng.integers(0, 50, size=300).astype(np.int64)
+    bvalid = rng.random(300) > 0.1
+    probe = rng.integers(0, 60, size=257).astype(np.int64)
+    pvalid = rng.random(257) > 0.1
+    return build, bvalid, probe, pvalid
+
+
+@pytest.mark.parametrize("inputs", [_dup_runs_of_4, _null_keys_heavy_dups])
+def test_run_pairs_provable_cap_matches_exact_total(inputs):
+    build, bvalid, probe, pvalid = inputs()
+    table = JX.build_table(_keys(build, bvalid))
+    keys = _keys(probe, pvalid)
+    expected = _expected_pairs(build, probe, bvalid, pvalid)
+
+    # the exact-total form (what an overflow retry runs): the landed
+    # candidate total picks the bucket
+    total = int(JX.probe_ranges_device(table, keys, [None])[2].get())
+    pairs_x, ok_x, bid_x, _ = _run_pairs_at(table, keys, cap=None, total=total)
+
+    # planner cap from build-side stats (max_run), no total sync: the
+    # planner must prove the cap and skip the flag
+    cap, provable = JX.ExpandPlanner().plan(len(probe), table.max_run)
+    assert provable
+    pairs_p, ok_p, bid_p, overflow = _run_pairs_at(
         table, keys, cap=cap, donate=provable)
-    ok_s = np.asarray(ok_s)
-    bid_s = np.asarray(bid_s)[ok_s]
     assert not bool(np.asarray(overflow))
 
-    # both paths produce the same (probe value, build row) multiset, and
-    # the build rows of each must be exactly the expected pair set's
-    assert sorted(bid_l.tolist()) == sorted(bid_s.tolist())
-    assert set(bid_s.tolist()) == {b for _, b in expected}
-    assert sorted(np.asarray(pairs_s[0][0])[ok_s].tolist()) == \
-        sorted(pi_l.tolist())
+    assert _matched_pairs(pairs_x, ok_x, bid_x) == expected
+    assert _matched_pairs(pairs_p, ok_p, bid_p) == expected
+    assert int(np.asarray(ok_p).sum()) == len(expected)  # no pair twice
 
 
 def test_run_pairs_overflow_flag_and_retry():
@@ -330,22 +294,33 @@ def test_lookup_join_steady_state_zero_hot_syncs():
 # query-level equivalence + observability
 
 
-@pytest.mark.parametrize("sql,expected_via", [
+@pytest.fixture(scope="module")
+def harness():
+    from trino_tpu.connectors.catalog import default_catalog
+    from trino_tpu.runner import StandaloneQueryRunner
+    from trino_tpu.testing.oracle import SqliteOracle
+
+    catalog = default_catalog(scale_factor=0.01)
+    oracle = SqliteOracle()
+    oracle.load_connector_tables(
+        catalog.connector("tpch"), ("nation", "orders", "lineitem"))
+    return StandaloneQueryRunner(catalog), oracle
+
+
+@pytest.mark.parametrize("sql,expected", [
     ("select count(*) from orders o join lineitem l "
      "on o.o_orderkey = l.l_orderkey", None),
     ("select count(*) from nation a join nation b "
      "on a.n_regionkey = b.n_regionkey", [(125,)]),
 ])
-def test_query_equivalence_sync_free_vs_legacy(monkeypatch, sql, expected_via):
-    from trino_tpu.runner import StandaloneQueryRunner
+def test_join_query_matches_oracle(harness, sql, expected):
+    from trino_tpu.testing.oracle import assert_same_rows
 
-    results = {}
-    for mode in ("1", "0"):
-        monkeypatch.setenv("TRINO_TPU_SYNC_FREE", mode)
-        results[mode] = StandaloneQueryRunner().execute(sql).rows()
-    assert results["1"] == results["0"]
-    if expected_via is not None:
-        assert results["1"] == expected_via
+    runner, oracle = harness
+    rows = runner.execute(sql).rows()
+    assert_same_rows(rows, oracle.query(sql))
+    if expected is not None:
+        assert rows == expected
 
 
 def test_explain_analyze_reports_sync_stats():

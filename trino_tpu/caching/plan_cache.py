@@ -44,9 +44,7 @@ __all__ = [
 # the bitwise result of float aggregation): a flip must miss
 PLANNING_ENV_KNOBS = (
     "TRINO_TPU_HASH_IMPL", "TRINO_TPU_FUSED_STAGE", "TRINO_TPU_FUSED_CAP",
-    "TRINO_TPU_SYNC_FREE", "TRINO_TPU_LEGACY_EXPAND",
-    "TRINO_TPU_TPCH_VECTOR_DECODE", "TRINO_TPU_PREFETCH",
-    "TRINO_TPU_OPTIMIZER", "TRINO_TPU_HBO",
+    "TRINO_TPU_TPCH_VECTOR_DECODE", "TRINO_TPU_PREFETCH", "TRINO_TPU_HBO",
     "TRINO_TPU_JOIN_REORDER_DP_LIMIT", "TRINO_TPU_BROADCAST_ROW_LIMIT",
 )
 

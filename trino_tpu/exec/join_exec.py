@@ -9,10 +9,11 @@ this module keeps the whole probe on device:
 - ``build_table``: ONE jitted program hashes + sorts the build keys
   (``hash_combine`` + argsort on chip); one 2-scalar device_get fetches
   (has_null_key, live_rows) for planner-visible semantics.
-- ``probe_ranges``: ONE jitted program computes candidate ranges via binary
-  search in the sorted hash; ONE scalar sync fetches the total candidate
-  count (needed to pick the static expansion bucket — the only data-
-  dependent shape in the join).
+- ``probe_ranges_device``: ONE jitted program computes candidate ranges via
+  binary search in the sorted hash; the total candidate count comes back as
+  an AsyncScalar.  The static expansion bucket — the only data-dependent
+  shape in the join — is planned from build-side statistics
+  (``ExpandPlanner``) and guarded by a deferred overflow flag.
 - ``run_pairs``: ONE jitted program per (join shape, residual, bucket)
   expands candidates, verifies key equality exactly (hash candidates ->
   per-key compare, NaN=NaN), evaluates the residual predicate, gathers ALL
@@ -20,7 +21,7 @@ this module keeps the whole probe on device:
   for LEFT/SINGLE and the semi-join mark — outputs stay on device as a
   ``live``-masked batch.
 
-Total blocking host interaction per probe batch: one scalar sync.
+Blocking host interaction per probe batch in the steady state: none.
 """
 
 from __future__ import annotations
@@ -38,7 +39,7 @@ from ..sql.ir import RowExpression
 from . import kernels as K
 from . import syncguard as SG
 
-__all__ = ["DeviceJoinTable", "JoinHashTable", "build_table", "probe_ranges",
+__all__ = ["DeviceJoinTable", "JoinHashTable", "build_table",
            "probe_ranges_device", "run_pairs", "run_unique",
            "ExpandPlanner", "OverflowQueue", "plan_unique_cap", "key_input"]
 
@@ -87,7 +88,8 @@ class DeviceJoinTable:
         self.dense = None
         self.dense_lo = 0
         # open-addressing index over the build hashes (TRINO_TPU_HASH_IMPL):
-        # probe_ranges dispatches on it; every downstream program is shared
+        # probe_ranges_device dispatches on it; every downstream program is
+        # shared
         self.hash_idx: Optional["JoinHashTable"] = None
 
     def _fetch(self) -> tuple:
@@ -130,7 +132,8 @@ class JoinHashTable:
     """Open-addressing index over the build side's 64-bit key hashes
     (TRINO_TPU_HASH_IMPL, ops/pallas_kernels.py): maps a probe hash to the
     contiguous run of matching rows in sorted-hash order, replacing the two
-    binary searches of probe_ranges with one kernel probe plus two gathers.
+    binary searches of probe_ranges_device with one kernel probe plus two
+    gathers.
     The (lo, counts) it yields are value-identical to the searchsorted
     implementation — both index the SAME sorted order — so every downstream
     expand/verify/gather program is shared between implementations, and
@@ -471,14 +474,6 @@ def probe_ranges_device(table: DeviceJoinTable, probe_keys: Sequence[tuple],
     return lo, counts, SG.async_scalar(total, "join.pair-total")
 
 
-def probe_ranges(table: DeviceJoinTable, probe_keys: Sequence[tuple],
-                 remaps: Sequence[Optional[np.ndarray]], live=None):
-    """Legacy wrapper around :func:`probe_ranges_device` that syncs the
-    candidate total to a host int — ONE blocking host sync per call."""
-    lo, counts, total = probe_ranges_device(table, probe_keys, remaps, live)
-    return lo, counts, int(total.get())
-
-
 # ---------------------------------------------------------------------------
 # padded-expand capacity planning
 
@@ -811,8 +806,8 @@ def run_pairs(table: DeviceJoinTable, lo, counts, total,
     5th element is the device build_id per pair slot for a regular join, or
     the (data, valid) semi-join mark when ``semi`` is set.
 
-    ``total`` may be a host int (legacy, picks ``cap`` exactly) or a device
-    scalar (sync-free; ``cap`` must then be given, chosen from build-side
+    ``total`` may be a host int (the overflow retry: picks ``cap`` exactly)
+    or a device scalar (``cap`` must then be given, chosen from build-side
     statistics — see :class:`ExpandPlanner`).  ``overflow`` is a device bool:
     True means the ``cap`` bucket truncated candidates and the batch must be
     re-run at a larger cap (results are otherwise a silent subset).
@@ -874,12 +869,12 @@ def run_pairs(table: DeviceJoinTable, lo, counts, total,
 # Profile-driven split (r5): gathering every output column at the probe
 # batch's full static width costs O(probe_lanes) random reads per column —
 # for a selective join that is the dominant device cost.  So the probe runs
-# as TWO programs around ONE combined scalar sync:
-#   A (`run_unique_ranges`)  — hash + binary search + exact verify; returns
-#       (match mask, build row per lane, match count, build max-run) with
-#       the count/max-run fetched together in a single sync.  The max-run
-#       rides along so the build table needs NO separate scalar fetch: a
-#       duplicate-key build (max_run > 1) falls back to the pair path.
+# as TWO programs with no blocking sync between them:
+#   A (`run_unique_ranges_device`)  — hash + binary search + exact verify;
+#       returns (match mask, build row per lane, match count), the count as
+#       an AsyncScalar that sizes LATER batches' compact bucket.  Whether the
+#       build is unique at all is the table's per-BUILD scalar fetch
+#       (`DeviceJoinTable.unique`): a duplicate-key build takes the pair path.
 #   B (`run_unique_gather`)  — if matches are sparse, compact (probe cols +
 #       build ids) to bucket(count) lanes FIRST and gather build columns at
 #       O(count); if dense, gather wide.  Residual and the RIGHT-join
@@ -890,7 +885,7 @@ def run_pairs(table: DeviceJoinTable, lo, counts, total,
 def _uranges_fn(num_keys: int, has_pvalid: tuple, has_remap: tuple,
                 has_live: bool):
     @program("join.uranges")
-    def fn(sorted_hash, perm, max_run, *flat):
+    def fn(sorted_hash, perm, *flat):
         i = 0
         pkeys, pkvalids = [], []
         for k in range(num_keys):
@@ -929,7 +924,7 @@ def _uranges_fn(num_keys: int, has_pvalid: tuple, has_remap: tuple,
             ok = ok & ~K._neq(pk, bk[bid])
         if live is not None:
             ok = ok & live
-        return ok, bid, jnp.sum(ok), max_run
+        return ok, bid, jnp.sum(ok)
 
     return fn
 
@@ -999,53 +994,10 @@ def run_unique_ranges_device(table: DeviceJoinTable, probe_keys, remaps,
     flat.extend(table.key_datas)
     if live is not None:
         flat.append(jnp.asarray(live))
-    mr_in = table._scalars[2] if not isinstance(table._scalars, tuple) \
-        else jnp.asarray(table._scalars[2])
-    ok, bid, cnt, _mr = _uranges_fn(
+    ok, bid, cnt = _uranges_fn(
         len(probe_keys), has_pvalid, has_remap, live is not None)(
-        table.sorted_hash, table.perm, mr_in, *flat)
+        table.sorted_hash, table.perm, *flat)
     return ok, bid, SG.async_scalar(cnt, "join.unique-count")
-
-
-def run_unique_ranges(table: DeviceJoinTable, probe_keys, remaps, live=None):
-    """Program A.  Returns (ok_live, bid, count:int, max_run:int) with ONE
-    combined scalar sync; max_run > 1 means the build was not unique and the
-    mask/ids must be discarded in favor of the pair path.  A dense build
-    takes the direct-address variant (uniqueness already proven: max_run
-    returns as 1 with no extra device work)."""
-    has_pvalid = tuple(v is not None for _, v in probe_keys)
-    has_remap = tuple(r is not None for r in remaps)
-    if table.dense is not None and len(probe_keys) == 1:
-        d, v = probe_keys[0]
-        flat = [jnp.asarray(d)]
-        if remaps[0] is not None:
-            flat.append(jnp.asarray(remaps[0]))
-        if v is not None:
-            flat.append(jnp.asarray(v))
-        if live is not None:
-            flat.append(jnp.asarray(live))
-        ok, bid, cnt = _dense_uranges_fn(
-            int(table.dense.shape[0]), table.dense_lo,
-            has_pvalid[0], has_remap[0], live is not None)(
-            table.dense, *flat)
-        return ok, bid, int(SG.fetch(cnt, "join.unique-count")), 1
-    flat = []
-    for (d, v), r in zip(probe_keys, remaps):
-        flat.append(jnp.asarray(d))
-        if r is not None:
-            flat.append(jnp.asarray(r))
-        if v is not None:
-            flat.append(jnp.asarray(v))
-    flat.extend(table.key_datas)
-    if live is not None:
-        flat.append(jnp.asarray(live))
-    mr_in = table._scalars[2] if not isinstance(table._scalars, tuple) \
-        else jnp.asarray(table._scalars[2])
-    ok, bid, cnt, mr = _uranges_fn(
-        len(probe_keys), has_pvalid, has_remap, live is not None)(
-        table.sorted_hash, table.perm, mr_in, *flat)
-    cnt_h, mr_h = SG.fetch((cnt, mr), "join.unique-count+run")
-    return ok, bid, int(cnt_h), int(mr_h)
 
 
 def _make_ugather_fn(cap: Optional[int], pair_types, pair_dicts,
@@ -1116,9 +1068,9 @@ def _make_ugather_fn(cap: Optional[int], pair_types, pair_dicts,
 
 def plan_unique_cap(n_lanes: int, count: Optional[int]) -> Optional[int]:
     """Compact-vs-wide decision for program B: compact to bucket(count) when
-    matches fill < 1/4 of the lanes, else stay wide (None).  ``count`` may be
-    an exact host int (legacy) or an estimate from a previous batch's
-    asynchronously-landed count (sync-free; overflow flag guards it)."""
+    matches fill < 1/4 of the lanes, else stay wide (None).  ``count`` is an
+    estimate from previous batches' asynchronously-landed counts (the
+    overflow flag guards it) or, in tests, the exact count."""
     if count is None:
         return None
     return K.bucket(max(count, 1)) if count * 4 <= n_lanes else None

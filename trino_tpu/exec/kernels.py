@@ -1,4 +1,4 @@
-"""Jitted relational kernels: grouped aggregation, join, sort, partition.
+"""Jitted relational kernels: grouped aggregation, key hashing, sort, partition.
 
 These are the TPU-native replacements for Trino's hand-specialized flat-memory
 data structures (reference: operator/FlatHash.java:42, operator/join/
@@ -44,8 +44,6 @@ __all__ = [
     "key_planes",
     "grouped_reduce",
     "sort_perm",
-    "build_join_table",
-    "probe_join_table",
     "hash_combine",
     "partition_assignments",
     "rle_fill",
@@ -1472,7 +1470,7 @@ def sort_perm(keys: Sequence[tuple]) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# join: sorted-build + binary-search probe
+# key hashing (the join itself lives in exec/join_exec.py)
 
 _M1 = np.uint64(0xBF58476D1CE4E5B9)
 _M2 = np.uint64(0x94D049BB133111EB)
@@ -1531,184 +1529,6 @@ def hash_combine(datas: Sequence) -> jnp.ndarray:
             x = x.astype(jnp.int64).astype(jnp.uint64)
         h = _mix64(h ^ (x + jnp.uint64(0x9E3779B97F4A7C15)))
     return h
-
-
-@program("kernels.sorted_hash")
-def _sorted_hash(h):
-    perm = jnp.argsort(h)
-    return h[perm], perm
-
-
-class JoinTable:
-    """Sorted-hash build side (the PagesHash/LookupSource equivalent)."""
-
-    __slots__ = ("sorted_hash", "perm", "key_datas", "_has_null", "num_rows")
-
-    def __init__(self, sorted_hash, perm, key_datas, has_null_key, num_rows):
-        self.sorted_hash = sorted_hash
-        self.perm = perm  # build row index per sorted-hash position
-        self.key_datas = key_datas  # original (unsorted) key arrays for verify
-        # host bool, or a device scalar fetched lazily on first access (its
-        # async copy usually lands before any probe asks)
-        self._has_null = has_null_key
-        self.num_rows = num_rows
-
-    @property
-    def has_null_key(self) -> bool:
-        if not isinstance(self._has_null, bool):
-            from . import syncguard as SG
-
-            self._has_null = bool(
-                SG.fetch(self._has_null, "kernels.has-null-key"))
-        return self._has_null
-
-
-def build_join_table(keys: Sequence[tuple], num_rows: Optional[int] = None) -> JoinTable:
-    """keys: [(data, valid|None), ...] over build rows.  Rows with any NULL
-    key never match (SQL equi-join) — they are excluded via a reserved hash.
-
-    Empty ``keys`` (with explicit ``num_rows``) builds a cross-join table:
-    every probe row matches every build row (nested-loop fallback, mirrors
-    operator/join/NestedLoopJoinOperator.java:45)."""
-    if not keys:
-        return JoinTable(None, None, [], False, int(num_rows or 0))
-    datas = [jnp.asarray(d) for d, _ in keys]
-    n = int(datas[0].shape[0]) if datas else 0
-    h = hash_combine(datas)
-    null_mask = None
-    for _, v in keys:
-        if v is not None:
-            nm = ~jnp.asarray(v)
-            null_mask = nm if null_mask is None else (null_mask | nm)
-    has_null = False
-    if null_mask is not None:
-        # stays a device scalar: building the table costs zero blocking
-        # syncs; JoinTable.has_null_key fetches lazily (async copy already
-        # in flight, usually landed by first access)
-        has_null = jnp.any(null_mask)
-        try:
-            has_null.copy_to_host_async()
-        except AttributeError:
-            pass
-        # reserved sentinel: max uint64 never produced for probes (probes with
-        # null keys are masked out before lookup)
-        h = jnp.where(null_mask, jnp.uint64(0xFFFFFFFFFFFFFFFF), h)
-    sh, perm = _sorted_hash(h)
-    return JoinTable(sh, perm, datas, has_null, n)
-
-
-@jit_memo("kernels._probe_ranges_fn")
-def _probe_ranges_fn():
-    @program("kernels.probe_ranges")
-    def fn(sorted_hash, probe_hash):
-        lo = searchsorted(sorted_hash, probe_hash, side="left")
-        hi = searchsorted(sorted_hash, probe_hash, side="right")
-        return lo, hi - lo
-
-    return fn
-
-
-_PAIR_PAD = 4  # speculative expand headroom over bucket(n_probe)
-
-
-@jit_memo("kernels._expand_fn")
-def _expand_fn(cap: int):
-    """Expansion kernel sized to a power-of-two bucket ``cap`` >= total so
-    varying per-batch match counts reuse a handful of compiled programs;
-    slots >= total produce clamped garbage the caller slices off."""
-
-    @program("kernels.expand")
-    def fn(lo, counts, perm):
-        n = counts.shape[0]
-        ends = jnp.cumsum(counts)
-        starts = ends - counts
-        slot = jnp.arange(cap)
-        probe_id = jnp.clip(searchsorted(ends, slot, side="right"), 0, n - 1)
-        within = slot - starts[probe_id]
-        build_pos = lo[probe_id] + within
-        return probe_id, perm[jnp.clip(build_pos, 0, perm.shape[0] - 1)]
-
-    return fn
-
-
-def probe_join_table(
-    table: JoinTable, probe_keys: Sequence[tuple], live=None
-) -> tuple[np.ndarray, np.ndarray]:
-    """Returns (probe_idx, build_idx) pairs of ALL equi-matches, exactly
-    verified.  Caller layers inner/left/semi semantics on top.  ``live``
-    masks padded/filtered-out probe rows (they never match).
-
-    ``n_probe`` must be passed for the keyless (cross-join) table."""
-    if not table.key_datas:  # cross join
-        nb = table.num_rows
-        n_probe = probe_keys  # caller passes the row count in place of keys
-        assert isinstance(n_probe, int), "cross-join probe needs a row count"
-        return (np.repeat(np.arange(n_probe, dtype=np.int64), nb),
-                np.tile(np.arange(nb, dtype=np.int64), n_probe))
-    pdatas = [jnp.asarray(d) for d, _ in probe_keys]
-    n_probe = int(pdatas[0].shape[0])
-    if n_probe == 0 or table.num_rows == 0:
-        return np.empty(0, np.int64), np.empty(0, np.int64)
-    ph = hash_combine(pdatas)
-    pnull = None
-    for _, v in probe_keys:
-        if v is not None:
-            nm = ~jnp.asarray(v)
-            pnull = nm if pnull is None else (pnull | nm)
-    if pnull is not None:
-        # flip to a hash that cannot exist in the table's non-null region
-        ph = jnp.where(pnull, jnp.uint64(0xFFFFFFFFFFFFFFFE), ph)
-    lo, counts = _probe_ranges_fn()(table.sorted_hash, ph)
-    if pnull is not None:
-        counts = jnp.where(pnull, 0, counts)
-    if live is not None:
-        counts = jnp.where(jnp.asarray(live), counts, 0)
-    if table.has_null_key:
-        # sentinel region must never match
-        counts = jnp.where(ph == jnp.uint64(0xFFFFFFFFFFFFFFFF), 0, counts)
-    from . import syncguard as SG
-
-    total_dev = jnp.sum(counts)
-    if os.environ.get("TRINO_TPU_LEGACY_EXPAND") == "1":
-        # legacy two-fetch expand: block on the exact candidate total, size
-        # the bucket from it, then fetch the verified pairs (kept for
-        # equivalence testing against the padded single-fetch path)
-        total = int(SG.fetch(total_dev, "kernels.pair-total"))
-        if total == 0:
-            return np.empty(0, np.int64), np.empty(0, np.int64)
-        probe_id, build_id = _expand_fn(bucket(total))(lo, counts, table.perm)
-        probe_id, build_id = probe_id[:total], build_id[:total]
-        ok = jnp.ones((total,), jnp.bool_)
-        for (pd, pv), bd in zip(probe_keys, table.key_datas):
-            p, b = jnp.asarray(pd)[probe_id], bd[build_id]
-            ok = ok & ~_neq(p, b)
-        keep, probe_id, build_id = SG.fetch(
-            (ok, probe_id, build_id), "kernels.pair-batch")
-        return probe_id[keep], build_id[keep]
-
-    def expand_verify(cap: int):
-        """Padded expand + exact verify (hash candidates -> equality on
-        every key column; float equality mirrors the grouping semantics:
-        NaN matches NaN).  Slots beyond the total are masked out."""
-        probe_id, build_id = _expand_fn(cap)(lo, counts, table.perm)
-        ok = jnp.arange(cap) < total_dev
-        for (pd, pv), bd in zip(probe_keys, table.key_datas):
-            p, b = jnp.asarray(pd)[probe_id], bd[build_id]
-            ok = ok & ~_neq(p, b)
-        return ok, probe_id, build_id
-
-    # padded single-fetch expand: speculate a bucket from the probe width,
-    # land the total WITH the verified pairs in one device->host round trip
-    # (the blocking total-sync this replaces was half the legacy path's syncs)
-    cap = bucket(max(n_probe, 1)) * _PAIR_PAD
-    total, keep, probe_id, build_id = SG.fetch(
-        (total_dev,) + expand_verify(cap), "kernels.pair-batch")
-    if int(total) > cap:  # rare: speculation too small — exact-size re-run
-        SG.count_overflow()
-        total, keep, probe_id, build_id = SG.fetch(
-            (total_dev,) + expand_verify(bucket(int(total))),
-            "kernels.pair-batch")
-    return probe_id[keep], build_id[keep]
 
 
 # ---------------------------------------------------------------------------

@@ -20,7 +20,6 @@ moves fixed-shape column arrays in and out of those programs.
 
 from __future__ import annotations
 
-import os
 import threading
 from typing import NamedTuple, Optional, Sequence
 
@@ -868,15 +867,6 @@ _COUNT_SYNC_S = 1.0e-3                # the blocking exec.compact-count fetch
 _COMPACT_S_PER_LANE = 3.6e-9          # kernels.compact: stable sort + gathers
 _MASKED_S_PER_LANE_REDUCTION = 150e-12       # small_agg: one more state column
 _MASKED_S_PER_LANE_GROUP_REDUCTION = 2.7e-12  # ... in one more group
-
-
-def _sync_free() -> bool:
-    """Sync-free probe/expand hot loop (default on): joins pick padded
-    expand capacities from build-side statistics and defer overflow checks
-    to async flag polls, so steady-state probe batches cross the device
-    boundary zero times.  ``TRINO_TPU_SYNC_FREE=0`` restores the legacy
-    one-scalar-sync-per-batch paths (equivalence tests, triage)."""
-    return os.environ.get("TRINO_TPU_SYNC_FREE", "1") != "0"
 
 
 def _compaction_candidate(batch: ColumnBatch) -> bool:
@@ -2326,19 +2316,18 @@ class LookupJoinOperator(Operator):
         if any(r is not None for r in remaps):
             # dictionary keys probe as remapped int32 CODES, never values
             self.encoding_stats.code_join_batches += 1
-        if table.num_rows:
+        # uniqueness comes from the per-BUILD scalar fetch (amortized over
+        # every probe batch); a duplicate-key build takes the pair path
+        if table.num_rows and table.unique:
             if self.join_type in ("INNER", "RIGHT"):
-                # speculative FK->PK probe: ranges+verify first, ONE combined
-                # (count, max-run) sync, then a width-adaptive gather; falls
-                # through to the pair path only when the build proved
-                # non-unique (exec/join_exec.py r5 design notes)
-                if self._add_inner_unique(probe, table, build, keys, remaps):
-                    return
-            elif table.unique:
+                # FK->PK probe: ranges+verify, then a width-adaptive gather
+                # (exec/join_exec.py r5 design notes)
+                self._add_inner_unique(probe, table, build, keys, remaps)
+            else:
                 # LEFT/SINGLE/FULL keep every probe row: the wide one-program
                 # path with zero per-batch syncs
                 self._add_unique_input(probe, table, build, keys, remaps)
-                return
+            return
         self._add_pairs(probe, table, build, keys, remaps)
 
     def _null_extended(self, probe: ColumnBatch, build: ColumnBatch,
@@ -2358,10 +2347,9 @@ class LookupJoinOperator(Operator):
     def _add_pairs(self, probe: ColumnBatch, table, build,
                    keys, remaps) -> None:
         """General (non-unique build) probe: candidate ranges + padded
-        expand.  Sync-free mode picks the expand bucket from build-side
-        statistics (ExpandPlanner) so the steady state never blocks on the
-        candidate total; TRINO_TPU_SYNC_FREE=0 keeps the legacy
-        one-total-sync-per-batch behavior."""
+        expand.  The expand bucket comes from build-side statistics
+        (ExpandPlanner) and overflow checks are deferred to async flag
+        polls, so the steady state never blocks on the candidate total."""
         from . import join_exec as JX
 
         need_matched = self.join_type in ("LEFT", "SINGLE", "FULL")
@@ -2380,11 +2368,10 @@ class LookupJoinOperator(Operator):
                       + [c.type for c in build.columns])
         pair_dicts = ([c.dictionary for c in probe.columns]
                       + [c.dictionary for c in build.columns])
-        sf = _sync_free()
 
         def commit(res) -> None:
             pairs, ok, matched, maxc, build_id, _overflow = res
-            if self.join_type == "SINGLE" and sf:
+            if self.join_type == "SINGLE":
                 # scalar subquery: >1 match per probe row is a cardinality
                 # violation (EnforceSingleRowNode semantics).  The check
                 # stays a device scalar on the deferred error channel —
@@ -2408,26 +2395,6 @@ class LookupJoinOperator(Operator):
                     jnp.asarray(probe.live) & ~matched)
                 self._pending.append(
                     self._null_extended(probe, build, un_live))
-
-        if not sf:
-            # legacy: ONE blocking candidate-total sync picks the bucket
-            lo, counts, total = JX.probe_ranges(
-                table, keys, remaps, probe.live)
-            if not total:
-                if need_matched:  # nothing matched: all live rows pass
-                    self._pending.append(
-                        self._null_extended(probe, build, probe.live))
-                return
-            res = JX.run_pairs(
-                table, lo, counts, total, keys, remaps, probe_cols,
-                build_cols, pair_types, pair_dicts, self.residual,
-                need_matched)
-            if self.join_type == "SINGLE" and int(
-                    SG.fetch(res[3], "join.single-maxc")) > 1:
-                raise TrinoError(SUBQUERY_MULTIPLE_ROWS,
-                                 "scalar subquery returned multiple rows")
-            commit(res)
-            return
 
         with SG.hot_region():
             lo, counts, total_a = JX.probe_ranges_device(
@@ -2458,32 +2425,18 @@ class LookupJoinOperator(Operator):
             self._inflight.drain()
 
     def _add_inner_unique(self, probe: ColumnBatch, table, build,
-                          keys, remaps) -> bool:
-        """INNER/RIGHT probe against a (speculatively) unique build.
-        Returns False when the build turned out non-unique — the caller
-        falls back to the general pair path."""
+                          keys, remaps) -> None:
+        """INNER/RIGHT probe against a unique build: ranges and the match
+        count stay on device, the gather's width adapts to earlier batches'
+        counts."""
         from . import join_exec as JX
 
-        sf = _sync_free()
-        if sf:
-            # uniqueness comes from the per-BUILD scalar fetch (amortized
-            # over every probe batch); ranges + count stay on device
-            if not table.unique:
-                return False
-            if probe.num_rows == 0:
-                return True
-            ok_live, bid, cnt_a = JX.run_unique_ranges_device(
-                table, keys, remaps, probe.live)
-            cnt = None
-        else:
-            ok_live, bid, cnt, mr = JX.run_unique_ranges(
-                table, keys, remaps, probe.live)
-            if mr > 1:
-                return False
+        if probe.num_rows == 0:
+            return
+        ok_live, bid, cnt_a = JX.run_unique_ranges_device(
+            table, keys, remaps, probe.live)
         if self.join_type == "RIGHT":
             self._probe_dicts = [c.dictionary for c in probe.columns]
-        if cnt == 0:  # legacy only (sync-free never knows the exact count)
-            return True  # nothing matched; RIGHT epilogue emits build rows
         probe_cols = [(c.data, c.valid) for c in probe.columns]
         build_cols = [(c.data, c.valid) for c in build.columns]
         pair_types = ([c.type for c in probe.columns]
@@ -2510,13 +2463,6 @@ class LookupJoinOperator(Operator):
             self._pending.append(ColumnBatch(
                 self.output_names, left_cols + right_cols, live))
 
-        if not sf:
-            cap = JX.plan_unique_cap(probe.num_rows, cnt)
-            commit(JX.run_unique_gather(
-                table, ok_live, bid, cap, probe_cols, build_cols,
-                pair_types, pair_dicts, self.residual, need_bm))
-            return True
-
         with SG.hot_region():
             # compact-vs-wide from the previous batches' async-landed match
             # counts; the compact path's overflow flag guards the estimate
@@ -2530,7 +2476,7 @@ class LookupJoinOperator(Operator):
                 pair_types, pair_dicts, self.residual, need_bm)
             if cap is None:  # wide path cannot overflow
                 commit(res)
-                return True
+                return
 
             def retry():
                 # compact bucket overflowed: re-run wide (provably safe)
@@ -2542,7 +2488,6 @@ class LookupJoinOperator(Operator):
                 SG.async_scalar(res[4], "join.unique-overflow"),
                 res, retry, commit)
             self._inflight.drain()
-        return True
 
     def _add_unique_input(self, probe: ColumnBatch, table, build,
                           keys, remaps) -> None:
@@ -2745,15 +2690,6 @@ class SemiJoinOperator(Operator):
             mark = Column(BOOLEAN, mark_data, mark_valid)
             self._pending.append(ColumnBatch(
                 self.output_names, list(batch.columns) + [mark], batch.live))
-
-        if not _sync_free():
-            lo, counts, total = JX.probe_ranges(
-                table, keys, remaps, batch.live)
-            commit(JX.run_pairs(
-                table, lo, counts, total, keys, remaps, probe_cols,
-                build_cols, pair_types, pair_dicts, self.residual, False,
-                semi=semi))
-            return
 
         with SG.hot_region():
             lo, counts, total_a = JX.probe_ranges_device(

@@ -31,7 +31,7 @@ groups that can arrive.
 
 ``TRINO_TPU_FUSED_STAGE={auto,1,0}``: 0 restores today's per-operator +
 collective-exchange path bit-for-bit (same knob pattern as
-TRINO_TPU_SYNC_FREE / TRINO_TPU_HASH_IMPL).
+TRINO_TPU_HASH_IMPL).
 """
 
 from __future__ import annotations

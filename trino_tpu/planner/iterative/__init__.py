@@ -9,8 +9,8 @@ subtrees, and the :mod:`driver` explores groups to fixpoint in named
 phases, recording every firing in a :class:`~trino_tpu.planner.
 iterative.rule.Trace` that EXPLAIN surfaces.
 
-``optimize_iterative`` is the entry point wired behind
-``TRINO_TPU_OPTIMIZER=iterative`` in planner/optimizer.py.
+``optimize_iterative`` is the entry point ``planner/optimizer.optimize``
+calls.
 """
 
 from .driver import IterativeOptimizer, default_phases, last_report, optimize_iterative

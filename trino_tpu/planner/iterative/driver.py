@@ -8,9 +8,9 @@ in named phases (decorrelate -> simplify -> aggregations -> reorder ->
 cleanup), each a full fixpoint pass over the memo.
 
 ``optimize_iterative`` is the planner entry point: it runs the phases,
-then hands the extracted tree to the legacy final passes (column
-pruning, scan-constraint attachment, limit-into-scan) that both
-optimizer modes share, and publishes the firing trace for EXPLAIN.
+then hands the extracted tree to the final passes of planner/optimizer.py
+(column pruning, scan-constraint attachment, limit-into-scan) and
+publishes the firing trace for EXPLAIN.
 """
 
 from __future__ import annotations
@@ -141,7 +141,7 @@ def _assert_decorrelated(node: PlanNode) -> None:
 
 def optimize_iterative(root: PlanNode, catalog) -> PlanNode:
     """Full iterative pipeline: rule phases over the memo, then the
-    shared legacy final passes; publishes the trace for EXPLAIN."""
+    final passes; publishes the trace for EXPLAIN."""
     from .. import history as hbo
     from .. import optimizer as opt
 

@@ -2,10 +2,10 @@
 TransformCorrelatedScalarSubquery.java,
 TransformCorrelatedInPredicateToJoin.java).
 
-The logical planner emits a :class:`CorrelatedJoin` placeholder when the
-iterative optimizer is active; these rules lower it to the same join
-shapes the legacy planner builds directly — but as rules, so the
-subquery side participates in simplification/reordering first."""
+The logical planner emits a :class:`CorrelatedJoin` placeholder for an IN
+predicate and for a correlated scalar aggregate; these rules lower it to a
+null-aware SemiJoin or a LEFT join — as rules, so the subquery side
+participates in simplification/reordering first."""
 
 from __future__ import annotations
 

@@ -1,7 +1,7 @@
 """Column-pruning rules (reference: iterative/rule/
 PruneJoinColumns.java / PruneJoinChildrenColumns.java).
 
-The legacy ``_prune`` pass already narrows scans bottom-up; this rule
+The final ``_prune`` pass already narrows scans bottom-up; this rule
 covers the shape it misses inside the memo — a Project over a Join that
 carries channels no one above needs — by narrowing the join inputs with
 identity sub-projections before the fragmenter materializes exchanges."""

@@ -2,22 +2,21 @@
 ReorderJoins.java + JoinEnumerator, and
 DetermineJoinDistributionType.java).
 
-``ReorderJoins`` re-expresses the legacy optimizer's filter-cluster
-machinery as a rule: flatten a maximal INNER/CROSS join cluster (with
-the Filter above it, when present) into leaves + conjuncts, push
-single-leaf conjuncts into their leaf, and rebuild a left-deep spine.
+``ReorderJoins`` flattens a maximal INNER/CROSS join cluster (with the
+Filter above it, when present) into leaves + conjuncts, pushes single-leaf
+conjuncts into their leaf, and rebuilds a left-deep spine.
 Two orderers share the expansion cost model (|A><B| ~ |A|*|B| /
 max key NDV — cost/JoinStatsRule): exhaustive DP over connected
 subsets when the cluster has at most TRINO_TPU_JOIN_REORDER_DP_LIMIT
 leaves (JoinEnumerator's memoized search, minimizing the sum of
-intermediate output estimates), and the legacy greedy otherwise.  Both
+intermediate output estimates), and a greedy spine otherwise.  Both
 prefer history-observed row counts over catalog estimates when a
 HistoryProvider is active — the "second run plans right" loop.
 
-Unlike the legacy pass, leaves are NOT recursively rewritten here — the
-driver explores nested groups with the same rule set; to keep a cluster
-from being re-flattened at every nested join group, a firing records the
-repr of every join subtree it produced and the rule skips those."""
+Leaves are NOT recursively rewritten here — the driver explores nested
+groups with the same rule set; to keep a cluster from being re-flattened
+at every nested join group, a firing records the repr of every join
+subtree it produced and the rule skips those."""
 
 from __future__ import annotations
 
@@ -58,8 +57,9 @@ def _cluster_top(n: PlanNode, ctx: Context) -> bool:
 
 
 def _flatten_cluster(node: PlanNode):
-    """Legacy _flatten without the recursive leaf rewrite: leaves stay
-    whatever subtree the memo holds there (Filters included)."""
+    """Collect the cluster's leaves with their ORIGINAL channel offsets,
+    and its join keys and residuals as conjuncts over those channels.
+    Leaves stay whatever subtree the memo holds there (Filters included)."""
     leaves: list[tuple[PlanNode, int]] = []
     conjuncts: list[RowExpression] = []
 
@@ -120,7 +120,7 @@ def _dp_order(n: int, est: list[float], edges, out_est) -> list[int]:
 
 
 def _greedy_order(n: int, est: list[float], edges, out_est) -> list[int]:
-    """The legacy greedy: spine = largest relation, then repeatedly the
+    """The greedy orderer: spine = largest relation, then repeatedly the
     connected relation with the smallest estimated join output."""
     order = [max(range(n), key=lambda i: est[i])]
     remaining = set(range(n)) - set(order)

@@ -1143,15 +1143,10 @@ class LogicalPlanner:
         mark_name = f"_mark{src.width}"
         names = tuple(src.node.output_names) + (mark_name,)
         types = tuple(src.node.output_types) + (BOOLEAN,)
-        from .optimizer import optimizer_mode
-        if optimizer_mode() == "iterative":
-            # leave a CorrelatedJoin placeholder for the decorrelate rules
-            # (TransformCorrelatedInPredicate lowers it to this SemiJoin)
-            sj: PlanNode = CorrelatedJoin(names, types, src.node, sub.node,
-                                          "in", (s_ch,), (0,))
-        else:
-            sj = SemiJoin(names, types, src.node, sub.node, (s_ch,), (0,),
-                          negated=False, residual=None, null_aware=True)
+        # a CorrelatedJoin placeholder for the decorrelate rules
+        # (TransformCorrelatedInPredicate lowers it to a null-aware SemiJoin)
+        sj = CorrelatedJoin(names, types, src.node, sub.node,
+                            "in", (s_ch,), (0,))
         new_rel = RelationPlan(sj, src.qualifiers + [None])
         mark = InputRef(BOOLEAN, new_rel.width - 1)
         ir = Call(BOOLEAN, "$not", (mark,)) if node.negated else mark
@@ -1321,16 +1316,10 @@ class LogicalPlanner:
         och, src = _as_channels(outer_keys, rel)
         names = tuple(src.node.output_names) + proj.output_names
         types = tuple(src.node.output_types) + proj.output_types
-        from .optimizer import optimizer_mode
-        if optimizer_mode() == "iterative":
-            # placeholder for TransformCorrelatedScalarSubquery, which
-            # lowers to exactly the LEFT join the legacy branch builds
-            jn: PlanNode = CorrelatedJoin(names, types, src.node, proj,
-                                          "scalar_agg", tuple(och),
-                                          tuple(range(nkeys)))
-        else:
-            jn = Join(names, types, src.node, proj, "LEFT",
-                      tuple(och), tuple(range(nkeys)), None)
+        # placeholder for TransformCorrelatedScalarSubquery, which lowers
+        # it to a LEFT join on the correlation keys
+        jn = CorrelatedJoin(names, types, src.node, proj,
+                            "scalar_agg", tuple(och), tuple(range(nkeys)))
         new_rel = RelationPlan(jn, src.qualifiers + [None] * (nkeys + 2))
         value_ref: RowExpression = InputRef(types[-2], new_rel.width - 2)
         mark_ref = InputRef(BIGINT, new_rel.width - 1)

@@ -1,18 +1,21 @@
-"""Plan optimizer: cross-join flattening + stats-greedy join ordering,
-filter pushdown, join distribution choice, column pruning.
+"""What the iterative rule engine (planner/iterative/) shares: the cost
+model, the channel helpers and the final passes.
 
-The deliberately small stand-in for sql/planner/PlanOptimizers' 228 iterative
-rules (reference: iterative/rule/ReorderJoins.java,
-DetermineJoinDistributionType.java, PushPredicateIntoTableScan.java,
-PruneUnreferencedOutputs.java).  Rules operate on channel indices, so every
-rewrite returns (new_node, mapping old-channel -> new-channel) and parents
-remap their expressions — the moral equivalent of Trino's symbol mapper.
+``optimize`` runs the rule engine (the stand-in for sql/planner/
+PlanOptimizers' 228 iterative rules).  This module keeps what the rules and
+the engine's driver import:
 
-Join ordering: comma/CROSS-join clusters under a Filter are flattened into a
-join graph; the spine starts at the largest estimated relation and greedily
-joins the smallest connected relation next (build sides stay small); every
-available equality edge becomes a hash-join key, including cycle-closing
-edges (Q5's c_nationkey = s_nationkey).
+- the cost model — ``estimate_rows``, ``_channel_ndv``,
+  ``_conjunct_selectivity`` and ``_choose_distribution`` (reference:
+  cost/JoinStatsRule, DetermineJoinDistributionType.java), each preferring
+  history-observed statistics when a provider is given;
+- the channel helpers — plan nodes address columns by channel index, so a
+  rewrite remaps expressions (``_remap_expr``, ``_shift``, ``_split_and``,
+  ``_conjoin``, the leaf/spine remaps of ``rules/reorder.py``): the moral
+  equivalent of Trino's symbol mapper;
+- ``final_passes`` — column pruning (PruneUnreferencedOutputs.java),
+  advisory scan constraints (PushPredicateIntoTableScan.java) and
+  LIMIT-into-scan, run once on the tree the engine extracts.
 """
 
 from __future__ import annotations
@@ -25,7 +28,6 @@ from ..spi.types import BOOLEAN
 from ..sql.ir import Call, InputRef, Literal, RowExpression, walk
 from .plan import (
     Aggregate,
-    DistinctLimit,
     Exchange,
     Filter,
     GroupId,
@@ -48,7 +50,7 @@ from .plan import (
     Window,
 )
 
-__all__ = ["optimize", "estimate_rows", "optimizer_mode", "final_passes"]
+__all__ = ["optimize", "estimate_rows", "final_passes"]
 
 _BROADCAST_LIMIT = 2_000_000  # build rows below this replicate to every task
 
@@ -59,31 +61,14 @@ _BROADCAST_LIMIT = 2_000_000  # build rows below this replicate to every task
 _EXTRA_JOIN_CLAUSE_SEL = 0.9
 
 
-def optimizer_mode() -> str:
-    """iterative | legacy (TRINO_TPU_OPTIMIZER; legacy is the bit-for-bit
-    single-pass pipeline below)."""
-    from ..spi import knobs
-
-    mode = knobs.get_str("TRINO_TPU_OPTIMIZER").strip().lower()
-    return mode if mode in ("iterative", "legacy") else "iterative"
-
-
 def optimize(root: PlanNode, catalog: Catalog) -> PlanNode:
-    if optimizer_mode() == "iterative":
-        from .iterative import optimize_iterative
+    from .iterative import optimize_iterative
 
-        return optimize_iterative(root, catalog)
-    return _optimize_legacy(root, catalog)
-
-
-def _optimize_legacy(root: PlanNode, catalog: Catalog) -> PlanNode:
-    node, mapping = _rewrite(root, catalog)
-    assert mapping == list(range(len(node.output_types))), "root remap escaped"
-    return final_passes(node, catalog)
+    return optimize_iterative(root, catalog)
 
 
 def final_passes(node: PlanNode, catalog: Catalog) -> PlanNode:
-    """Mapping-free tail passes both optimizer modes share: column pruning,
+    """Mapping-free tail passes the rule engine ends with: column pruning,
     advisory scan constraints, LIMIT-into-scan."""
     node = _prune(node, set(range(len(node.output_types))))[0]
     node = _attach_scan_constraints(node)
@@ -314,156 +299,6 @@ def estimate_rows(node: PlanNode, catalog: Catalog, history=None) -> float:
     return 1000.0
 
 
-# --------------------------------------------------------------------------
-# main rewrite (returns node + channel mapping old->new)
-
-
-def _identity(node: PlanNode) -> list[int]:
-    return list(range(len(node.output_types)))
-
-
-def _rewrite(node: PlanNode, catalog: Catalog) -> tuple[PlanNode, list[int]]:
-    if isinstance(node, Filter):
-        return _rewrite_filter_cluster(node, catalog)
-    if isinstance(node, Join) and node.join_type in ("CROSS", "INNER"):
-        return _rewrite_filter_cluster(node, catalog)
-
-    if isinstance(node, (Output,)):
-        child, m = _rewrite(node.source, catalog)
-        if m != list(range(len(child.output_types))):
-            child = _restore_layout(child, m, node.source)
-        return replace(node, source=child), _identity(node)
-
-    if isinstance(node, Project):
-        child, m = _rewrite(node.source, catalog)
-        exprs = tuple(_remap_expr(e, m) for e in node.expressions)
-        return replace(node, source=child, expressions=exprs), _identity(node)
-
-    if isinstance(node, Aggregate):
-        child, m = _rewrite(node.source, catalog)
-        return (
-            replace(
-                node,
-                source=child,
-                group_keys=tuple(m[k] for k in node.group_keys),
-                aggregates=tuple(
-                    replace(a, arg=m[a.arg] if a.arg >= 0 else -1)
-                    for a in node.aggregates
-                ),
-            ),
-            _identity(node),
-        )
-
-    if isinstance(node, Join):  # LEFT / SINGLE
-        left, lm = _rewrite(node.left, catalog)
-        right, rm = _rewrite(node.right, catalog)
-        lw_old = len(node.left.output_types)
-        lw_new = len(left.output_types)
-        mapping = [lm[i] for i in range(lw_old)] + [rm[i - lw_old] + lw_new
-                                                   for i in range(lw_old, lw_old + len(rm))]
-        names = tuple(left.output_names) + tuple(right.output_names)
-        types = tuple(left.output_types) + tuple(right.output_types)
-        residual = (_remap_expr(node.residual, mapping)
-                    if node.residual is not None else None)
-        out = replace(
-            node, output_names=names, output_types=types, left=left, right=right,
-            left_keys=tuple(lm[k] for k in node.left_keys),
-            right_keys=tuple(rm[k] for k in node.right_keys),
-            residual=residual,
-            distribution=_choose_distribution(right, catalog, node.join_type),
-        )
-        return out, mapping
-
-    if isinstance(node, SemiJoin):
-        src, sm = _rewrite(node.source, catalog)
-        filt, fm = _rewrite(node.filter_source, catalog)
-        sw_old = len(node.source.output_types)
-        sw_new = len(src.output_types)
-        mapping = [sm[i] for i in range(sw_old)] + [sw_new]  # mark at end
-        residual = None
-        if node.residual is not None:
-            # residual layout: source ++ filter channels
-            rmap = sm + [fm[i] + sw_new for i in range(len(fm))]
-            residual = _remap_expr(node.residual, rmap)
-        names = tuple(src.output_names) + (node.output_names[-1],)
-        types = tuple(src.output_types) + (BOOLEAN,)
-        out = replace(
-            node, output_names=names, output_types=types,
-            source=src, filter_source=filt,
-            source_keys=tuple(sm[k] for k in node.source_keys),
-            filter_keys=tuple(fm[k] for k in node.filter_keys),
-            residual=residual,
-        )
-        return out, mapping
-
-    if isinstance(node, (Sort, TopN, Limit, TableWriter, Exchange,
-                         DistinctLimit, Replicate)):
-        child, m = _rewrite(node.source, catalog)
-        kwargs = dict(source=child, output_names=child.output_names,
-                      output_types=child.output_types)
-        if isinstance(node, (Sort, TopN)):
-            kwargs["keys"] = tuple(replace(k, channel=m[k.channel]) for k in node.keys)
-        if isinstance(node, Exchange):
-            kwargs["partition_keys"] = tuple(m[k] for k in node.partition_keys)
-        if isinstance(node, Replicate):
-            kwargs["count_channel"] = m[node.count_channel]
-        return replace(node, **kwargs), m
-
-    if isinstance(node, GroupId):
-        child, m = _rewrite(node.source, catalog)
-        out = replace(node, source=child,
-                      key_channels=tuple(m[c] for c in node.key_channels),
-                      passthrough=tuple(m[c] for c in node.passthrough))
-        return out, _identity(node)
-
-    if isinstance(node, Unnest):
-        child, m = _rewrite(node.source, catalog)
-        out = replace(node, source=child,
-                      replicate=tuple(m[c] for c in node.replicate),
-                      unnest_channels=tuple(m[c] for c in node.unnest_channels))
-        return out, _identity(node)
-
-    if isinstance(node, MatchRecognize):
-        child, m = _rewrite(node.source, catalog)
-        if m != list(range(len(child.output_types))):
-            child = _restore_layout(child, m, node.source)
-        return replace(node, source=child), _identity(node)
-
-    if isinstance(node, Window):
-        child, m = _rewrite(node.source, catalog)
-        sw_old = len(node.source.output_types)
-        sw_new = len(child.output_types)
-        funcs = tuple(
-            replace(f, args=tuple(m[a] for a in f.args))
-            for f in node.functions)
-        names = tuple(child.output_names) + tuple(
-            node.output_names[sw_old + j] for j in range(len(funcs)))
-        types = tuple(child.output_types) + tuple(f.type for f in funcs)
-        out = replace(
-            node, output_names=names, output_types=types, source=child,
-            partition_keys=tuple(m[k] for k in node.partition_keys),
-            order_keys=tuple(replace(k, channel=m[k.channel])
-                             for k in node.order_keys),
-            functions=funcs)
-        mapping = [m[i] for i in range(sw_old)] + [
-            sw_new + j for j in range(len(funcs))]
-        return out, mapping
-
-    if isinstance(node, Union):
-        new_sources = []
-        for s in node.sources:
-            child, m = _rewrite(s, catalog)
-            if m != list(range(len(child.output_types))):
-                child = _restore_layout(child, m, s)
-            new_sources.append(child)
-        return replace(node, sources=tuple(new_sources)), _identity(node)
-
-    if isinstance(node, (TableScan, Values, TableFunctionScan)):
-        return node, _identity(node)
-
-    raise NotImplementedError(f"optimizer: {type(node).__name__}")
-
-
 def _restore_layout(child: PlanNode, mapping: list[int], original: PlanNode) -> PlanNode:
     exprs = tuple(InputRef(t, mapping[i]) for i, t in enumerate(original.output_types))
     return Project(tuple(original.output_names), tuple(original.output_types),
@@ -514,31 +349,6 @@ def _shift(e: RowExpression, by: int) -> RowExpression:
     return e
 
 
-def _flatten(node: PlanNode, catalog: Catalog):
-    """Collect cluster leaves with their ORIGINAL channel offsets."""
-    leaves: list[tuple[PlanNode, list[int]]] = []
-    conjuncts: list[RowExpression] = []
-
-    def go(n: PlanNode, offset: int) -> int:
-        """Returns width of n's original layout; appends leaves/conjuncts."""
-        if isinstance(n, Join) and n.join_type in ("CROSS", "INNER"):
-            lw = go(n.left, offset)
-            rw = go(n.right, offset + lw)
-            for lk, rk in zip(n.left_keys, n.right_keys):
-                conjuncts.append(Call(BOOLEAN, "eq", (
-                    InputRef(n.left.output_types[lk], offset + lk),
-                    InputRef(n.right.output_types[rk], offset + lw + rk))))
-            if n.residual is not None:
-                conjuncts.append(_shift(n.residual, offset))
-            return lw + rw
-        leaf, m = _rewrite(n, catalog)
-        leaves.append((leaf, offset, m))
-        return len(n.output_types)
-
-    total = go(node, 0)
-    return leaves, conjuncts, total
-
-
 def _hoist_common_or(e: RowExpression) -> list[RowExpression]:
     """(A ∧ X) ∨ (A ∧ Y) → [A, X ∨ Y] — extract conjuncts common to every
     OR arm (reference: sql/planner/iterative/rule/... ExtractCommonPredicates
@@ -558,189 +368,6 @@ def _hoist_common_or(e: RowExpression) -> list[RowExpression]:
         out.append(Call(BOOLEAN, "$or",
                         tuple(_conjoin(r) for r in reduced)))
     return out
-
-
-def _rewrite_filter_cluster(node: PlanNode, catalog: Catalog):
-    if isinstance(node, Filter):
-        cluster_root = node.source
-        preds = [p for c in _split_and(node.predicate)
-                 for p in _hoist_common_or(c)]
-    else:
-        cluster_root = node
-        preds = []
-    if not (isinstance(cluster_root, Join)
-            and cluster_root.join_type in ("CROSS", "INNER")):
-        # plain filter over a non-join child
-        child, m = _rewrite(cluster_root, catalog)
-        if not isinstance(node, Filter):
-            return child, m
-        pred = _conjoin([_remap_expr(p, m) for p in preds])
-        out = Filter(child.output_names, child.output_types, child, pred)
-        return out, m
-
-    leaves, conjuncts, total_width = _flatten(cluster_root, catalog)
-    conjuncts = conjuncts + preds
-
-    # original channel -> (leaf idx, local channel through leaf's mapping)
-    chan_leaf: dict[int, tuple[int, int]] = {}
-    for li, (leaf, offset, m) in enumerate(leaves):
-        for local_old, local_new in enumerate(m):
-            chan_leaf[offset + local_old] = (li, local_new)
-
-    def leaf_of(e: RowExpression) -> Optional[int]:
-        ls = {chan_leaf[i][0] for i in _refs(e)}
-        return ls.pop() if len(ls) == 1 else None
-
-    # push single-leaf conjuncts into the leaf
-    leaf_nodes = [leaf for (leaf, _, _) in leaves]
-    leaf_filters: list[list[RowExpression]] = [[] for _ in leaves]
-    edges: list[tuple[int, int, RowExpression, RowExpression]] = []
-    residual: list[RowExpression] = []
-    for c in conjuncts:
-        refs = _refs(c)
-        involved = {chan_leaf[i][0] for i in refs}
-        if len(involved) == 1:
-            li = involved.pop()
-            local = _remap_to_leaf(c, chan_leaf, li)
-            leaf_filters[li].append(local)
-        elif (isinstance(c, Call) and c.name == "eq" and len(involved) == 2
-              and _single_leaf(c.args[0], chan_leaf) is not None
-              and _single_leaf(c.args[1], chan_leaf) is not None):
-            a, b = c.args
-            la, lb = _single_leaf(a, chan_leaf), _single_leaf(b, chan_leaf)
-            edges.append((la, lb,
-                          _remap_to_leaf(a, chan_leaf, la),
-                          _remap_to_leaf(b, chan_leaf, lb)))
-        else:
-            residual.append(c)
-
-    for li, filters in enumerate(leaf_filters):
-        if filters:
-            leaf = leaf_nodes[li]
-            leaf_nodes[li] = Filter(leaf.output_names, leaf.output_types,
-                                    leaf, _conjoin(filters))
-
-    est = [estimate_rows(l, catalog) for l in leaf_nodes]
-
-    # greedy: spine = largest; next = the connected relation with the
-    # SMALLEST ESTIMATED JOIN OUTPUT (|A><B| ~ |A|*|B| / max key NDV —
-    # cost/JoinStatsRule's core rule).  Size-only greediness exploded Q5 at
-    # scale: customer joined the spine over the 25-value nationkey edge
-    # (fan-out x6000) before orders made the custkey edge available.
-    order = [max(range(len(leaf_nodes)), key=lambda i: est[i])]
-    remaining = set(range(len(leaf_nodes))) - set(order)
-    spine_est = est[order[0]]
-
-    ndv_cache: dict[tuple[int, int], Optional[float]] = {}
-
-    def _leaf_ndv(leaf: int, expr) -> Optional[float]:
-        if not isinstance(expr, InputRef):
-            return None
-        key = (leaf, expr.index)
-        if key not in ndv_cache:
-            ndv_cache[key] = _channel_ndv(leaf_nodes[leaf], expr.index,
-                                          catalog)
-        return ndv_cache[key]
-
-    def _edge_ndv(i: int) -> Optional[float]:
-        """max(NDV) over BOTH endpoints of the best usable edge
-        (|A><B| ~ |A|*|B| / max(ndv_A, ndv_B) — cost/JoinStatsRule)."""
-        best: Optional[float] = None
-        for (a, b, ea, eb) in edges:
-            if a in order and b == i:
-                se, ce = ea, eb
-                sl = a
-            elif b in order and a == i:
-                se, ce = eb, ea
-                sl = b
-            else:
-                continue
-            nd = max((x for x in (_leaf_ndv(i, ce), _leaf_ndv(sl, se))
-                      if x), default=None)
-            if nd:
-                best = max(best or 0.0, nd)
-        return best
-
-    # key expressions must be channels; all edge endpoint exprs that are
-    # plain InputRefs can be used directly, others appended via projection.
-    while remaining:
-        connected = [
-            i for i in remaining
-            if any((a in order and b == i) or (b in order and a == i)
-                   for (a, b, _, _) in edges)
-        ]
-        if connected:
-
-            def out_est(i: int) -> float:
-                nd = _edge_ndv(i)
-                if nd:
-                    return spine_est * est[i] / max(nd, 1.0)
-                # keyed join with unknown NDV: PK-FK-ish assumption
-                return max(spine_est, est[i])
-
-            outs = {i: out_est(i) for i in connected}
-            pick = min(connected, key=lambda i: (outs[i], est[i]))
-            spine_est = max(outs[pick], 1.0)
-        else:
-            pick = min(remaining, key=lambda i: est[i])
-            spine_est = spine_est * max(est[pick], 1.0)  # cross join
-        order.append(pick)
-        remaining.discard(pick)
-
-    # build the tree left-deep; track mapping (leaf idx, local ch) -> spine ch
-    spine = leaf_nodes[order[0]]
-    pos: dict[tuple[int, int], int] = {
-        (order[0], i): i for i in range(len(spine.output_types))
-    }
-    used_edges = set()
-    for step in range(1, len(order)):
-        li = order[step]
-        right = leaf_nodes[li]
-        lkeys, rkeys = [], []
-        for ei, (a, b, ea, eb) in enumerate(edges):
-            if ei in used_edges:
-                continue
-            if a in order[:step] and b == li:
-                sa, rb = ea, eb
-            elif b in order[:step] and a == li:
-                sa, rb = eb, ea
-                a, b = b, a
-            else:
-                continue
-            used_edges.add(ei)
-            # spine-side expr: remap leaf-local -> spine channels
-            sa_spine = _remap_leaf_to_spine(sa, a, pos)
-            lkeys.append(sa_spine)
-            rkeys.append(rb)
-        lch, spine = _exprs_as_channels(lkeys, spine)
-        rch, right = _exprs_as_channels(rkeys, right)
-        names = tuple(spine.output_names) + tuple(right.output_names)
-        types = tuple(spine.output_types) + tuple(right.output_types)
-        sw = len(spine.output_types)
-        jt = "INNER" if lch else "CROSS"
-        spine = Join(names, types, spine, right, jt, tuple(lch), tuple(rch),
-                     None, distribution=_choose_distribution(right, catalog))
-        for i in range(len(right.output_types)):
-            pos[(li, i)] = sw + i
-
-    # residual conjuncts over the final spine
-    if residual:
-        def remap_residual(e: RowExpression) -> RowExpression:
-            if isinstance(e, InputRef):
-                li, local = chan_leaf[e.index]
-                return InputRef(e.type, pos[(li, local)])
-            if isinstance(e, Call):
-                return Call(e.type, e.name, tuple(remap_residual(a) for a in e.args))
-            return e
-        spine = Filter(spine.output_names, spine.output_types, spine,
-                       _conjoin([remap_residual(r) for r in residual]))
-
-    # overall mapping: original concat channel -> spine channel
-    mapping = []
-    for i in range(total_width):
-        li, local = chan_leaf.get(i, (None, None))
-        mapping.append(pos.get((li, local)) if li is not None else None)
-    return spine, mapping
 
 
 def _remap_leaf_to_spine(e: RowExpression, leaf_idx: int,

@@ -717,16 +717,13 @@ class StandaloneQueryRunner:
         inner = stmt.statement
         plan = self._plan_stmt(inner)
         lines = plan_text(plan).splitlines()
-        # rule-firing trace of the iterative optimizer run that shaped this
-        # plan (planner/iterative/driver.py publishes it per-thread)
-        from .planner.optimizer import optimizer_mode
+        # rule-firing trace of the optimizer run that shaped this plan
+        # (planner/iterative/driver.py publishes it per-thread)
+        from .planner.iterative import last_report
 
-        if optimizer_mode() == "iterative":
-            from .planner.iterative import last_report
-
-            trace = last_report()
-            if trace is not None:
-                lines.extend(trace.lines(timings=stmt.analyze))
+        trace = last_report()
+        if trace is not None:
+            lines.extend(trace.lines(timings=stmt.analyze))
         if stmt.analyze:
             import time as _time
 

@@ -180,9 +180,6 @@ _DECLARATIONS = (
          "Rotated journal generations kept."),
     Knob("TRINO_TPU_JOURNAL_MAX_BYTES", "int", "4194304",
          "Journal rotate threshold per file."),
-    Knob("TRINO_TPU_LEGACY_EXPAND", "bool", "0",
-         "1 restores the legacy per-run join expand (pre padded "
-         "single-fetch)."),
     Knob("TRINO_TPU_MESH_SHAPE", "str", "",
          "Mesh-shape override for resident-plan programs (\"8\" or "
          "\"2x4\"); the dimension product caps the mesh width a plan may "
@@ -190,11 +187,6 @@ _DECLARATIONS = (
     Knob("TRINO_TPU_OOM_POLICY", "enum", "largest_query",
          "Victim selection policy for the cluster low-memory killer.",
          choices=("largest_query", "lowest_priority", "youngest")),
-    Knob("TRINO_TPU_OPTIMIZER", "enum", "iterative",
-         "Logical optimizer implementation: iterative is the "
-         "memo/fixpoint rule engine (planner/iterative/); legacy is the "
-         "bit-for-bit single-pass rewrite pipeline.",
-         choices=("iterative", "legacy")),
     Knob("TRINO_TPU_PALLAS", "bool", "1",
          "Master switch for Pallas kernels; 0 forces the jnp fallbacks."),
     Knob("TRINO_TPU_PLAN_CACHE", "bool", "1",
@@ -274,9 +266,6 @@ _DECLARATIONS = (
     Knob("TRINO_TPU_STAGE_DEVICE", "bool", "1",
          "Double-buffered device staging of coalesced scan batches; 0 "
          "leaves batches on host until the operator touches them."),
-    Knob("TRINO_TPU_SYNC_FREE", "bool", "1",
-         "Sync-free probe/expand hot loop; 0 is the legacy per-batch "
-         "host-sync path."),
     Knob("TRINO_TPU_TEST_BOOT_FAIL", "bool", "0",
          "Test-only: worker processes exit at boot to exercise the boot "
          "timeout path."),

@@ -250,6 +250,19 @@ class SqliteOracle:
             self.db.executemany(f'insert into "{name}" values ({placeholders})', rows)
         self.db.commit()
 
+    def load_connector_tables(self, conn, tables: Iterable[str]) -> None:
+        """Every row of ``tables`` as the connector serves them."""
+        for t in tables:
+            cols = conn.get_table_schema(t).column_names()
+            batches = []
+            for split in conn.get_splits(t, 2, 1):
+                src = conn.create_page_source(split, cols)
+                while not src.is_finished():
+                    b = src.get_next_batch()
+                    if b is not None:
+                        batches.append(b)
+            self.load_table(t, batches)
+
     def query(self, sql: str) -> list[tuple]:
         return list(self.db.execute(transpile(sql)))
 

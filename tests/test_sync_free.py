@@ -162,10 +162,16 @@ def test_planner_unknown_max_run_never_provable():
     assert not provable and cap == K.bucket(64)
 
 
-def test_plan_unique_cap():
-    assert JX.plan_unique_cap(1024, 10) == K.bucket(10)  # sparse: compact
-    assert JX.plan_unique_cap(1024, 800) is None  # dense: stay wide
-    assert JX.plan_unique_cap(1024, None) is None  # unknown: stay wide
+@pytest.mark.parametrize("lanes,count,probe_words,build_words,cap", [
+    (1024, 10, 7, 7, 16),              # sparse: compact, to a power of four
+    (1024, 800, 7, 7, None),           # dense: stay wide
+    # where the chip's costs cross at 2^20 lanes for Q3's two builds (PR 33)
+    (1 << 20, 1 << 18, 7, 7, 1 << 18), (1 << 20, 1 << 19, 7, 7, None),
+    (1 << 20, 1 << 16, 7, 3, 1 << 16), (1 << 20, 1 << 17, 7, 3, None),
+    (1024, 10, 7, 0, None),            # no build column: nothing to gather
+])
+def test_plan_unique_cap(lanes, count, probe_words, build_words, cap):
+    assert JX.plan_unique_cap(lanes, count, probe_words, build_words) == cap
 
 
 # ---------------------------------------------------------------------------

@@ -162,6 +162,24 @@ def test_join_probe_ranges(shape, rows):
 
 
 @pytest.mark.parametrize("rows", BUCKETS)
+def test_join_unique_gather_compact_leg(shape, rows):
+    # Q3's lineitem-orders probe as the seeded runs launch it: 2^20 probe
+    # lanes compacted to a sixteenth, three BIGINT and one INTEGER column a
+    # side, a 2^18-row build (the leg had never run on the chip before
+    # PR 33: no estimate ever reached it)
+    from trino_tpu.spi.types import BIGINT, INTEGER
+
+    types = [BIGINT] * 3 + [INTEGER]
+    build = max(rows >> 2, 8)
+    JX._make_ugather_fn(
+        rows >> 4, types * 2, [None] * 8, 4, 4, (False,) * 4, (False,) * 4,
+        None, False).lower(
+        shape(rows, jnp.bool_), shape(rows, jnp.int64),
+        *[shape(rows, t.storage_dtype) for t in types],
+        *[shape(build, t.storage_dtype) for t in types]).compile()
+
+
+@pytest.mark.parametrize("rows", BUCKETS)
 def test_static_grouped_agg_as_the_fused_stage_calls_it(
         shape, tpu_backend, rows):
     """stage_compiler._agg_merge: cap 8192, every key carries a validity

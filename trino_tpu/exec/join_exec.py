@@ -41,7 +41,8 @@ from . import syncguard as SG
 
 __all__ = ["DeviceJoinTable", "JoinHashTable", "build_table",
            "probe_ranges_device", "run_pairs", "run_unique",
-           "ExpandPlanner", "OverflowQueue", "plan_unique_cap", "key_input"]
+           "ExpandPlanner", "OverflowQueue", "plan_unique_cap",
+           "unique_cap_bucket", "key_input"]
 
 _SENT_BUILD = 0xFFFFFFFFFFFFFFFF  # build rows with NULL keys / dead rows
 _SENT_PROBE = 0xFFFFFFFFFFFFFFFE  # probe rows with NULL keys
@@ -491,8 +492,15 @@ EST_WINDOW = 8            # totals remembered for the estimate
 # estimate from the last execution instead of cold-starting at n_probe —
 # a repartitioned probe arriving as one large page otherwise overflows its
 # first cap and re-runs the whole pair program (correct, but double work).
+# A seed is written whenever a count is observed: when a later ``plan`` /
+# ``estimate`` call of the same planner finds its copy landed, and — for a
+# unique-build probe that sees ONE batch and so never makes that later
+# call — when the operator calls ``land`` at its input's end.  Seeds only
+# grow (the max over every execution and every task that shares the
+# identity).
 # Correctness never depends on a seed: the overflow flag still guards
-# every estimated cap, a stale seed only costs padding.
+# every estimated cap, a stale seed only costs padding or one counted
+# re-run.
 _EST_SEEDS: dict = {}
 _EST_SEEDS_CAP = 4096
 _EST_SEEDS_LOCK = threading.Lock()
@@ -514,9 +522,19 @@ class ExpandPlanner:
     expand program's overflow flag before emitting; ``observe`` feeds the
     planner so steady state converges to zero overflows.  With a ``key``
     the planner also reads/writes the process-global seed store, so the
-    convergence carries across executions of the same plan shape."""
+    convergence carries across executions of the same plan shape.
 
-    __slots__ = ("_totals", "_pending", "_key")
+    When a count lands: a batch's count is handed over in flight
+    (``observe_async``) and folded in by the first later ``plan`` /
+    ``estimate`` / ``land`` call that finds its copy on the host — never by
+    waiting.  The unique-build probe calls ``land`` once its input has ended
+    (and after its last blocking overflow poll, when the copies are there):
+    without it an operator that sees one batch would neither learn its own
+    count nor leave a seed for the next execution — which is still so for
+    the pair and semi-join planners, whose cap never goes under the probe's
+    width whatever the seed."""
+
+    __slots__ = ("_totals", "_pending", "_key", "_observed")
 
     def __init__(self, key=None):
         self._key = key
@@ -526,11 +544,12 @@ class ExpandPlanner:
                 seed = _EST_SEEDS.get(key)
         self._totals: list[int] = [seed] if seed else []
         self._pending: list[SG.AsyncScalar] = []
+        self._observed = False  # a count of THIS operator's batches landed
 
     def plan(self, n_probe: int, max_run: Optional[int]) -> tuple[int, bool]:
         """Returns (cap, provable).  ``max_run`` None = unknown (cross joins
         or builds whose scalars were never fetched)."""
-        self._drain()
+        self.land()
         floor = K.bucket(max(n_probe, 1))
         bound = None  # provable candidate-total upper bound
         if max_run is not None and max_run >= 0:
@@ -545,17 +564,27 @@ class ExpandPlanner:
 
     def observe_async(self, total: SG.AsyncScalar) -> None:
         """Feed a batch's device total; it is read only once its async copy
-        landed (non-blocking polls on later ``plan`` calls)."""
+        landed (non-blocking polls on later ``plan`` / ``estimate`` /
+        ``land`` calls)."""
         self._pending.append(total)
 
-    def recent_max(self) -> Optional[int]:
-        """Largest asynchronously-landed total of the recent window (None
-        until the first one lands) — the unique-path density estimate."""
-        self._drain()
-        return max(self._totals) if self._totals else None
+    def estimate(self) -> tuple[Optional[int], Optional[str]]:
+        """(largest total of the recent window, where it came from) — the
+        unique-path density estimate.  The origin is ``"batch"`` once a
+        count of this operator's own batches landed, ``"seed"`` while only
+        the seed store's value of an earlier execution is known, and the
+        pair is (None, None) when there is neither: a statement's first
+        execution in a process, before its first count."""
+        self.land()
+        if not self._totals:
+            return None, None
+        return max(self._totals), "batch" if self._observed else "seed"
 
     def observe(self, total: int) -> None:
+        """Fold one landed count in, and raise the seed store's value for
+        this planner's key to it (seeds are written here and only here)."""
         total = int(total)
+        self._observed = True
         self._totals.append(total)
         del self._totals[:-EST_WINDOW]
         if self._key is not None:
@@ -566,7 +595,10 @@ class ExpandPlanner:
                         _EST_SEEDS.clear()  # coarse bound; seeds re-learn
                     _EST_SEEDS[self._key] = total
 
-    def _drain(self) -> None:
+    def land(self) -> None:
+        """Fold in every handed-over count whose copy is on the host by now
+        (``get_if_ready``: never a wait).  Called by ``plan`` / ``estimate``
+        before they read, and by the operator at its input's end."""
         still = []
         for h in self._pending:
             v = h.get_if_ready()
@@ -869,16 +901,32 @@ def run_pairs(table: DeviceJoinTable, lo, counts, total,
 # Profile-driven split (r5): gathering every output column at the probe
 # batch's full static width costs O(probe_lanes) random reads per column —
 # for a selective join that is the dominant device cost.  So the probe runs
-# as TWO programs with no blocking sync between them:
+# as TWO programs:
 #   A (`run_unique_ranges_device`)  — hash + binary search + exact verify;
 #       returns (match mask, build row per lane, match count), the count as
-#       an AsyncScalar that sizes LATER batches' compact bucket.  Whether the
-#       build is unique at all is the table's per-BUILD scalar fetch
-#       (`DeviceJoinTable.unique`): a duplicate-key build takes the pair path.
+#       an AsyncScalar.  Whether the build is unique at all is the table's
+#       per-BUILD scalar fetch (`DeviceJoinTable.unique`): a duplicate-key
+#       build takes the pair path.
 #   B (`run_unique_gather`)  — if matches are sparse, compact (probe cols +
-#       build ids) to bucket(count) lanes FIRST and gather build columns at
-#       O(count); if dense, gather wide.  Residual and the RIGHT-join
-#       matched-build scatter evaluate on the narrow lanes.
+#       build ids) to a cap of `unique_cap_bucket(count)` lanes FIRST and
+#       gather build columns at O(count); if dense, gather wide.  Residual and the
+#       RIGHT-join matched-build scatter evaluate on the narrow lanes.
+# B's width is sized from a match count in EVERY batch
+# (LookupJoinOperator._add_inner_unique; `plan_unique_cap` draws the line):
+#   - an earlier batch's count of the same operator, landed asynchronously
+#     (origin "batch"), or the seed an earlier execution of the same plan
+#     shape left in `_EST_SEEDS` (origin "seed"): no wait between A and B,
+#     the cap is EST_HEADROOM x the estimate and B's overflow flag guards
+#     it (OverflowQueue: a landed True re-runs the batch wide, counted);
+#   - neither known — a statement's first execution in a process, on its
+#     first batch — the batch's own count (origin "count"): one scalar
+#     fetch after A, the per-build `table.unique` precedent.  That cap
+#     cannot overflow.  Going wide for want of an estimate cost a probe of
+#     one batch a task 20 full-width gathers a query (PERF.md section 6,
+#     PR 33), and everything downstream rides B's output shape.
+# A count lands, and the seed is written, when a later estimate() of the
+# operator finds the copy on the host and when the operator's input ends
+# (ExpandPlanner.land): a probe of one batch leaves its seed too.
 
 
 @jit_memo("join._uranges_fn")
@@ -1000,6 +1048,21 @@ def run_unique_ranges_device(table: DeviceJoinTable, probe_keys, remaps,
     return ok, bid, SG.async_scalar(cnt, "join.unique-count")
 
 
+def _live_lanes_first(ok_live, cap: int):
+    """Gather index of the first ``cap`` lanes in (live first, lane order):
+    what ``argsort(~ok_live)[:cap]`` gives, from ONE sorted operand — a live
+    lane's key is its index, a dead lane's its index plus the lane count.
+    On a v5e 2.2-4.3 ms against argsort's 2.5-4.9 at 2^20 lanes and half
+    its compile time; the searches and scatters over a running count of the
+    live lanes (jnp.nonzero, cumsum + scatter, cumsum + binary search) cost
+    6-75 ms there (tools/unique_gather_crossover.py, PR 33)."""
+    n = ok_live.shape[0]
+    dtype = jnp.int32 if 2 * n <= np.iinfo(np.int32).max else jnp.int64
+    lane = jnp.arange(n, dtype=dtype)
+    key = jnp.sort(jnp.where(ok_live, lane, lane + n))[:cap]
+    return jnp.where(key >= n, key - n, key)
+
+
 def _make_ugather_fn(cap: Optional[int], pair_types, pair_dicts,
                      n_probe_cols: int, n_build_cols: int,
                      pcol_has_valid: tuple, bcol_has_valid: tuple,
@@ -1036,7 +1099,7 @@ def _make_ugather_fn(cap: Optional[int], pair_types, pair_dicts,
             # truncation guard: more matches than compact lanes means the
             # batch must re-run wide (or at a bigger cap)
             overflow = jnp.sum(ok_live.astype(jnp.int64)) > cap
-            order = jnp.argsort(~ok_live)[:cap]
+            order = _live_lanes_first(ok_live, cap)
             ok_c = ok_live[order]
             bid_c = bid[order]
             p_out = [(d[order], None if v is None else v[order])
@@ -1066,14 +1129,58 @@ def _make_ugather_fn(cap: Optional[int], pair_types, pair_dicts,
     return program("join.unique_gather", fn)
 
 
-def plan_unique_cap(n_lanes: int, count: Optional[int]) -> Optional[int]:
-    """Compact-vs-wide decision for program B: compact to bucket(count) when
-    matches fill < 1/4 of the lanes, else stay wide (None).  ``count`` is an
-    estimate from previous batches' asynchronously-landed counts (the
-    overflow flag guards it) or, in tests, the exact count."""
-    if count is None:
+# what plan_unique_cap weighs, as read on a v5e by
+# tools/unique_gather_crossover.py at 2^20 probe lanes (PERF.md section 6,
+# PR 33; device seconds)
+_GATHER_S_PER_WORD_LANE = 8.3e-9  # one 32-bit gather, a lane, either leg
+_INDEX_S_PER_LANE = 3.5e-9        # the compact leg's index of the live lanes
+_COMPACT_EXTRA_WORDS = 3          # ... which also gathers the mask and bid
+
+
+def gather_words(cols) -> int:
+    """32-bit gathers a lane that [(data, valid|None), ...] costs: the chip
+    has no 64-bit vector path, so an 8-byte column is two (15.9-23.2 ms
+    against 9.8 ms at 2^20 lanes), and a validity mask is one more."""
+    return sum((2 if d.dtype.itemsize > 4 else 1) + (v is not None)
+               for d, v in cols)
+
+
+def unique_cap_bucket(count: int) -> int:
+    """The compact leg's caps come in powers of FOUR.  Everything downstream
+    of the probe is compiled for the cap's shape, a 64-bit sort among it
+    (minutes each on a cold start: one `group_ids` at 2^15 lanes took the
+    v5e compiler 224 s, PR 33), and a statement's first execution sizes
+    each task's gather from its own count where later ones read the seed,
+    the maximum over tasks: with every power of two a cap, the first query
+    compiled a set of shapes no later one used.  A cap one size up costs
+    the compact leg a few milliseconds (7.7 -> 11.8 ms from 2^15 to 2^16)."""
+    cap = K.bucket(max(count, 1))
+    return cap if cap.bit_length() % 2 else 2 * cap
+
+
+def plan_unique_cap(n_lanes: int, count: int, probe_words: int,
+                    build_words: int) -> Optional[int]:
+    """Compact-vs-wide decision for program B: the cap to compact to, or
+    None to stay wide.  ``count`` is the caller's estimate of the matches
+    with its headroom (an earlier batch's or execution's count, guarded by
+    the overflow flag, or the batch's own).  The wide leg gathers the build
+    columns over all the lanes; the compact leg indexes the live lanes (one
+    sort over the lanes) and gathers probe AND build columns at
+    unique_cap_bucket(count) lanes: whichever is cheaper by the unit costs
+    above.  For TPC-H Q3's probes (7 probe words; 7 and 3 build words) the
+    costs cross between a cap of lanes/4 and lanes/2, and between lanes/8
+    and lanes/4 — where the chip put them (43.5 against 60.4 ms and 83
+    against 60; 15.9 against 26.9 and 33.1 against 26.9).  A join that
+    emits no build column stays wide: its probe columns pass through for
+    nothing."""
+    cap = unique_cap_bucket(count)
+    if cap >= n_lanes:
         return None
-    return K.bucket(max(count, 1)) if count * 4 <= n_lanes else None
+    wide_s = n_lanes * build_words * _GATHER_S_PER_WORD_LANE
+    compact_s = (n_lanes * _INDEX_S_PER_LANE
+                 + cap * (probe_words + build_words + _COMPACT_EXTRA_WORDS)
+                 * _GATHER_S_PER_WORD_LANE)
+    return cap if compact_s <= wide_s else None
 
 
 def run_unique_gather(table: DeviceJoinTable, ok_live, bid,

@@ -2427,8 +2427,10 @@ class LookupJoinOperator(Operator):
     def _add_inner_unique(self, probe: ColumnBatch, table, build,
                           keys, remaps) -> None:
         """INNER/RIGHT probe against a unique build: ranges and the match
-        count stay on device, the gather's width adapts to earlier batches'
-        counts."""
+        count stay on device, and the gather's width is sized from a match
+        count in every batch — an earlier batch's, the seed an earlier
+        execution of the same plan shape left, or (neither known) this
+        batch's own, fetched once."""
         from . import join_exec as JX
 
         if probe.num_rows == 0:
@@ -2463,23 +2465,39 @@ class LookupJoinOperator(Operator):
             self._pending.append(ColumnBatch(
                 self.output_names, left_cols + right_cols, live))
 
-        with SG.hot_region():
-            # compact-vs-wide from the previous batches' async-landed match
-            # counts; the compact path's overflow flag guards the estimate
-            est = self._uplanner.recent_max()
-            cap = JX.plan_unique_cap(
-                probe.num_rows,
-                None if est is None else est * JX.EST_HEADROOM)
+        lanes = probe.num_rows
+        est, origin = self._uplanner.estimate()
+        if est is None:
+            # a statement's first execution in this process: no earlier
+            # batch, no seed.  One scalar fetch of program A's count (0.4 ms
+            # on a v5e) instead of going wide for want of an estimate; it
+            # is this operator's one deliberate wait, outside the hot
+            # region (exec/join_exec.py, the unique-build design note)
+            est, origin = int(cnt_a.get()), "count"
+            self._uplanner.observe(est)
+        else:
             self._uplanner.observe_async(cnt_a)
+        cap = JX.plan_unique_cap(
+            lanes, est * JX.EST_HEADROOM, JX.gather_words(probe_cols),
+            JX.gather_words(build_cols))
+        SG.count_unique_gather(compact=cap is not None,
+                               seeded=origin == "seed")
+        # cap == lanes: the wide leg
+        self.trace_attrs = {"cap": lanes if cap is None else cap,
+                            "lanes": lanes, "estimate": origin}
+        with SG.hot_region():
             res = JX.run_unique_gather(
                 table, ok_live, bid, cap, probe_cols, build_cols,
                 pair_types, pair_dicts, self.residual, need_bm)
-            if cap is None:  # wide path cannot overflow
+            if cap is None or origin == "count":
+                # the wide path cannot overflow, nor can a cap sized from
+                # the batch's own count
                 commit(res)
                 return
 
             def retry():
                 # compact bucket overflowed: re-run wide (provably safe)
+                SG.count_unique_gather(compact=False, seeded=False)
                 return JX.run_unique_gather(
                     table, ok_live, bid, None, probe_cols, build_cols,
                     pair_types, pair_dicts, self.residual, need_bm)
@@ -2488,6 +2506,15 @@ class LookupJoinOperator(Operator):
                 SG.async_scalar(res[4], "join.unique-overflow"),
                 res, retry, commit)
             self._inflight.drain()
+
+    def finish_input(self) -> None:
+        super().finish_input()
+        # a probe of ONE batch makes no later estimate() call that would
+        # fold its count in: land it here (never a wait), so the next
+        # execution of this plan shape finds its seed.  The pair path's
+        # planner is left as it was (its cap never goes under the probe's
+        # width, and no cell runs it: ROADMAP queue 1)
+        self._uplanner.land()
 
     def _add_unique_input(self, probe: ColumnBatch, table, build,
                           keys, remaps) -> None:
@@ -2565,6 +2592,10 @@ class LookupJoinOperator(Operator):
             # commit landed estimated-cap batches; at input end the tail
             # entries are waited on (the only blocking poll of the query)
             self._inflight.drain(block=self.input_done)
+            if self.input_done:
+                # the counts handed over in flight landed before the flags
+                # just waited on
+                self._uplanner.land()
         if self._pending:
             return self._pending.popleft()
         if (self.input_done and not self._closed

@@ -67,7 +67,12 @@ class SyncStats:
     ``async_polls``/``poll_hits`` the async-copy handles created and how many
     had already landed when read (a hit costs ~0 instead of a device wait).
     ``expand_overflows``/``expand_retries`` count padded-expand buckets that
-    proved too small and the re-runs that fixed them."""
+    proved too small and the re-runs that fixed them.
+    ``unique_gather_wide``/``unique_gather_compact`` count the unique-build
+    probe's gathers (join_exec.run_unique_gather) by the width they ran at:
+    the probe batch's lanes, or a cap sized from a match count (an overflow's
+    wide re-run counts as wide); ``unique_gather_seeded`` the subset whose
+    estimate was the seed an earlier execution left."""
 
     host_syncs: int = 0
     blocking_syncs: int = 0
@@ -75,6 +80,9 @@ class SyncStats:
     poll_hits: int = 0
     expand_overflows: int = 0
     expand_retries: int = 0
+    unique_gather_wide: int = 0
+    unique_gather_compact: int = 0
+    unique_gather_seeded: int = 0
     hot_loop_syncs: int = 0      # blocking syncs inside hot regions (want: 0)
     by_tag: dict = field(default_factory=dict)
 
@@ -94,7 +102,9 @@ class SyncStats:
             f"({self.blocking_syncs} blocking, {self.hot_loop_syncs} in hot "
             f"loops), {self.poll_hits}/{self.async_polls} async polls ready, "
             f"expand overflow {self.expand_overflows}/"
-            f"retry {self.expand_retries}"
+            f"retry {self.expand_retries}, unique gathers "
+            f"{self.unique_gather_wide} wide/{self.unique_gather_compact} "
+            f"compact ({self.unique_gather_seeded} seeded)"
             + (f" [{tags}]" if tags else "")
         )
 
@@ -167,6 +177,16 @@ def count_overflow(retried: bool = True) -> None:
         _STATS.expand_overflows += 1
         if retried:
             _STATS.expand_retries += 1
+
+
+def count_unique_gather(compact: bool, seeded: bool) -> None:
+    with _LOCK:
+        if compact:
+            _STATS.unique_gather_compact += 1
+        else:
+            _STATS.unique_gather_wide += 1
+        if seeded:
+            _STATS.unique_gather_seeded += 1
 
 
 def fetch(x, tag: str):

@@ -366,6 +366,15 @@ EXPAND_OVERFLOWS = REGISTRY.counter(
     "padded-expand capacity overflows detected on device")
 EXPAND_RETRIES = REGISTRY.counter(
     "trino_exec_expand_retries_total", "expand re-runs after an overflow")
+UNIQUE_GATHER_WIDE = REGISTRY.counter(
+    "trino_exec_unique_gather_wide_total",
+    "unique-build probe gathers at the probe batch's full width")
+UNIQUE_GATHER_COMPACT = REGISTRY.counter(
+    "trino_exec_unique_gather_compact_total",
+    "unique-build probe gathers compacted to a cap sized from a match count")
+UNIQUE_GATHER_SEEDED = REGISTRY.counter(
+    "trino_exec_unique_gather_seeded_total",
+    "unique-build probe gathers sized from an earlier execution's seed")
 
 # resilience (retry_policy=QUERY loop, heartbeats, exchange backoff)
 RES_QUERY_RETRIES = REGISTRY.counter(
@@ -761,6 +770,12 @@ def observe_sync(sync) -> None:
         EXPAND_OVERFLOWS.inc(sync.expand_overflows)
     if sync.expand_retries:
         EXPAND_RETRIES.inc(sync.expand_retries)
+    if sync.unique_gather_wide:
+        UNIQUE_GATHER_WIDE.inc(sync.unique_gather_wide)
+    if sync.unique_gather_compact:
+        UNIQUE_GATHER_COMPACT.inc(sync.unique_gather_compact)
+    if sync.unique_gather_seeded:
+        UNIQUE_GATHER_SEEDED.inc(sync.unique_gather_seeded)
 
 
 def observe_resilience(res) -> None:

@@ -195,8 +195,11 @@ class Driver:
 
 
 def run_pipelines(pipelines: Sequence[Sequence[Operator]],
-                  stats: Optional[QueryStats] = None) -> None:
-    """Execute pipelines in dependency order (build sides first).
+                  stats: Optional[QueryStats] = None) -> float:
+    """Execute pipelines in dependency order (build sides first); returns
+    the thread-CPU seconds the pipeline-group threads consumed (0.0 when
+    every pipeline ran on the calling thread, whose own CPU the caller
+    reads: the ``task`` event's ``cpu_s``).
     Pipelines belonging to one local-exchange cluster (tagged with the same
     ``_concurrent_group`` on their source operator — producers, parallel
     aggregation drivers AND the consumer chain) run on concurrent threads
@@ -211,6 +214,7 @@ def run_pipelines(pipelines: Sequence[Sequence[Operator]],
     from .operators import UnionSinkOperator
 
     sync_before = syncguard.snapshot() if stats is not None else None
+    group_cpu = []  # one entry per ended group thread; append is atomic
 
     def run_one(p, stop=None) -> None:
         ps = None
@@ -252,6 +256,9 @@ def run_pipelines(pipelines: Sequence[Sequence[Operator]],
             except BaseException as e:  # noqa: BLE001
                 errors.append(e)
                 stop.set()  # unpark siblings so the group can unwind
+            finally:
+                # the thread is this function: its CPU clock began at zero
+                group_cpu.append(time.thread_time())
 
         threads = [threading.Thread(target=wrapped, args=(q,),
                                     daemon=True) for q in group]
@@ -307,3 +314,4 @@ def run_pipelines(pipelines: Sequence[Sequence[Operator]],
         e for p in pipelines for op in p
         for e in getattr(op, "pending_errors", ())
     ])
+    return sum(group_cpu)

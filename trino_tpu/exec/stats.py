@@ -264,6 +264,28 @@ class ResidentPlanStats:
 
 
 @dataclass
+class SharingStats:
+    """How a query shared its runner with the others in flight
+    (telemetry/runtime.py QueryRecord; the flight recorder's ``execute``
+    and ``task`` events carry the same figures per event)."""
+
+    in_flight: int = 0       # executions open when this one began, itself too
+    task_cpu_s: float = 0.0  # thread-CPU seconds of the tasks' threads
+    task_wall_s: float = 0.0
+
+    @property
+    def any(self) -> bool:
+        return self.in_flight > 0
+
+    def text(self) -> str:
+        share = 100.0 * self.task_cpu_s / self.task_wall_s \
+            if self.task_wall_s else 0.0
+        return (f"sharing: {self.in_flight} in flight at start, task cpu "
+                f"{self.task_cpu_s:.3f} s of {self.task_wall_s:.3f} s task "
+                f"wall ({share:.1f} %)")
+
+
+@dataclass
 class AdaptiveStats:
     """Counters + decision tags for the adaptive execution plane
     (execution/adaptive.py): phased stage activations and the join-
@@ -430,6 +452,7 @@ class QueryStats:
     resident: ResidentPlanStats | None = None  # whole-plan compilation counters
     adaptive: AdaptiveStats | None = None  # adaptive-execution decisions
     encoding: EncodingStats | None = None  # compressed-execution counters
+    sharing: SharingStats | None = None  # in flight at start, task CPU
 
     def merge_scan(self, ingest: ScanIngestStats) -> None:
         if self.scan is None:
@@ -476,6 +499,8 @@ class QueryStats:
             lines.append("  " + self.adaptive.text())
         if self.encoding is not None and self.encoding.any:
             lines.append("  " + self.encoding.text())
+        if self.sharing is not None and self.sharing.any:
+            lines.append("  " + self.sharing.text())
         for i, p in enumerate(self.pipelines):
             lines.append(f"  pipeline {i}:")
             for op in p.operators:

@@ -767,6 +767,16 @@ class DistributedQueryRunner:
                 stats_sink.append(QueryStats(label="adaptive:",
                                              adaptive=adaptive.stats))
 
+        if stats_sink is not None:
+            from ..exec.stats import SharingStats
+            from ..telemetry import runtime as _rt
+
+            qrec = _rt.current_record()
+            if qrec is not None:
+                stats_sink.append(QueryStats(
+                    label="sharing:", sharing=SharingStats(
+                        qrec.in_flight, qrec.task_cpu_s, qrec.task_wall_s)))
+
         # close the runtime-truth loop: journal per-fingerprint observed
         # stats so the NEXT run of this (or any row-equivalent) plan shape
         # costs joins/aggregations from reality (planner/history.py)
@@ -1308,6 +1318,11 @@ class DistributedQueryRunner:
         # threads, via run_pipelines context inheritance) records attributes
         profiler.set_context(trec.query_id, trec.task_id)
         t0 = _time.perf_counter()
+        # what the thread ran, beside how long the task took: the rest of
+        # the wall it waited (host-sync, exchange-wait) or stood runnable
+        # behind another thread (the interpreter lock, the OS)
+        cpu0 = _time.thread_time()
+        group_cpu_s = 0.0
         pipelines = None
         state = "FINISHED"
         err = None
@@ -1320,7 +1335,7 @@ class DistributedQueryRunner:
                     stage, task_index, stages, stats_sink, collective or {},
                     attempt, memory_owner=memory_owner, spec_ctx=spec_ctx,
                     adaptive=adaptive, tee=tee)
-                run_pipelines(pipelines, stats)
+                group_cpu_s = run_pipelines(pipelines, stats)
             except SpeculationLost:
                 # this attempt lost the first-commit race — its twin owns
                 # the output stream; unwind without touching the query
@@ -1365,8 +1380,11 @@ class DistributedQueryRunner:
                     rt.add_input(query_record, ingest.scan_rows,
                                  ingest.scan_bytes)
             # closing the span writes the flight recorder's ``task`` event
-            sp.record(state=state)
-        tm.TASK_WALL_SECONDS.record(_time.perf_counter() - t0)
+            cpu_s = _time.thread_time() - cpu0 + group_cpu_s
+            sp.record(state=state, cpu_s=round(cpu_s, 6))
+        wall_s = _time.perf_counter() - t0
+        rt.add_task_time(query_record, cpu_s, wall_s)
+        tm.TASK_WALL_SECONDS.record(wall_s)
         if state == "FAILED":
             tm.TASKS_FAILED.inc()
         rt.task_finished(trec, state, error=err)

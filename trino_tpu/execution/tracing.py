@@ -253,6 +253,19 @@ class Tracer:
         # finished root, under the lock)
         self.finished: deque = deque(maxlen=keep)
         self._lock = threading.Lock()
+        self._open_queries = 0
+
+    def query_opened(self) -> int:
+        """One more execution is open on this tracer's runner; returns how
+        many are, this one included (``run_with_query_events`` writes it on
+        the ``execute`` event as ``in_flight``)."""
+        with self._lock:
+            self._open_queries += 1
+            return self._open_queries
+
+    def query_closed(self) -> None:
+        with self._lock:
+            self._open_queries -= 1
 
     def _stack(self) -> list:
         if not hasattr(self._local, "stack"):

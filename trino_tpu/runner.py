@@ -326,14 +326,17 @@ def run_with_query_events(qid: str, sql: str, user: str, listeners, tracer,
     # the two clocks can be matched (benchmark/harness/program_spans.py)
     prof_ctx = profiler.set_context(qid)
     root = tracer.span("trino.query", query_id=qid)
-    root.__enter__()
+    in_flight = tracer.query_opened()
+    root.__enter__().record(in_flight=in_flight)
     listeners.query_created(QueryCreatedEvent(qid, sql, user))
     rec = rt.query_started(qid, sql, user)
+    rec.in_flight = in_flight
     tm.QUERIES_STARTED.inc()
     t0 = _time.perf_counter()
     cpu0 = _time.process_time()
 
     def _finish(state: str, rows: int, error, error_code=None):
+        tracer.query_closed()
         wall = (_time.perf_counter() - t0) * 1e3
         cpu = (_time.process_time() - cpu0) * 1e3
         tm.QUERY_WALL_SECONDS.record(wall / 1e3)

@@ -54,9 +54,11 @@ class _Query:
         self.cancelled = False
         self.recovered = False  # rehydrated from the query-state WAL
         # the protocol's own view of the query, on the flight recorder's
-        # clock: POST received, execution ended, polls answered, and whether
-        # the last page went out (the recorder's ``query`` span, ``stats``)
+        # clock: POST received, handed to the runner, execution ended, polls
+        # answered, and whether the last page went out (the recorder's
+        # ``query`` span, ``stats``)
         self.t_post = profiler.now()
+        self.t_run: Optional[float] = None
         self.t_done: Optional[float] = None
         self.polls = 0
         self.served = False
@@ -66,27 +68,28 @@ class _Query:
         self.t_done = profiler.now()
         self.done.set()
 
+    def queued_ms(self) -> float:
+        """POST received until the dispatcher handed the query to the
+        runner (the wait for a slot, ``_await_memory``, the hand-over), or
+        until now while it still waits; for a query that ended before it
+        ran, until it ended."""
+        until = self.t_run or self.t_done or profiler.now()
+        return max(until - self.t_post, 0.0) * 1e3
+
     def stats(self) -> dict:
         """The ``stats`` object of every protocol response, as a Trino
-        client prints it.  ``queuedTimeMillis``: POST received until the
-        runner began executing (the recorder's ``execute`` span once the
-        query is over, the QueryRecord's creation before) plus the
-        resource-group wait; ``elapsedTimeMillis``: POST received until the
-        execution ended, or until now; ``processedRows``: rows the scans
-        read (QueryRecord)."""
+        client prints it.  ``queuedTimeMillis``: ``queued_ms`` plus the
+        resource-group wait inside the runner; ``elapsedTimeMillis``: POST
+        received until the execution ended, or until now;
+        ``processedRows``: rows the scans read (QueryRecord)."""
         if self.final_stats is not None:
             return dict(self.final_stats, state=self.state)
         rec = rt.find_query(self.id)
         end = self.t_done if self.t_done is not None else profiler.now()
-        began = rec.create_time if rec is not None else end
-        executed = profiler.find(self.id, profiler.EXECUTE) \
-            if self.t_done is not None else []
-        if executed:
-            began = executed[0]["ts"]
         out = {
             "state": self.state,
             "queuedTimeMillis": int(round(
-                max(began - self.t_post, 0.0) * 1e3
+                self.queued_ms()
                 + (rec.queued_ms if rec is not None else 0.0))),
             "elapsedTimeMillis": int(round((end - self.t_post) * 1e3)),
             "processedRows": rec.input_rows if rec is not None else 0,
@@ -165,7 +168,10 @@ class QueryDispatcher:
     def submit(self, sql: str, qid: Optional[str] = None) -> _Query:
         """``qid`` lets the HA front tier pre-assign the query id it hashed
         the owning coordinator from, so routing and identity agree."""
-        from ..telemetry.metrics import DISPATCHER_QUERIES
+        from ..telemetry.metrics import (
+            DISPATCHER_IN_FLIGHT,
+            DISPATCHER_QUERIES,
+        )
 
         DISPATCHER_QUERIES.inc()
         q = _Query(qid or uuid.uuid4().hex[:16], sql)
@@ -176,35 +182,37 @@ class QueryDispatcher:
             finished = [k for k, v in self.queries.items() if v.done.is_set()]
             for k in finished[:max(0, len(self.queries) - self.MAX_RETAINED)]:
                 del self.queries[k]
+        DISPATCHER_IN_FLIGHT.set(self.in_flight())
         self.pool.submit(self._run, q)
         return q
 
     def _run(self, q: _Query) -> None:
         if q.cancelled:
             q.state = "CANCELED"
-            q.finish()
+            self._finish(q)
             return
         try:
             self._await_memory(q)
         except Exception as e:
             q.error = f"{type(e).__name__}: {e}"
             q.state = "FAILED"
-            q.finish()
+            self._finish(q)
             return
         if q.cancelled:
             q.state = "CANCELED"
-            q.finish()
+            self._finish(q)
             return
         q.state = "RUNNING"
         try:
             # the protocol query id IS the engine query id, so the flight
             # recorder's /v1/query/{id}/profile resolves without a mapping
+            q.t_run = profiler.now()
             result = self.runner.execute(q.sql, query_id=q.id)
             self._deliver(q, result)
         except Exception as e:  # surfaced through the protocol, not the log
             q.error = f"{type(e).__name__}: {e}"
             q.state = "FAILED"
-        q.finish()
+        self._finish(q)
 
     def _resume(self, q: _Query, pq) -> None:
         """Run one crash-recovered query to completion under its original
@@ -212,15 +220,22 @@ class QueryDispatcher:
         the same nextUri and sees the query finish."""
         if q.cancelled:
             q.state = "CANCELED"
-            q.finish()
+            self._finish(q)
             return
         q.state = "RUNNING"
         try:
+            q.t_run = profiler.now()
             self._deliver(q, self.runner.resume_fte_query(pq))
         except Exception as e:
             q.error = f"{type(e).__name__}: {e}"
             q.state = "FAILED"
+        self._finish(q)
+
+    def _finish(self, q: _Query) -> None:
+        from ..telemetry.metrics import DISPATCHER_IN_FLIGHT
+
         q.finish()
+        DISPATCHER_IN_FLIGHT.set(self.in_flight())
 
     def _deliver(self, q: _Query, result) -> None:
         if q.cancelled:
@@ -335,7 +350,8 @@ class _Handler(BaseHTTPRequestHandler):
             first, q.served = not q.served, True
         if first:
             profiler.query_event(q.id, q.t_post, profiler.now(),
-                                 state=q.state, polls=q.polls)
+                                 state=q.state, polls=q.polls,
+                                 queued_ms=round(q.queued_ms(), 3))
 
     def do_POST(self):
         if self.path.rstrip("/") != "/v1/statement":

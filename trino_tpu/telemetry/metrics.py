@@ -512,6 +512,10 @@ TASK_WALL_SECONDS = REGISTRY.distribution(
 DISPATCHER_QUERIES = REGISTRY.counter(
     "trino_dispatcher_queries_total",
     "statements admitted through the HTTP dispatcher")
+DISPATCHER_IN_FLIGHT = REGISTRY.gauge(
+    "trino_dispatcher_in_flight",
+    "statements the HTTP dispatcher holds that have not ended: waiting for "
+    "a slot or for memory, or running")
 
 # device memory watermark (best-effort; jax CPU backends may not report)
 DEVICE_MEMORY_IN_USE = REGISTRY.gauge(

@@ -21,7 +21,14 @@ boundaries — ``query`` (server/protocol.py), ``execute``, ``plan``,
 ``jax.profiler.TraceAnnotation("trino.<kind>[:<name>]")``, so an open
 profiler session shows the program's rows beside the device's on the
 session's own clock; ``operator``, ``launch`` and ``query`` stay
-ring-only.  ``events_since``/``dropped_since`` hand every query's events
+ring-only.  Three attributes say how queries in flight together shared the
+process: ``task`` carries ``cpu_s`` (thread-CPU seconds of the task's
+thread and its pipeline-group threads; what is left of the wall after it
+and the task's ``host-sync`` / ``exchange-wait`` spans, the thread stood
+runnable and did not run), ``execute`` carries ``in_flight`` (executions
+open on its runner when it began, itself included) and ``query`` carries
+``queued_ms`` (POST received to the hand-over to the runner).
+``events_since``/``dropped_since`` hand every query's events
 from an instant on to a reader that lays them over a device trace
 (benchmark/harness/program_spans.py finds the offset between the clocks).
 

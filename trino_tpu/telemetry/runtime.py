@@ -43,7 +43,7 @@ class QueryRecord:
                  "input_rows", "input_bytes", "retry_count",
                  "peak_memory_bytes", "fingerprint", "queued_ms",
                  "resource_group", "speculative_wins", "adaptive_decisions",
-                 "_lock")
+                 "in_flight", "task_cpu_s", "task_wall_s", "_lock")
 
     def __init__(self, query_id: str, sql: str, user: str):
         self.query_id = query_id
@@ -67,6 +67,11 @@ class QueryRecord:
         # compact "kind[site]=choice" list, comma-joined — the
         # system.runtime.queries adaptive_decisions column
         self.adaptive_decisions = ""
+        # how the query shared its runner: executions open when it began
+        # (itself included), and its tasks' thread-CPU and wall seconds
+        self.in_flight = 0
+        self.task_cpu_s = 0.0
+        self.task_wall_s = 0.0
         self._lock = threading.Lock()
 
 
@@ -130,6 +135,16 @@ def add_input(rec: Optional[QueryRecord], rows: int, nbytes: int) -> None:
     with rec._lock:
         rec.input_rows += int(rows)
         rec.input_bytes += int(nbytes)
+
+
+def add_task_time(rec: Optional[QueryRecord], cpu_s: float,
+                  wall_s: float) -> None:
+    """Credit one finished task's thread-CPU and wall seconds."""
+    if rec is None:
+        return
+    with rec._lock:
+        rec.task_cpu_s += cpu_s
+        rec.task_wall_s += wall_s
 
 
 def add_retries(rec: Optional[QueryRecord], n: int) -> None:

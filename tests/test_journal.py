@@ -260,3 +260,110 @@ def test_peer_journal_append_invalidates_admission_seed(tmp_path,
 
     assert estimate_peak_memory(fp, default) == 7 << 20, \
         "the peer's append must invalidate the local seed cache"
+
+
+# ------------------------------------------------- the follower (PR 35)
+
+
+def _stats(j, qid: str, rows: int) -> None:
+    j.plan_stats(qid, "sqlfp", {"fp": {"rows": rows}}, ts=1.0)
+
+
+def _ids(records) -> list:
+    return [rec["query_id"] for _, rec in records]
+
+
+def test_follower_hands_out_each_appended_record_once(tmp_path):
+    j = journal.QueryJournal(directory=str(tmp_path / "j"))
+    follower = journal.JournalFollower(j, ("plan_stats",))
+    assert follower.poll() == (True, [], 0), "no file yet: an empty start"
+    assert follower.poll() is None
+    _stats(j, "q_1", 1)
+    j.query_completed(_completed("q_other"))  # not the followed type
+    from_nothing, records, nbytes = follower.poll()
+    assert from_nothing, "a file appeared: only a fresh start is right"
+    assert _ids(records) == ["q_1"]
+    assert records[0][0] == j.path, "a record names its stream"
+    assert nbytes == os.path.getsize(j.path)
+    assert follower.poll() is None, "nothing moved: a stat() and no read"
+    size = os.path.getsize(j.path)
+    _stats(j, "q_2", 2)
+    _stats(j, "q_3", 3)
+    from_nothing, records, nbytes = follower.poll()
+    assert not from_nothing and _ids(records) == ["q_2", "q_3"]
+    assert nbytes == os.path.getsize(j.path) - size, \
+        "only the appended bytes are read"
+
+
+def test_follower_leaves_a_torn_tail_until_its_newline(tmp_path):
+    j = journal.QueryJournal(directory=str(tmp_path / "j"))
+    follower = journal.JournalFollower(j, ("plan_stats",))
+    _stats(j, "q_1", 1)
+    follower.poll()
+    line = json.dumps(journal._record_plan_stats(
+        "q_torn", "sqlfp", {"fp": {"rows": 9}}, ts=2.0))
+    with open(j.path, "a", encoding="utf-8") as f:
+        f.write(line[:40])
+    assert follower.poll() == (False, [], 40), "read, and not consumed"
+    with open(j.path, "a", encoding="utf-8") as f:
+        f.write(line[40:] + "\n" + "garbage\n")
+    from_nothing, records, _ = follower.poll()
+    assert not from_nothing and _ids(records) == ["q_torn"]
+    assert follower.poll() is None
+
+
+@pytest.mark.parametrize("event", ["rotation", "shrink", "replaced",
+                                   "vanished", "peer_appears"])
+def test_follower_starts_from_nothing_when_files_do_not_just_grow(
+        event, tmp_path, monkeypatch):
+    d = str(tmp_path / "j")
+    j = journal.QueryJournal(directory=d, max_bytes=600, max_files=2)
+    follower = journal.JournalFollower(j, ("plan_stats",))
+    _stats(j, "q_1", 1)
+    _stats(j, "q_2", 2)
+    assert _ids(follower.poll()[1]) == ["q_1", "q_2"]
+    expected = ["q_1", "q_2"]
+    if event == "rotation":
+        for i in range(3, 24):  # four records a file, three files kept
+            _stats(j, f"q_{i}", i)
+        expected = _ids((j.path, r) for r in j.read())
+        assert "q_1" not in expected, "the oldest generation was dropped"
+    elif event == "shrink":
+        first = open(j.path, encoding="utf-8").readline()
+        with open(j.path, "w", encoding="utf-8") as f:
+            f.write(first)
+        expected = ["q_1"]
+    elif event == "replaced":
+        text = open(j.path, encoding="utf-8").read()
+        os.replace(j.path, j.path + ".old")
+        with open(j.path, "w", encoding="utf-8") as f:
+            f.write(text)  # the same bytes under another inode
+        os.remove(j.path + ".old")
+    elif event == "vanished":
+        os.remove(j.path)
+        expected = []
+    elif event == "peer_appears":
+        monkeypatch.setenv("TRINO_TPU_HA_NODE_ID", "peer")
+        _stats(journal.QueryJournal(directory=d), "q_peer", 5)
+        expected = ["q_peer", "q_1", "q_2"]  # streams in name order
+    from_nothing, records, _ = follower.poll()
+    assert from_nothing
+    assert _ids(records) == expected
+    assert follower.poll() is None
+
+
+def test_follower_reads_a_growing_peer_stream_from_its_cursor(tmp_path,
+                                                              monkeypatch):
+    d = str(tmp_path / "j")
+    mine = journal.QueryJournal(directory=d)
+    monkeypatch.setenv("TRINO_TPU_HA_NODE_ID", "peer")
+    peer = journal.QueryJournal(directory=d)
+    _stats(mine, "q_mine", 1)
+    _stats(peer, "q_peer_1", 2)
+    follower = journal.JournalFollower(mine, ("plan_stats",))
+    assert _ids(follower.poll()[1]) == ["q_peer_1", "q_mine"]
+    _stats(peer, "q_peer_2", 3)
+    from_nothing, records, nbytes = follower.poll()
+    assert not from_nothing and records[0][0] == peer.path
+    assert _ids(records) == ["q_peer_2"]
+    assert 0 < nbytes < os.path.getsize(peer.path)

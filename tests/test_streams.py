@@ -240,6 +240,52 @@ def test_three_clients_get_no_new_program_after_a_one_client_warm_up(served):
         mon.unregister_event_duration_listener(on_compile)
 
 
+# (4b): once the recorded numbers repeat, three clients plan from the cache
+def test_three_clients_hit_the_plan_cache_after_their_first_block(served):
+    """A finished query appends to the journal, but the history epoch is
+    the folded table's: three clients' lookups go to the same Tier A keys,
+    each reads what the others appended (not the journal again), and the
+    recorder's ``plan`` span says so."""
+    from trino_tpu.caching import plan_cache
+    from trino_tpu.telemetry import metrics as tm
+
+    runner, base = served
+    for sql in (Q6, Q1):
+        for _ in range(3):
+            statement(base, sql)
+    streams(base, blocks([Q6, Q6, Q1], seed=11, n_blocks=1))
+    before = plan_cache.stats()
+    counters = {c: c.value() for c in (
+        tm.JOURNAL_BYTES, tm.HBO_JOURNAL_BYTES_READ, tm.HBO_TABLE_REBUILDS)}
+    t0 = profiler.now()
+    got = streams(base, blocks([Q6, Q6, Q1], seed=12, n_blocks=3))
+    after = plan_cache.stats()
+    hits = after["hits"] - before["hits"]
+    lookups = hits + after["misses"] - before["misses"]
+    assert lookups == sum(len(s) for s in got) == 9 * STREAMS
+    assert hits / lookups >= 0.9, (before, after)
+    moved = {c: c.value() - v for c, v in counters.items()}
+    assert moved[tm.HBO_TABLE_REBUILDS] == 0
+    assert 0 < moved[tm.HBO_JOURNAL_BYTES_READ] \
+        <= 1.1 * moved[tm.JOURNAL_BYTES]
+    lookups = [e for e in events_with_queries(t0, 9 * STREAMS)
+               if e["kind"] == "plan" and e["name"] == "cache-lookup"]
+    assert len(lookups) == 9 * STREAMS
+    assert sum(e["args"]["cache_hit"] for e in lookups) == hits
+    assert len({e["args"]["epoch"] for e in lookups}) <= 2
+    assert all(e["args"]["epoch"] and e["args"]["journal_bytes_read"] >= 0
+               for e in lookups)
+    # a lookup reads what was appended since the last one, by any stream
+    assert sum(e["args"]["journal_bytes_read"] for e in lookups) \
+        <= moved[tm.HBO_JOURNAL_BYTES_READ]
+    with urllib.request.urlopen(f"{base}/v1/metrics") as resp:
+        text = resp.read().decode()
+    for family in ("trino_hbo_journal_bytes_read_total",
+                   "trino_hbo_table_folds_total",
+                   "trino_hbo_table_rebuilds_total"):
+        assert f"\n{family} " in text
+
+
 # (5), (6): the recorder under three open queries
 @pytest.fixture
 def three_open(served):

@@ -149,9 +149,12 @@ def _key(sql: str, session, catalog, flavor: str) -> tuple:
     # instance id keeps the process-global cache partitioned per catalog:
     # two runners with fresh catalogs (and fresh memory connectors) must
     # never see each other's plans or results
-    # the history epoch keys out plans shaped by observed stats: new
-    # plan_stats records -> new epoch -> cached history-driven plans
-    # cannot outlive (or poison) the history that shaped them
+    # the history epoch keys out plans shaped by observed stats: it digests
+    # the folded table the planner reads (planner/history.py), so a record
+    # that changes an observed number changes it and strands the plans made
+    # before, and one that repeats what is known leaves them reachable.
+    # ``store`` runs inside history.pinned() with the planning it
+    # publishes: its epoch is the epoch of the table the optimizer read
     from ..planner.history import history_epoch
 
     return (flavor, fingerprint(sql), sql.strip(), session_key(session),

@@ -136,13 +136,15 @@ class DistributedQueryRunner:
         return self._plan_stmt(parse_statement(sql))
 
     def _plan_stmt(self, stmt: ast.Statement) -> PlanNode:
+        from ..planner import history
         from ..runner import check_select_access
 
-        with self.tracer.span("trino.planner") as sp:
-            sp.record(cache_hit=False)
+        with self.tracer.span("trino.planner") as sp, history.pinned():
+            read = history.thread_bytes_read()
             plan = LogicalPlanner(
                 self.catalog, self.session.default_catalog).plan(stmt)
             plan = optimize(plan, self.catalog)
+            sp.record(cache_hit=False, **history.plan_span_attrs(read))
             check_select_access(plan, self.access_control,
                                 self.session.user)
             writer_tasks = 1
@@ -175,16 +177,20 @@ class DistributedQueryRunner:
 
     def _execute(self, sql: str) -> QueryResult:
         from ..caching import plan_cache, result_cache
+        from ..planner import history
         from ..runner import check_ddl_access, check_select_access
         from ..telemetry import profiler
 
         # Tier A fast path (see runner.py): a hit skips parse → analyze →
         # plan → optimize → add_exchanges; only statements that reached
         # _plan_stmt were ever stored, so non-SELECT texts always miss
-        with profiler.span(profiler.PLAN, "cache-lookup") as lookup:
+        with profiler.span(profiler.PLAN, "cache-lookup") as lookup, \
+                history.pinned():
+            read = history.thread_bytes_read()
             entry = plan_cache.lookup(sql, self.session, self.catalog,
                                       flavor="fragmented")
-            lookup.set(cache_hit=entry is not None)
+            lookup.set(cache_hit=entry is not None,
+                       **history.plan_span_attrs(read))
         if entry is not None:
             check_select_access(entry.plan, self.access_control,
                                 self.session.user)
@@ -224,6 +230,11 @@ class DistributedQueryRunner:
             subplan = fragment_plan(self._plan_stmt(stmt.statement))
             lines = subplan.text().splitlines()
             if stmt.analyze:
+                from ..planner.iterative import last_report
+
+                trace = last_report()
+                if trace is not None and trace.history_lookups:
+                    lines.append(trace.history_line(epoch=True))
                 stats: list[QueryStats] = []
                 self._execute_subplan(subplan, stats)
                 for s in sorted(stats, key=lambda s: s.label):
@@ -250,9 +261,12 @@ class DistributedQueryRunner:
 
         def run(fsm):
             fsm.set("PLANNING")
-            plan = self._plan_stmt(stmt)
-            new_entry = plan_cache.store(sql, self.session, self.catalog,
-                                         plan, flavor="fragmented")
+            # planned and published under one history table: the key's
+            # epoch is the epoch of the table the optimizer read
+            with history.pinned():
+                plan = self._plan_stmt(stmt)
+                new_entry = plan_cache.store(sql, self.session, self.catalog,
+                                             plan, flavor="fragmented")
             # version vector read BEFORE execution (see runner.py: a
             # racing mutation strands the entry, never serves stale)
             store_ctx["key"] = result_cache.result_key(

@@ -15,9 +15,11 @@ Two halves, both here so the fingerprint definition cannot drift:
 - **Reading** (:class:`HistoryProvider`): ``estimate_rows`` and the
   iterative optimizer's reorder/distribution rules look observed stats up
   by the same fingerprint; a hit replaces the estimate.  The provider's
-  table is memoized on the journal file-set signature (the
-  ``seeded_peak`` pattern), so steady-state planning costs a few stat()
-  calls.
+  table is kept beside the journal (:class:`_HistoryTable`): a read costs
+  a stat() per journal file, plus the bytes appended since the last read
+  (about a kilobyte a finished query) folded into the live table.  Only
+  a rotation, or a file that shrank or changed identity, re-reads the
+  journal from nothing.
 
 The fingerprint is **row-equivalence** hashing, not structural hashing:
 two plan shapes that must produce the same row stream hash equal, so a
@@ -40,11 +42,17 @@ is costing on the next run.  Concretely:
 
 Misses degrade to estimates; history can change plans, never results.
 Plan-cache poisoning is prevented by :func:`history_epoch`, a digest of
-the plan_stats corpus mixed into the Tier A key (caching/plan_cache.py).
+the folded table (not of the records it was folded from) mixed into the
+Tier A key (caching/plan_cache.py): a finished query that observed what
+is already known leaves every cached plan reachable, one that observed
+another number strands exactly the plans made before it.  A statement is
+planned and its plan published inside :func:`pinned`, under one table.
 """
 
 from __future__ import annotations
 
+import contextlib
+import dataclasses
 import hashlib
 import threading
 import time
@@ -53,6 +61,7 @@ from typing import Callable, Optional
 
 from ..spi import knobs
 from ..sql.ir import Call, InputRef, Literal, OuterRef, RowExpression
+from ..telemetry import journal
 from .plan import (
     Aggregate,
     DistinctLimit,
@@ -77,8 +86,8 @@ from .plan import (
 
 __all__ = [
     "NodeStats", "HistoryProvider", "hbo_enabled", "provider_if_enabled",
-    "history_epoch", "logical_fingerprint", "fragment_fingerprints",
-    "record_query_stats",
+    "history_epoch", "pinned", "thread_bytes_read", "plan_span_attrs",
+    "logical_fingerprint", "fragment_fingerprints", "record_query_stats",
 ]
 
 
@@ -223,50 +232,146 @@ class NodeStats:
     skew: Optional[float] = None
 
 
-# (journal signature, table, epoch) memo — the seeded_peak pattern
-_TABLE_CACHE: Optional[tuple] = None
+class _HistoryTable:
+    """The folded plan_stats table of one journal, kept beside it: a
+    :class:`~trino_tpu.telemetry.journal.JournalFollower` hands over what
+    was appended, and only that is folded.  One table per stream, because
+    the fold's order is the read set's (streams in name order, each oldest
+    first) and a peer's stream may grow in the middle of it; ``table`` is
+    their merge in that order, the newer observation winning field by
+    field, and what the planner reads.  ``table`` and its entries are
+    replaced, never changed, so a planner keeps the table it began with
+    while another thread folds."""
+
+    def __init__(self, j):
+        self.journal = j
+        self.follower = journal.JournalFollower(j, ("plan_stats",))
+        self.streams: dict[str, dict[str, dict]] = {}  # fp -> {field: v}
+        self.table: dict[str, NodeStats] = {}
+        self.epoch = ""
+
+    def refresh(self) -> int:
+        """Fold what the journal got since the last call; returns the bytes
+        read.  Costs a stat() per journal file while nothing moved."""
+        from ..telemetry import metrics as tm
+
+        polled = self.follower.poll()
+        if polled is None:
+            return 0
+        from_nothing, records, nbytes = polled
+        tm.HBO_JOURNAL_BYTES_READ.inc(nbytes)
+        (tm.HBO_TABLE_REBUILDS if from_nothing else tm.HBO_TABLE_FOLDS).inc()
+        if from_nothing:
+            self.streams = {}
+        touched = set()
+        for stream, rec in records:
+            nodes = rec.get("nodes")
+            if not isinstance(nodes, dict):
+                continue
+            mine = self.streams.setdefault(stream, {})
+            for fp, st in nodes.items():
+                if isinstance(st, dict):
+                    mine.setdefault(fp, {}).update(
+                        (name, st[name]) for name in journal.PLAN_STATS_FIELDS
+                        if st.get(name) is not None)
+                    touched.add(fp)
+        table = {} if from_nothing else self.table
+        for fp in touched:
+            fields: dict = {}
+            for stream in sorted(self.streams):
+                fields.update(self.streams[stream].get(fp, {}))
+            merged = NodeStats(**fields)
+            if table.get(fp) != merged:
+                if table is self.table:
+                    table = dict(table)
+                table[fp] = merged
+        if table is not self.table:
+            self.table = table
+            self.epoch = _epoch_of(table)
+        return nbytes
+
+
+def _epoch_of(table: dict) -> str:
+    """Digest of the table the planner would consult: equal tables give
+    equal epochs, whatever records they were folded from."""
+    if not table:
+        return ""
+    rows = sorted((fp,) + dataclasses.astuple(st)
+                  for fp, st in table.items())
+    return hashlib.sha1(repr(rows).encode("utf-8")).hexdigest()[:12]
+
+
+_STATE: Optional[_HistoryTable] = None
 _TABLE_LOCK = threading.Lock()
+# per thread: the snapshot pinned() holds, and the journal bytes this
+# thread's reads of the table consumed (the recorder's ``plan`` span)
+_TLS = threading.local()
+_UNREAD = object()  # the pin inside pinned() before the block's first read
 
 
 def _stats_table() -> tuple[dict, str]:
-    """(fingerprint -> NodeStats, epoch) from the journal's plan_stats
-    records, newest record winning per fingerprint; memoized on the
-    journal file-set signature."""
-    global _TABLE_CACHE
-    from ..telemetry import journal
-
+    """(fingerprint -> NodeStats, epoch) as the journal's plan_stats
+    records stand now, newest record winning per fingerprint and field —
+    or, inside :func:`pinned`, as the block's first read found them."""
+    global _STATE
+    pin = getattr(_TLS, "pin", None)
+    if pin is not None and pin is not _UNREAD:
+        return pin
     j = journal.get_journal()
     if j is None:
         return {}, ""
     with _TABLE_LOCK:
-        sig = journal._journal_signature(j)
-        if _TABLE_CACHE is not None and _TABLE_CACHE[0] == sig:
-            return _TABLE_CACHE[1], _TABLE_CACHE[2]
-        table: dict[str, NodeStats] = {}
-        h = hashlib.sha1()
-        for rec in j.read(events=("plan_stats",)):
-            nodes = rec.get("nodes")
-            if not isinstance(nodes, dict):
-                continue
-            h.update(repr(sorted(nodes.items())).encode("utf-8"))
-            for fp, st in nodes.items():
-                if not isinstance(st, dict):
-                    continue
-                cur = table.setdefault(fp, NodeStats())
-                for field_name in journal.PLAN_STATS_FIELDS:
-                    v = st.get(field_name)
-                    if v is not None:
-                        setattr(cur, field_name, v)
-        epoch = h.hexdigest()[:12] if table else ""
-        _TABLE_CACHE = (sig, table, epoch)
-        return table, epoch
+        if _STATE is None or _STATE.journal is not j:
+            _STATE = _HistoryTable(j)
+        try:
+            nbytes = _STATE.refresh()
+        except BaseException:
+            _STATE = None  # half a fold is no table: the next read rebuilds
+            raise
+        out = _STATE.table, _STATE.epoch
+    _TLS.bytes_read = thread_bytes_read() + nbytes
+    if pin is _UNREAD:
+        _TLS.pin = out
+    return out
+
+
+@contextlib.contextmanager
+def pinned():
+    """One table for everything this thread reads inside the block, taken
+    at the block's first read.  A statement is planned and its plan
+    published (caching/plan_cache.py ``store``) inside one block, so the
+    Tier A key's epoch is the epoch of the table the optimizer read,
+    whatever other streams append meanwhile.  Nests."""
+    if getattr(_TLS, "pin", None) is not None:
+        yield
+        return
+    _TLS.pin = _UNREAD
+    try:
+        yield
+    finally:
+        _TLS.pin = None
+
+
+def thread_bytes_read() -> int:
+    """Journal bytes this thread's reads of the table have consumed."""
+    return getattr(_TLS, "bytes_read", 0)
+
+
+def plan_span_attrs(read_before: int) -> dict:
+    """What the recorder's ``plan`` span says of history: the epoch the
+    lookup or the planning went by (call it inside their :func:`pinned`
+    block) and the journal bytes read for it."""
+    return {"epoch": history_epoch(),
+            "journal_bytes_read": thread_bytes_read() - read_before}
 
 
 def history_epoch() -> str:
-    """Digest of the observed-stats corpus the planner would consult right
-    now; mixed into the Tier A plan-cache key so history-driven plans
-    never outlive the history that shaped them.  "" when HBO is off or
-    no stats exist."""
+    """Digest of the folded table the planner would consult right now
+    (sorted fingerprint, rows, bytes, groups, skew); mixed into the Tier A
+    plan-cache key so history-driven plans never outlive the history that
+    shaped them.  A record that repeats what is known leaves it as it was;
+    one that changes a number changes it.  "" when HBO is off or no stats
+    exist."""
     if not hbo_enabled():
         return ""
     try:
@@ -279,8 +384,9 @@ class HistoryProvider:
     """Per-planning view over the shared stats table (fresh instance per
     optimize call so lookup/hit counters are per-query for the trace)."""
 
-    def __init__(self, table: dict):
+    def __init__(self, table: dict, epoch: str = ""):
         self.table = table
+        self.epoch = epoch  # of ``table``: what EXPLAIN ANALYZE prints
         self.lookups = 0
         self.hits = 0
         self._fp_cache: dict[int, str] = {}
@@ -317,18 +423,18 @@ def provider_if_enabled() -> Optional[HistoryProvider]:
     if not hbo_enabled():
         return None
     try:
-        table, _ = _stats_table()
+        table, epoch = _stats_table()
     except Exception:
         return None
     if not table:
         return None
-    return HistoryProvider(table)
+    return HistoryProvider(table, epoch)
 
 
 def reset_for_test() -> None:
-    global _TABLE_CACHE
+    global _STATE
     with _TABLE_LOCK:
-        _TABLE_CACHE = None
+        _STATE = None
 
 
 # ------------------------------------------------------------------ recording
@@ -348,8 +454,6 @@ def record_query_stats(fragments, stages, skip_fids, adaptive,
     resident/collective edges); ``adaptive`` (optional) supplies staging
     counters and skew for deferred producers.  Returns the number of
     fingerprints recorded; never raises into the query path."""
-    from ..telemetry import journal
-
     if not hbo_enabled():
         return 0
     j = journal.get_journal()

@@ -155,6 +155,7 @@ def optimize_iterative(root: PlanNode, catalog) -> PlanNode:
     if history is not None:
         ctx.trace.history_lookups = history.lookups
         ctx.trace.history_hits = history.hits
+        ctx.trace.history_epoch = history.epoch
     _LAST.trace = ctx.trace
 
     try:

@@ -21,6 +21,7 @@ class Trace:
         self.fires: list[tuple[str, str, str]] = []  # (phase, rule, node)
         self.history_hits = 0
         self.history_lookups = 0
+        self.history_epoch = ""  # of the table the lookups went to
         self.planning_ms = 0.0
 
     def fire(self, phase: str, rule: str, node: PlanNode) -> None:
@@ -46,10 +47,14 @@ class Trace:
         for phase, rule in order:
             out.append(f"  rule {rule} [{phase}] fired x{seen[(phase, rule)]}")
         if self.history_lookups:
-            out.append(
-                f"history: {'hit' if self.history_hits else 'miss'} "
-                f"({self.history_hits}/{self.history_lookups} lookups)")
+            out.append(self.history_line(epoch=timings))
         return out
+
+    def history_line(self, epoch: bool) -> str:
+        """``epoch``: name the table the plan was made under (ANALYZE)."""
+        return (f"history: {'hit' if self.history_hits else 'miss'} "
+                f"({self.history_hits}/{self.history_lookups} lookups)"
+                + (f", epoch {self.history_epoch}" if epoch else ""))
 
 
 @dataclass

@@ -580,10 +580,14 @@ class StandaloneQueryRunner:
         return self._plan_stmt(parse_statement(sql))
 
     def _plan_stmt(self, stmt: ast.Statement) -> PlanNode:
-        with self.tracer.span("trino.planner"):
+        from .planner import history
+
+        with self.tracer.span("trino.planner") as sp, history.pinned():
+            read = history.thread_bytes_read()
             planner = LogicalPlanner(self.catalog, self.session.default_catalog)
             plan = planner.plan(stmt)
             plan = optimize(plan, self.catalog)
+            sp.record(cache_hit=False, **history.plan_span_attrs(read))
         check_select_access(plan, self.access_control, self.session.user)
         return plan
 
@@ -640,8 +644,13 @@ class StandaloneQueryRunner:
                           lambda st: self._execute_stmt(st, False)[0])
         if ddl is not None:
             return ddl
-        plan = self._plan_stmt(stmt)
-        entry = plan_cache.store(sql, self.session, self.catalog, plan)
+        from .planner import history
+
+        # planned and published under one history table: the key's epoch
+        # is the epoch of the table the optimizer read
+        with history.pinned():
+            plan = self._plan_stmt(stmt)
+            entry = plan_cache.store(sql, self.session, self.catalog, plan)
         # Tier C: capture the table-version vector BEFORE executing — a
         # mutation racing the read then strands the entry under a stale
         # key (never served) instead of publishing stale data as fresh

@@ -39,8 +39,8 @@ from ..spi.eventlistener import (
 
 __all__ = [
     "SCHEMA_VERSION", "REQUIRED_FIELDS", "PLAN_STATS_FIELDS", "QueryJournal",
-    "default_dir", "journal_enabled", "get_journal", "history", "seeded_peak",
-    "sample_records", "reset_for_test",
+    "JournalFollower", "default_dir", "journal_enabled", "get_journal",
+    "history", "seeded_peak", "sample_records", "reset_for_test",
 ]
 
 # v2: adds the per-query ``plan_stats`` event — observed per-plan-node
@@ -287,20 +287,98 @@ class QueryJournal(EventListener):
             try:
                 with open(path, encoding="utf-8") as f:
                     for line in f:
-                        line = line.strip()
-                        if not line:
-                            continue
-                        try:
-                            rec = json.loads(line)
-                        except ValueError:
-                            continue
-                        if not isinstance(rec, dict) or "schema" not in rec:
-                            continue
-                        if events is None or rec.get("event") in events:
+                        rec = _record_of(line, events)
+                        if rec is not None:
                             out.append(rec)
             except OSError:
                 continue
         return out
+
+
+def _record_of(line, events: Optional[tuple]) -> Optional[dict]:
+    """The journal record a line (text or bytes) holds, if it is one and of
+    a wanted event type; garbage and torn lines are nobody's record."""
+    line = line.strip()
+    if not line:
+        return None
+    try:
+        rec = json.loads(line)
+    except ValueError:
+        return None
+    if not isinstance(rec, dict) or "schema" not in rec:
+        return None
+    return rec if events is None or rec.get("event") in events else None
+
+
+def _stream_of(path: str) -> str:
+    """The stream a journal file belongs to: its path without the rotated
+    generation's ``.N`` (every member of a fleet appends to its own)."""
+    base, _, suffix = path.rpartition(".")
+    return base if suffix.isdigit() else path
+
+
+class JournalFollower:
+    """Hands out the fleet's journal records of some event types, each once:
+    a cursor per file (path, inode, bytes consumed), moved by what was
+    appended.  The trigger is :func:`_journal_signature` (a ``stat()`` per
+    file of the fleet set, so a peer's append is seen).  While the same
+    files only grow, :meth:`poll` reads each from its cursor and consumes
+    whole lines (a torn tail waits for its newline).  Anything else — a file
+    shrank, vanished, appeared, changed identity, rotated — makes the next
+    poll start from nothing and say so: a rotation drops the oldest
+    generation, so a consumer's fold may have to lose records again.
+
+    Not thread-safe: the consumer holds its own lock around ``poll``."""
+
+    def __init__(self, journal: QueryJournal, events: tuple):
+        self.journal = journal
+        self.events = events
+        # a line of another event type is not parsed: json.dumps writes the
+        # type's name in quotes, so a line without it cannot be of the type
+        self._needles = tuple(json.dumps(e).encode("utf-8") for e in events)
+        self._sig: Optional[tuple] = None
+        self._cursors: dict[str, tuple[int, int]] = {}  # path -> (ino, at)
+
+    def poll(self) -> Optional[tuple[bool, list, int]]:
+        """None while nothing moved; else ``(from_nothing, [(stream,
+        record)], bytes_read)``, the records oldest-first per stream and
+        the streams in :meth:`QueryJournal.fleet_files` order.  With
+        ``from_nothing`` the records are every record on disk, and what
+        earlier polls handed out is void."""
+        sig = _journal_signature(self.journal)
+        if sig == self._sig:
+            return None
+        same_files = [(p, ino) for p, _, _, ino in sig] == \
+            [(p, ino) for p, (ino, _) in self._cursors.items()]
+        grew = self._sig is not None and same_files and \
+            all(size >= self._cursors[p][1] for p, size, _, _ in sig)
+        if not grew:
+            self._cursors = {p: (ino, 0) for p, _, _, ino in sig}
+        records: list = []
+        total = 0
+        for path, size, _, ino in sig:
+            at = self._cursors[path][1]
+            if size <= at:
+                continue
+            try:
+                with open(path, "rb") as f:
+                    if os.fstat(f.fileno()).st_ino != ino:
+                        continue  # rotated since the stat, see below
+                    f.seek(at)
+                    data = f.read()
+            except OSError:
+                continue  # gone since the stat: the next signature says so
+            total += len(data)
+            whole = data.rfind(b"\n") + 1
+            self._cursors[path] = (ino, at + whole)
+            stream = _stream_of(path)
+            for line in data[:whole].splitlines():
+                if any(n in line for n in self._needles):
+                    rec = _record_of(line, self.events)
+                    if rec is not None:
+                        records.append((stream, rec))
+        self._sig = sig
+        return not grew, records, total
 
 
 # ------------------------------------------------------------ process state
@@ -308,9 +386,12 @@ class QueryJournal(EventListener):
 _SINGLETON: Optional[QueryJournal] = None
 _SINGLETON_LOCK = threading.Lock()
 # fingerprint → [peaks] seed map, keyed by the journal file-set signature
-# it was built from: (sig, cache).  Rebuilt only when a journal file
-# appears/rotates/grows — an admission decision costs a stat() per file,
-# not a full re-read
+# it was built from: (sig, cache).  Rebuilt whenever a journal file
+# appears, rotates or grows — and every finished query grows one, so an
+# admission decision that gets here (a capped memory manager and no
+# in-memory peak for the fingerprint) re-reads every file.  The history
+# table (planner/history.py) follows the journal through a JournalFollower
+# instead; this fold can take the same reader when it lands on a hot path
 _SEED_CACHE: Optional[tuple] = None
 _SEED_LOCK = threading.Lock()
 
@@ -324,7 +405,7 @@ def _journal_signature(j: QueryJournal) -> tuple:
             st = os.stat(path)
         except OSError:
             continue
-        sig.append((path, st.st_size, st.st_mtime_ns))
+        sig.append((path, st.st_size, st.st_mtime_ns, st.st_ino))
     return tuple(sig)
 
 
@@ -353,9 +434,9 @@ def history() -> list[dict]:
 def seeded_peak(fp: str, history_len: int = 5) -> int:
     """Journal-seeded admission estimate: max peak of the fingerprint's
     most recent FINISHED runs on disk, 0 when unknown.  The seed map is
-    memoized on the journal file-set signature (path, size, mtime), so
-    steady-state admission does a handful of stat() calls and re-reads the
-    files only when another coordinator appended or a rotation happened."""
+    memoized on the journal file-set signature (path, size, mtime, inode):
+    a handful of stat() calls while no file moved, a re-read of every file
+    after any append, local or a peer's (see ``_SEED_CACHE``)."""
     global _SEED_CACHE
     j = get_journal()
     if j is None:

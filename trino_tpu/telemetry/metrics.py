@@ -669,6 +669,17 @@ HBO_RECORD_ERRORS = REGISTRY.counter(
 HBO_FANOUT_ADJUSTED = REGISTRY.counter(
     "trino_hbo_fanout_adjusted_total",
     "stages whose task count was shrunk from history-observed input rows")
+HBO_JOURNAL_BYTES_READ = REGISTRY.counter(
+    "trino_hbo_journal_bytes_read_total",
+    "journal bytes the history table read: what was appended since its "
+    "last read, or every file when it had to start from nothing")
+HBO_TABLE_FOLDS = REGISTRY.counter(
+    "trino_hbo_table_folds_total",
+    "reads of the history table that folded appended journal bytes into it")
+HBO_TABLE_REBUILDS = REGISTRY.counter(
+    "trino_hbo_table_rebuilds_total",
+    "reads of the history table that re-read the journal from nothing: the "
+    "first, and after a rotation or a file that shrank or changed identity")
 
 
 # compressed execution (spi/batch.py encodings + encoding-aware operators):

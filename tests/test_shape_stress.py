@@ -100,3 +100,105 @@ def test_distinct_and_topn_at_edges():
     assert r.execute("select count(distinct k) from de").rows() == [(256,)]
     top = r.execute("select k from de order by k desc limit 8").rows()
     assert [t[0] for t in top] == [255] * 4 + [254] * 4
+
+
+# ---------------------------------------------------------------------------
+# the join path's shape set (PR 36): a probe page that crossed a REPARTITION
+# exchange used to arrive cut to its exact row count, and every join program
+# was compiled once a distinct count (~130 shapes at SF10, a minute each)
+
+_SHAPE_SQL = {
+    "lineitem_orders": (
+        "select count(*), sum(l_extendedprice) from lineitem join orders "
+        "on l_orderkey = o_orderkey where o_orderdate < date '1995-03-15'"),
+    "right_join": (
+        "select count(*), count(o_orderkey), sum(l_quantity) from orders "
+        "right join lineitem on o_orderkey = l_orderkey "
+        "and o_orderdate < date '1995-03-15'"),
+    "q3": None,  # connectors.tpch_queries.QUERIES[3]
+}
+
+
+def _is_pow2(n: int) -> bool:
+    return n > 0 and n & (n - 1) == 0
+
+
+@pytest.mark.parametrize("resident", ["large_pages", "every_page"])
+@pytest.mark.parametrize("batch_rows", [4096, 1024])
+@pytest.mark.parametrize("name", sorted(_SHAPE_SQL))
+def test_join_programs_compile_at_power_of_two_lanes_only(
+        name, batch_rows, resident, monkeypatch):
+    """Every join planned PARTITIONED (a build over the planner's broadcast
+    limit; a RIGHT join always is), three tasks a stage, many small probe
+    pages with as many different live counts out of the REPARTITION sinks:
+    each ``join.*`` program sees power-of-two lane counts only (the dense
+    table, sized to its key range, is the next issue's), and the number of
+    shapes does not grow with the number of pages.  Both ways a page can
+    cross: by the host, cut and padded to its bucket (these pages are small:
+    ``large_pages``), and on the device under a mask (``every_page``)."""
+    import jax
+
+    from trino_tpu.caching import executable_cache as EC
+    from trino_tpu.caching import result_cache
+    from trino_tpu.connectors.tpch_queries import QUERIES
+    from trino_tpu.exec.operators import LookupJoinOperator
+    from trino_tpu.execution import task as T
+    from trino_tpu.planner import optimizer as O
+    from trino_tpu.testing.oracle import assert_same_rows
+
+    monkeypatch.setenv("TRINO_TPU_FUSED_STAGE", "0")
+    monkeypatch.setenv("TRINO_TPU_RESIDENT_PLAN", "0")
+    monkeypatch.setattr(O, "_BROADCAST_LIMIT", 0)
+    if resident == "every_page":
+        monkeypatch.setattr(T, "_RESIDENT_MIN_LANES", 1)
+    monkeypatch.setenv("TRINO_TPU_COALESCE_TARGET_ROWS", str(batch_rows))
+    shapes: dict = {}
+    dense_tables: set = set()
+    call = EC._Program.__call__
+
+    def spy(self, *args, **kwargs):
+        out = call(self, *args, **kwargs)
+        if self.name.startswith("trino_join_"):
+            shapes.setdefault(self.name, set()).add(tuple(
+                x.shape[0] for x in jax.tree_util.tree_leaves((args, kwargs))
+                if getattr(x, "ndim", 0)))
+            if self.name == "trino_join_dense_build":
+                dense_tables.update(
+                    x.shape[0] for x in jax.tree_util.tree_leaves(out)
+                    if getattr(x, "ndim", 0))
+        return out
+
+    monkeypatch.setattr(EC._Program, "__call__", spy)
+    pages = []
+    add_input = LookupJoinOperator.add_input
+
+    def count_pages(self, probe):
+        pages.append(probe.num_rows)
+        return add_input(self, probe)
+
+    monkeypatch.setattr(LookupJoinOperator, "add_input", count_pages)
+    catalog = default_catalog(scale_factor=0.01)
+    catalog.connector("tpch").batch_rows = batch_rows
+    sql = _SHAPE_SQL[name] or QUERIES[3]
+    dist = DistributedQueryRunner(
+        catalog, worker_count=3,
+        session=Session(node_count=3, use_collectives=False))
+    assert "PARTITIONED" in dist.explain(sql)
+    with result_cache.disabled():
+        rows = dist.execute(sql).rows()
+    probe_pages = list(pages)
+    seen = {program: set(sigs) for program, sigs in shapes.items()}
+    assert_same_rows(
+        rows, StandaloneQueryRunner(catalog).execute(sql).rows(),
+        ordered=name == "q3")
+    # lineitem's 60,175 rows in small pages, each cut three ways by the
+    # sink: as many live counts as pages
+    assert len(probe_pages) >= 9, probe_pages
+    assert all(_is_pow2(n) for n in probe_pages)
+    assert seen, "no join program ran"
+    for program, signatures in seen.items():
+        lanes = {n for sig in signatures for n in sig} - dense_tables
+        assert all(_is_pow2(n) for n in lanes), (program, sorted(lanes))
+        # a shape a bucket (and a join), not a shape a page: the parent
+        # compiled one a page here
+        assert len(signatures) <= 8, (program, sorted(signatures))

@@ -228,3 +228,24 @@ def test_masked_aggregation_fold_with_a_donated_state(shape, rows):
     K._small_agg_zero_fn(layout, 6, True).lower().compile()
     K._small_agg_state_out_fn(spec, (3, 2),
                               (False, False)).lower(*state).compile()
+
+
+@pytest.mark.parametrize("rows", BUCKETS)
+def test_exchange_partition_masks(shape, rows):
+    # a REPARTITION sink over a page that stays on the device (PR 36): a
+    # nullable BIGINT key and a dictionary key hashed by value, under the
+    # page's live mask, three consumers -- the 64-bit remainder included
+    K._partition_masks_fn((True, False), (False, True), True, 3).lower(
+        shape(rows, jnp.int64), shape(rows, jnp.bool_),
+        shape(rows, jnp.int32), shape(64, jnp.int64),
+        shape(rows, jnp.bool_)).compile()
+
+
+@pytest.mark.parametrize("rows", BUCKETS)
+def test_exchange_live_count_and_shrink(shape, rows):
+    # the sink's count a page, and the shrink of a sparse page to a quarter
+    # of its lanes (Q3's customer page: two BIGINT columns, one nullable)
+    K.live_count.lower(shape(rows, jnp.bool_)).compile()
+    K._compact_fn(2, (False, True), True, rows >> 2).lower(
+        shape(rows, jnp.bool_), shape(rows, jnp.int64),
+        shape(rows, jnp.int64), shape(rows, jnp.bool_)).compile()

@@ -2305,6 +2305,10 @@ class LookupJoinOperator(Operator):
         if not self.left_keys:  # cross join (nested-loop fallback)
             self._add_cross_input(probe)
             return
+        # the probe programs are compiled per lane count: a dense page (a
+        # remote producer's, a host operator's) takes its bucket first, so
+        # they see powers of two from every source
+        probe = pad_to_bucket(probe)
         build = self.bridge.batch
         table = self.bridge.table
         keys = [(JX.key_input(probe.columns[ch]), probe.columns[ch].valid)

@@ -487,7 +487,9 @@ class QueryStats:
             lines.append(self.label)
         if self.scan is not None and self.scan.scan_batches:
             lines.append("  " + self.scan.text())
-        if self.sync is not None and self.sync.host_syncs:
+        if self.sync is not None and (
+                self.sync.host_syncs or self.sync.exchange_pages_device
+                or self.sync.exchange_pages_densified):
             lines.append("  " + self.sync.text())
         if self.resilience is not None and self.resilience.any:
             lines.append("  " + self.resilience.text())

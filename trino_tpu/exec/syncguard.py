@@ -72,7 +72,12 @@ class SyncStats:
     probe's gathers (join_exec.run_unique_gather) by the width they ran at:
     the probe batch's lanes, or a cap sized from a match count (an overflow's
     wide re-run counts as wide); ``unique_gather_seeded`` the subset whose
-    estimate was the seed an earlier execution left."""
+    estimate was the seed an earlier execution left.
+    ``exchange_pages_device``/``exchange_pages_densified`` count the pages an
+    exchange sink (execution/task.PartitionedOutputSink) handed on as they
+    were -- on the device, bucket-shaped, masked -- and those it pulled to
+    the host and cut to their rows because they were about to be serialized;
+    ``exchange_densified_bytes`` the bytes of the latter."""
 
     host_syncs: int = 0
     blocking_syncs: int = 0
@@ -83,6 +88,9 @@ class SyncStats:
     unique_gather_wide: int = 0
     unique_gather_compact: int = 0
     unique_gather_seeded: int = 0
+    exchange_pages_device: int = 0
+    exchange_pages_densified: int = 0
+    exchange_densified_bytes: int = 0
     hot_loop_syncs: int = 0      # blocking syncs inside hot regions (want: 0)
     by_tag: dict = field(default_factory=dict)
 
@@ -104,7 +112,10 @@ class SyncStats:
             f"expand overflow {self.expand_overflows}/"
             f"retry {self.expand_retries}, unique gathers "
             f"{self.unique_gather_wide} wide/{self.unique_gather_compact} "
-            f"compact ({self.unique_gather_seeded} seeded)"
+            f"compact ({self.unique_gather_seeded} seeded), exchange pages "
+            f"{self.exchange_pages_device} device/"
+            f"{self.exchange_pages_densified} densified "
+            f"({self.exchange_densified_bytes} B)"
             + (f" [{tags}]" if tags else "")
         )
 
@@ -187,6 +198,15 @@ def count_unique_gather(compact: bool, seeded: bool) -> None:
             _STATS.unique_gather_wide += 1
         if seeded:
             _STATS.unique_gather_seeded += 1
+
+
+def count_exchange_page(device: bool, nbytes: int) -> None:
+    with _LOCK:
+        if device:
+            _STATS.exchange_pages_device += 1
+        else:
+            _STATS.exchange_pages_densified += 1
+            _STATS.exchange_densified_bytes += nbytes
 
 
 def fetch(x, tag: str):

@@ -282,6 +282,9 @@ class _Router(threading.Thread):
 
     def _route(self, batch) -> None:
         n = self.n
+        # host routing cuts rows: ask for them (a staging sink densifies,
+        # so this is the identity on what it enqueued)
+        batch = batch.compact()
         if batch.num_rows == 0:
             return
         if self.mode == "broadcast":

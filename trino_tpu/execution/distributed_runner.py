@@ -1215,6 +1215,9 @@ class DistributedQueryRunner:
                 f.output_keys, serde=self.session.exchange_serde,
                 sketch=sketch, sketch_keys=sketch_keys,
                 coalesce_rows=f.sink_coalesce_rows)
+            # output pages stay on the device until a consumer acks them:
+            # the task's pool is charged for them
+            sink.attach_memory(planner.memory)
         local.pipelines[-1][-1] = sink
         stats = None
         if stats_sink is not None:

@@ -9,7 +9,11 @@ advance, done marker) is kept so a real DCN/HTTP transport can slot in
 without changing operators.
 
 Backpressure: per-buffer byte budget; producers block in ``enqueue`` until
-consumers drain (OutputBufferMemoryManager.java's blocking future).
+consumers drain (OutputBufferMemoryManager.java's blocking future).  The
+budget counts what a page really holds (a masked page: all its lanes); the
+cumulative ``rows_enqueued`` / ``bytes_enqueued`` the planner reads count
+LIVE rows, which the producer passes for a page whose mask is on the device
+(``live_rows``): nothing here fetches from the device.
 """
 
 from __future__ import annotations
@@ -17,9 +21,23 @@ from __future__ import annotations
 import threading
 from typing import Optional
 
+import numpy as np
+
+from ..exec.revoking import batch_device_nbytes
 from ..spi.batch import ColumnBatch
 
-__all__ = ["OutputBuffer", "ExchangeClient"]
+__all__ = ["OutputBuffer", "ExchangeClient", "known_live_rows"]
+
+
+def known_live_rows(page) -> Optional[int]:
+    """A page's live rows where the host knows them without a fetch (a
+    serialized page carries no row count: 0), else None."""
+    live = getattr(page, "live", None)
+    if live is None:
+        return getattr(page, "num_rows", 0)
+    if isinstance(live, np.ndarray):
+        return int(live.sum())
+    return None
 
 
 class OutputBuffer:
@@ -34,20 +52,24 @@ class OutputBuffer:
         self._finished = False
         self._aborted = False
         self._bytes = 0
+        self.device_bytes = 0  # of the unacked pages, what sits on the device
         self._cv = threading.Condition()
         self.pages_enqueued = 0
+        # cumulative LIVE rows and their bytes (never decremented on ack) —
+        # the planner's history and the adaptive scheduler's observed-output
+        # counters for activation barriers and join-distribution decisions
         self.rows_enqueued = 0
-        # cumulative (never decremented on ack) — the adaptive scheduler's
-        # observed-output counter for activation barriers and join-
-        # distribution decisions
         self.bytes_enqueued = 0
 
     def enqueue(self, partition: int, batch: ColumnBatch,
-                block: bool = True) -> None:
+                block: bool = True, live_rows: Optional[int] = None) -> None:
         """``block=False`` skips the backpressure wait (time-sharing mode:
         the sink's driver parks via ``needs_input`` instead of pinning its
         executor worker here; at most one batch's partitions overshoot the
-        byte budget between capacity checks)."""
+        byte budget between capacity checks).  ``live_rows``: the page's
+        live rows, from the producer (the sink lands a device mask's count
+        before it enqueues the page); a page whose mask is on the host, or
+        that has none, is counted here."""
         with self._cv:
             while (block and self._bytes > self.max_bytes
                    and not self._aborted):
@@ -55,12 +77,38 @@ class OutputBuffer:
             if self._aborted:
                 return
             self._pages[partition].append(batch)
-            self._bytes += batch.nbytes
+            nbytes = batch.nbytes
+            self._bytes += nbytes
             self.pages_enqueued += 1
-            # wire relays enqueue SerializedPage, which carries no row count
-            self.rows_enqueued += getattr(batch, "num_rows", 0)
-            self.bytes_enqueued += batch.nbytes
+            if isinstance(batch, ColumnBatch):
+                self.device_bytes += batch_device_nbytes(batch)
+                if live_rows is None:
+                    live_rows = known_live_rows(batch)
+                if live_rows is not None:
+                    self.rows_enqueued += live_rows
+                    self.bytes_enqueued += (
+                        nbytes if batch.live is None
+                        else batch.live_nbytes(live_rows))
+            else:
+                # wire relays enqueue SerializedPage: no row count, and its
+                # bytes are those of its (dense) rows
+                self.bytes_enqueued += nbytes
             self._cv.notify_all()
+
+    def evict_to_host(self) -> int:
+        """Move every unacked device page to host memory (memory revoked
+        from the producing task); returns the device bytes freed.  A page
+        keeps its lanes and its mask."""
+        with self._cv:
+            moved: dict[int, ColumnBatch] = {}
+            for stream in self._pages:
+                for i, b in enumerate(stream):
+                    if isinstance(b, ColumnBatch) and batch_device_nbytes(b):
+                        if id(b) not in moved:
+                            moved[id(b)] = b.to_host()
+                        stream[i] = moved[id(b)]
+            freed, self.device_bytes = self.device_bytes, 0
+            return freed
 
     def has_capacity(self) -> bool:
         """True while the byte budget admits another page (the non-blocking
@@ -82,6 +130,7 @@ class OutputBuffer:
             self._aborted = True
             self._pages = [[] for _ in range(self.num_partitions)]
             self._bytes = 0
+            self.device_bytes = 0
             self._cv.notify_all()
 
     @property
@@ -111,6 +160,10 @@ class OutputBuffer:
                     b = stream[i - acked]
                     if b is not None:
                         self._bytes -= b.nbytes
+                        if isinstance(b, ColumnBatch):
+                            self.device_bytes = max(
+                                0, self.device_bytes
+                                - batch_device_nbytes(b))
                         stream[i - acked] = None
                 # drop freed prefix
                 drop = token - acked

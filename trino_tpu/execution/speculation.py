@@ -144,6 +144,11 @@ class GatedBuffer:
     def aborted(self) -> bool:
         return self._inner.aborted
 
+    def __getattr__(self, name):
+        # what a write does not gate: the buffer's accounting surface
+        # (device_bytes, evict_to_host)
+        return getattr(self._inner, name)
+
     def enqueue(self, partition: int, batch, **kw) -> None:
         if not self._gate.claim(self.kind):
             raise SpeculationLost(self.kind)
@@ -238,8 +243,15 @@ class SpoolTeeBuffer:
     def aborted(self) -> bool:
         return self._inner.aborted
 
+    def __getattr__(self, name):
+        # the inner buffer's accounting surface (device_bytes,
+        # evict_to_host); the spool keeps no counters
+        return getattr(self._inner, name)
+
     def enqueue(self, partition: int, batch, **kw) -> None:
         self._inner.enqueue(partition, batch, **kw)
+        # the spool serializes, so it densifies (serde.serialize_batch): a
+        # masked device page lands on disk as its live rows
         self._writer.enqueue(partition, batch)
 
     def has_capacity(self) -> bool:

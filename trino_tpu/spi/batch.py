@@ -659,6 +659,30 @@ class ColumnBatch:
     def nbytes(self) -> int:
         return sum(c.nbytes for c in self.columns)
 
+    def live_nbytes(self, rows: int) -> int:
+        """Bytes a dense page of ``rows`` of this batch's rows holds (values
+        as they are stored, a run's value and the dictionaries once) -- what
+        the planner's statistics mean by a page's bytes, whatever lanes the
+        rows ride in.  For an unmasked flat batch of ``rows`` rows this is
+        ``nbytes``.  The history table's epoch digests these numbers: take
+        them where no other thread can touch a lazy column meanwhile (its
+        first touch changes its encoding)."""
+        n = 0
+        for c in self.columns:
+            enc = c._enc
+            if enc == "RLE":
+                n += int(c._rle_value.nbytes)
+                per_row = 0
+            elif enc == "LAZY":
+                per_row = np.dtype(np.int32 if c.dictionary is not None
+                                   else c.type.storage_dtype).itemsize
+            else:
+                per_row = np.dtype(c._data.dtype).itemsize
+            if enc != "LAZY" and c._valid is not None:
+                per_row += 1
+            n += rows * per_row + _dictionary_nbytes(c.dictionary)
+        return n
+
     def column(self, name: str) -> Column:
         return self.columns[self.names.index(name)]
 

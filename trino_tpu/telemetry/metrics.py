@@ -34,7 +34,7 @@ from typing import Optional
 
 __all__ = [
     "Counter", "Gauge", "Distribution", "MetricsRegistry", "REGISTRY",
-    "observe_scan", "observe_sync", "observe_resilience", "observe_fused",
+    "observe_scan", "observe_sync", "observe_exchange_page", "observe_resilience", "observe_fused",
     "observe_resident", "observe_exchange", "observe_adaptive",
     "observe_encoding",
     "update_device_memory_watermark",
@@ -372,6 +372,15 @@ UNIQUE_GATHER_WIDE = REGISTRY.counter(
 UNIQUE_GATHER_COMPACT = REGISTRY.counter(
     "trino_exec_unique_gather_compact_total",
     "unique-build probe gathers compacted to a cap sized from a match count")
+EXCHANGE_PAGES_DEVICE = REGISTRY.counter(
+    "trino_exchange_pages_device_total",
+    "exchange pages handed on device-resident, bucket-shaped and masked")
+EXCHANGE_PAGES_DENSIFIED = REGISTRY.counter(
+    "trino_exchange_pages_densified_total",
+    "exchange pages pulled to the host and cut to their rows to be serialized")
+EXCHANGE_DENSIFIED_BYTES = REGISTRY.counter(
+    "trino_exchange_pages_densified_bytes_total",
+    "bytes of the exchange pages densified on the host")
 UNIQUE_GATHER_SEEDED = REGISTRY.counter(
     "trino_exec_unique_gather_seeded_total",
     "unique-build probe gathers sized from an earlier execution's seed")
@@ -769,6 +778,17 @@ def observe_scan(ingest) -> None:
     SCAN_WAIT_SECONDS.inc(ingest.consumer_wait_s)
     if ingest.gbps:
         SCAN_GBPS.set(round(ingest.gbps, 3))
+
+
+def observe_exchange_page(device: bool, nbytes: int = 0) -> None:
+    """One page through an exchange sink (execution/task.py), as it goes:
+    the distributed runner folds no SyncStats delta, so the sink counts
+    here itself."""
+    if device:
+        EXCHANGE_PAGES_DEVICE.inc()
+    else:
+        EXCHANGE_PAGES_DENSIFIED.inc()
+        EXCHANGE_DENSIFIED_BYTES.inc(nbytes)
 
 
 def observe_sync(sync) -> None:

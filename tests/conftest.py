@@ -20,6 +20,15 @@ os.environ["TRINO_TPU_JOURNAL_DIR"] = tempfile.mkdtemp(
     prefix="trino-tpu-test-journal-")
 os.environ["TRINO_TPU_HBO"] = "0"
 
+# The query-state WAL (retry_policy=TASK) is durable by design too, and its
+# default directory is one per uid: a TrinoTpuServer that boots in one xdist
+# worker adopts whatever in-flight query it finds there (boot recovery), so
+# it re-ran another worker's running FTE query over the same spool root, and
+# that query then read 0 rows (seen 3 times in 5 whole runs once PR 37 moved
+# the files' timing).  One directory a test process; children inherit it.
+os.environ["TRINO_TPU_QUERY_STATE_DIR"] = tempfile.mkdtemp(
+    prefix="trino-tpu-test-query-state-")
+
 # executable_cache.init_compile_cache() would otherwise have six xdist
 # workers (and every spawned worker process) write each CPU executable into
 # one <checkout>/.jax_cache; the suite keeps JAX's persistent cache off, as

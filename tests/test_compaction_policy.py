@@ -164,10 +164,11 @@ def test_only_the_sorting_paths_pay_for_compaction(name, kind):
         es = op.encoding_stats
         assert (es.agg_masked, es.agg_compaction_skipped,
                 es.agg_compacted) == (1, 1, 0)
-        # (since PR 30 these stream: ``lanes`` is the last batch's)
+        # (since PR 30 these stream: ``lanes`` is the last batch's; since
+        # PR 37 a launch takes a group: the one batch, at finish)
         assert op.trace_attrs == {"path": "masked", "compaction": "skipped",
                                   "lanes": LANES, "mode": "streamed",
-                                  "fused": False}
+                                  "fused": False, "batches": 1}
     else:
         # a sort follows: one count sync each time, and the compaction when
         # under a quarter of the lanes live

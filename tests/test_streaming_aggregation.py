@@ -1,7 +1,10 @@
 """The streaming masked aggregation (PR 30): a PARTIAL or SINGLE aggregation
 that will take the masked path folds each batch into a small device-resident
 state with ONE program -- the filter/project's own when one sits directly in
-front -- instead of buffering its input and reducing it later.
+front -- instead of buffering its input and reducing it later.  Since PR 37
+that program takes a GROUP of up to ``O._FOLD_GROUP`` batches a launch: the
+operator holds a batch's operands until the group is full or something ends
+it (another signature, other dictionaries, finish, close).
 
 Equivalence is streamed against buffered (the same operator with its
 decision steered to "buffer", the way every aggregation ran before); the
@@ -30,11 +33,13 @@ from trino_tpu.ops.expr import QueryError, check_error_scalars
 from trino_tpu.planner.plan import AggCall
 from trino_tpu.runner import StandaloneQueryRunner
 from trino_tpu.spi.batch import Column, ColumnBatch
-from trino_tpu.spi.types import BIGINT, BOOLEAN, DOUBLE, VARCHAR
+from trino_tpu.spi.types import (BIGINT, BOOLEAN, DOUBLE, VARCHAR,
+                                 DecimalType)
 from trino_tpu.sql.ir import Call, InputRef, Literal
 from trino_tpu.telemetry import profiler
 
 ROWS = 4096
+GROUP = O._FOLD_GROUP
 FLAGS = np.array(["A", "N", "R"], dtype=object)
 FLAGS_WIDER = np.array(["A", "B", "N", "R"], dtype=object)
 STATUS = np.array(["F", "O"], dtype=object)
@@ -139,50 +144,60 @@ def _three(**kw):
 
 
 STREAMS = {
-    # name: (batches, group keys, seals expected)
-    "global": (lambda: _three(), (), 0),
-    "grouped": (lambda: _three(), (0, 1), 0),
-    "global_no_live_mask": (lambda: _three(live=None), (), 0),
-    "grouped_device_batches": (lambda: _three(device=True), (0, 1), 0),
-    "nullable_keys_and_arguments": (lambda: _three(nulls=True), (0, 1), 0),
-    "global_nullable_arguments": (lambda: _three(nulls=True), (), 0),
+    # name: (batches, group keys, seals expected, launches expected: one a
+    # group, and a group ends where the signature or the dictionaries change)
+    "global": (lambda: _three(), (), 0, 1),
+    "grouped": (lambda: _three(), (0, 1), 0, 1),
+    "global_no_live_mask": (lambda: _three(live=None), (), 0, 1),
+    "grouped_device_batches": (lambda: _three(device=True), (0, 1), 0, 1),
+    "nullable_keys_and_arguments": (lambda: _three(nulls=True), (0, 1), 0, 1),
+    "global_nullable_arguments": (lambda: _three(nulls=True), (), 0, 1),
     "an_all_filtered_batch": (
-        lambda: [_batch(1), _batch(2, live="dead"), _batch(3)], (0, 1), 0),
+        lambda: [_batch(1), _batch(2, live="dead"), _batch(3)], (0, 1), 0, 1),
+    "a_zero_row_batch_in_the_group": (
+        lambda: [_batch(1), _batch(2, live=None).slice(0, 0), _batch(3)],
+        (0, 1), 0, 1),
     "only_filtered_batches_global": (
-        lambda: [_batch(1, live="dead"), _batch(2, live="dead")], (), 0),
+        lambda: [_batch(1, live="dead"), _batch(2, live="dead")], (), 0, 1),
     "only_filtered_batches_grouped": (
-        lambda: [_batch(1, live="dead"), _batch(2, live="dead")], (0, 1), 0),
+        lambda: [_batch(1, live="dead"), _batch(2, live="dead")], (0, 1), 0,
+        1),
     "last_batch_in_a_smaller_bucket": (
+        # another signature: the two held batches are folded first
         lambda: [_batch(1, live=None), _batch(2, live=None),
-                 _batch(3, n=1000, live=None)], (0, 1), 0),
+                 _batch(3, n=1000, live=None)], (0, 1), 0, 2),
     "validity_appears_mid_stream": (
         # the null slot changes the group space: a new state
         lambda: [_batch(1), _batch(2, nulls=True), _batch(3, nulls=True)],
-        (0, 1), 1),
+        (0, 1), 1, 2),
     "dictionary_change_mid_stream": (
         lambda: [_batch(1), _batch(2), _batch(3, flags=FLAGS_WIDER),
-                 _batch(4, flags=FLAGS_WIDER)], (0, 1), 1),
+                 _batch(4, flags=FLAGS_WIDER)], (0, 1), 1, 2),
     "dictionary_change_every_batch": (
         lambda: [_batch(s, flags=FLAGS.copy()) for s in (1, 2, 3)],
-        (0,), 2),
+        (0,), 2, 3),
+    "a_group_and_a_remainder": (
+        lambda: [_batch(s, n=512) for s in range(GROUP + 3)], (0, 1), 0, 2),
 }
 
 
 @pytest.mark.parametrize("name", list(STREAMS))
 def test_streamed_equals_buffered(name, monkeypatch):
-    make, keys, seals = STREAMS[name]
+    make, keys, seals, groups = STREAMS[name]
     batches = make()
+    streamed = sum(1 for b in batches if b.num_rows)
     op = _agg(keys)
     t0 = profiler.now()
     got = _pages(op, batches)
     es = op.encoding_stats
     assert op._streamed and not op._batches
-    assert es.agg_streamed_batches == len(batches)
+    assert (es.agg_streamed_batches, es.agg_fold_launches) \
+        == (streamed, groups)
     assert (es.agg_state_seals, es.agg_fused_feed) == (seals, 0)
     assert len(got) == 1                      # SINGLE: one page, seals merge
     launches = [e["name"] for e in profiler.events_since(t0)
                 if e["kind"] == profiler.LAUNCH]
-    assert launches.count(FOLD) == len(batches)
+    assert launches.count(FOLD) == groups     # one launch a group
     assert op.trace_attrs["mode"] == "streamed"
     assert op.trace_attrs["lanes"] == O.K.bucket(batches[-1].num_rows)
 
@@ -394,7 +409,7 @@ FUSED_STREAMS = ["global", "grouped", "grouped_device_batches",
 @pytest.mark.parametrize("step", ["SINGLE", "PARTIAL"])
 @pytest.mark.parametrize("name", FUSED_STREAMS)
 def test_fused_equals_unfused_equals_buffered(name, step, monkeypatch):
-    make, keys, seals = STREAMS[name]
+    make, keys, seals, groups = STREAMS[name]
     batches = make()
     aggs = AGGS if step == "SINGLE" else [
         AggCall("sum", 2, BIGINT), AggCall("count", -1, BIGINT),
@@ -405,10 +420,10 @@ def test_fused_equals_unfused_equals_buffered(name, step, monkeypatch):
     launches = [e["name"] for e in profiler.events_since(t0)
                 if e["kind"] == profiler.LAUNCH]
     es = agg.encoding_stats
-    assert launches.count(FUSED) == len(batches)
+    assert launches.count(FUSED) == groups    # one launch a group
     assert FILTER not in launches and FOLD not in launches
-    assert (es.agg_fused_feed, es.agg_streamed_batches,
-            es.agg_state_seals) == (1, len(batches), seals)
+    assert (es.agg_fused_feed, es.agg_streamed_batches, es.agg_fold_launches,
+            es.agg_state_seals) == (1, len(batches), groups, seals)
     assert len(fused) == (1 if step == "SINGLE" else 1 + seals)
     assert es.agg_masked == 1 + seals
     attrs, = _finish_attrs(t0)
@@ -550,12 +565,16 @@ def _pjit_names(trace_dir):
     return names
 
 
-def test_one_named_launch_a_batch_and_nothing_else(tmp_path):
-    """The fused hot loop on device-resident batches: per batch ONE named
-    program (the recorder's ``launch`` events and the trace's ``PjitFunction``
-    rows agree), no eager ``jnp`` dispatch, no host sync; one page out."""
+def test_one_named_launch_a_group_and_nothing_else(tmp_path):
+    """The fused hot loop on device-resident batches: per GROUP of batches
+    ONE named program (the recorder's ``launch`` events and the trace's
+    ``PjitFunction`` rows agree) and nothing else in between -- no eager
+    ``jnp`` dispatch, no host sync, nothing at all for a batch that is only
+    held; one page out."""
+    n = 2 * GROUP + 3
+
     def batches():
-        return [_batch(s, device=True) for s in range(8)]
+        return [_batch(s, n=512, device=True) for s in range(n)]
 
     _pipeline(batches(), _filter_project(), _agg())       # warm: compiles
     hot = batches()
@@ -572,33 +591,45 @@ def test_one_named_launch_a_batch_and_nothing_else(tmp_path):
     opts.enable_hlo_proto = False
     before = SG.snapshot()
     t0 = profiler.now()
+    launched = []
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
         with SG.forbidden(), SG.hot_region():
             for b in hot[1:]:
                 move(b)
+                launched.append(agg.trace_attrs["batches"])
+            fp.finish_input()
+            agg.finish_input()                # the remainder: one more
     finally:
         jax.profiler.stop_trace()
+    # batches 1 .. 2K+3: a launch when the K-th and the 2K-th arrive, the
+    # three left at finish
+    assert launched == ([0] * (GROUP - 2) + [GROUP] + [0] * (GROUP - 1)
+                        + [GROUP] + [0] * 3)
+    assert agg.trace_attrs["batches"] == 3
     events = profiler.events_since(t0)
-    assert [e["name"] for e in events if e["kind"] == profiler.LAUNCH] \
-        == [FUSED] * 7
+    named = [e["name"] for e in events if e["kind"] == profiler.LAUNCH]
+    assert named[:3] == [FUSED] * 3 and FUSED not in named[3:]
     assert not [e for e in events if e["kind"] == profiler.HOST_SYNC]
     delta = SG.take_delta(before)
     assert (delta.host_syncs, delta.hot_loop_syncs) == (0, 0)
-    assert _pjit_names(tmp_path) == [FUSED] * 7   # nothing eager in between
-    fp.finish_input()
-    agg.finish_input()
+    # nothing eager in between, and after the last fold only finalization
+    pjit = _pjit_names(tmp_path)
+    assert pjit[:3] == [FUSED] * 3 and pjit == named
     out = agg.get_output()
     assert out is not None and agg.get_output() is None
     es = agg.encoding_stats
-    assert (es.agg_streamed_batches, es.agg_fused_feed,
-            es.agg_state_seals, es.agg_masked) == (8, 1, 0, 1)
+    assert (es.agg_streamed_batches, es.agg_fold_launches, es.agg_fused_feed,
+            es.agg_state_seals, es.agg_masked) == (n, 3, 1, 0, 1)
     _same(_rows([out]), _rows(_pipeline(batches(), _filter_project(),
                                         _agg(), fuse=False)))
 
 
-def test_the_state_is_what_memory_accounting_sees():
-    from trino_tpu.exec.revoking import TaskMemoryContext
+def test_memory_accounting_sees_the_state_and_the_held_batches():
+    """The state for as long as the stream lives; a held batch's device
+    bytes from the call that holds it to the launch that folds it (host
+    batches hold nothing on the device)."""
+    from trino_tpu.exec.revoking import TaskMemoryContext, batch_device_nbytes
 
     mem = TaskMemoryContext(1 << 30, 0)
     op = _agg()
@@ -609,10 +640,200 @@ def test_the_state_is_what_memory_accounting_sees():
     state_bytes = 6 * sum(np.dtype(d).itemsize for _, d in layout)
     assert sum(int(np.asarray(c).nbytes) for c in op._stream.state) \
         == state_bytes
-    assert mem.reserved_bytes() == state_bytes
+    assert mem.reserved_bytes() == state_bytes      # host batches
+    held = [_batch(s, device=True) for s in range(3, 1 + GROUP)]
+    one = batch_device_nbytes(held[0])
+    assert one > ROWS * 8
+    for i, b in enumerate(held[:-1], start=1):
+        op.add_input(b)               # the same signature: the group grows
+        assert mem.reserved_bytes() == state_bytes + i * one
+    assert op.encoding_stats.agg_fold_launches == 0
+    op.add_input(held[-1])            # the group is full: launched, let go
+    assert op.encoding_stats.agg_fold_launches == 1
+    assert not op._stream.pending and mem.reserved_bytes() == state_bytes
+    op.add_input(_batch(20, device=True))
+    assert mem.reserved_bytes() == state_bytes + one
     assert op.revoke_memory() == 0            # nothing buffered to revoke
     op.finish_input()
     assert op.get_output().num_rows == 6 and mem.reserved_bytes() == 0
+    assert op.encoding_stats.agg_fold_launches == 2
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["unfused", "fused"])
+def test_a_pinned_tables_batches_reserve_nothing_while_held(fused):
+    """What a scan reads from a table pinned to the device is the
+    connector's own storage (``ColumnBatch.resident``, kept through the page
+    source's column selection): the group that holds it keeps nothing alive
+    that was not, so only the state is reserved -- where the same arrays
+    from anywhere else (a staged batch) are reserved for as long as they
+    are held."""
+    from trino_tpu.connectors.memory import MemoryConnector
+    from trino_tpu.exec.revoking import TaskMemoryContext, batch_device_nbytes
+    from trino_tpu.spi.connector import ColumnSchema, TableSchema
+
+    mem_conn = MemoryConnector()
+    mem_conn.create_table(TableSchema("t", [
+        ColumnSchema(n, t) for n, t in zip(
+            NAMES, [VARCHAR, VARCHAR, BIGINT, DOUBLE])]))
+    mem_conn.finish_insert("t", [[_batch(s, live=None) for s in range(3)]])
+    mem_conn.pin_to_device("t")
+    src = mem_conn.create_page_source(mem_conn.get_splits("t", 1, 1)[0], NAMES)
+    pinned = [src.get_next_batch() for _ in range(3)]
+    assert all(b.resident and batch_device_nbytes(b) > ROWS * 8
+               for b in pinned)
+
+    def reserved_while_held(batches):
+        mem = TaskMemoryContext(1 << 30, 0)
+        fp, agg = _filter_project(), _agg()
+        agg.attach_memory(mem)
+        if fused:
+            plan_aggregation_feed([fp, agg])
+        seen = []
+        for b in batches:
+            fp.add_input(b)
+            agg.add_input(fp.get_output())
+            seen.append(mem.reserved_bytes() - agg._stream.state_bytes)
+        assert len(agg._stream.pending) == 3
+        agg.finish_input()
+        assert mem.reserved_bytes() == 0
+        return seen, _rows([agg.get_output()])
+
+    held, rows = reserved_while_held(pinned)
+    # fused, the pinned batch itself is held; unfused, the filter/project's
+    # fresh output is, which nothing else keeps alive
+    assert (held == [0, 0, 0]) == fused
+    staged = [ColumnBatch(b.names, b.columns, b.live) for b in pinned]
+    held_staged, rows_staged = reserved_while_held(staged)
+    assert held_staged[0] > ROWS * 8 and held_staged[2] == 3 * held_staged[0]
+    assert rows == rows_staged
+
+
+def test_close_with_a_group_pending_leaves_nothing_held():
+    """Downstream is done (a LIMIT): the held batches are let go unfolded,
+    their reservation with them."""
+    from trino_tpu.exec.revoking import TaskMemoryContext
+
+    mem = TaskMemoryContext(1 << 30, 0)
+    op = _agg()
+    op.attach_memory(mem)
+    t0 = profiler.now()
+    for s in range(3):
+        op.add_input(_batch(s, device=True))
+    st = op._stream
+    assert len(st.pending) == 3 and st.held_bytes > 0
+    state_bytes = mem.reserved_bytes() - st.held_bytes
+    assert state_bytes == st.state_bytes > 0
+    op.close()
+    assert op.is_finished() and not st.pending and st.held_bytes == 0
+    assert mem.reserved_bytes() == state_bytes
+    assert FOLD not in [e["name"] for e in profiler.events_since(t0)
+                        if e["kind"] == profiler.LAUNCH]
+
+
+# ------------------------------------------ groups of every length (PR 37)
+
+PRICE = DecimalType(12, 2)
+PRICED = NAMES + ["p"]
+LENGTHS = [1, GROUP - 1, GROUP, GROUP + 1, 2 * GROUP + 3]
+# integer, decimal and double states, every merge (add, min, max)
+PRICED_AGGS = [AggCall("sum", 2, BIGINT), AggCall("count", -1, BIGINT),
+               AggCall("min", 2, BIGINT), AggCall("max", 2, BIGINT),
+               AggCall("sum", 4, DecimalType(18, 2)),
+               AggCall("min", 4, PRICE), AggCall("sum", 3, DOUBLE)]
+
+
+def _priced(seed, n=512):
+    b = _batch(seed, n)
+    cents = np.random.default_rng(seed + 1000).integers(0, 10 ** 9, n)
+    return ColumnBatch(
+        PRICED, b.columns + [Column(PRICE, cents.astype(np.int64))], b.live)
+
+
+def _priced_filter_project():
+    fp = _filter_project()
+    return FilterProjectOperator(
+        fp.predicate, fp.projections + [_ref(4, PRICE)], PRICED,
+        fp.output_types + [PRICE])
+
+
+def _priced_agg(keys, step):
+    return HashAggregationOperator(
+        list(keys), PRICED_AGGS,
+        [PRICED[k] for k in keys] + [f"a{i}" for i in range(len(PRICED_AGGS))],
+        [VARCHAR] * len(keys) + [a.type for a in PRICED_AGGS], step)
+
+
+def _exactly(got, want):
+    """Integer and decimal columns bit for bit; DOUBLE sums to rounding."""
+    assert [[v for v in r if not isinstance(v, float)] for r in got] \
+        == [[v for v in r if not isinstance(v, float)] for r in want]
+    _same(got, want)
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["unfused", "fused"])
+@pytest.mark.parametrize("step", ["SINGLE", "PARTIAL"])
+@pytest.mark.parametrize("keys", [(), (0, 1)], ids=["global", "grouped"])
+@pytest.mark.parametrize("n", LENGTHS)
+def test_grouped_equals_single_fold_equals_buffered(n, keys, step, fused,
+                                                    monkeypatch):
+    """A stream of 1, K-1, K, K+1 and 2K+3 batches: ceil(n / K) launches,
+    and the page that leaves is the one a launch a batch gives and the one
+    the buffered operator gives."""
+    batches = [_priced(s) for s in range(n)]
+
+    def run():
+        agg = _priced_agg(keys, step)
+        agg.FLUSH_ROWS = 1 << 30
+        t0 = profiler.now()
+        pages = _pipeline(batches, _priced_filter_project(), agg, fuse=fused)
+        names = [e["name"] for e in profiler.events_since(t0)
+                 if e["kind"] == profiler.LAUNCH]
+        return agg, _rows(pages), names.count(FUSED if fused else FOLD)
+
+    agg, grouped, launches = run()
+    es = agg.encoding_stats
+    assert launches == es.agg_fold_launches == -(-n // GROUP)
+    assert (es.agg_streamed_batches, es.agg_fused_feed) == (n, int(fused))
+    check_error_scalars(agg.pending_errors)
+
+    monkeypatch.setattr(O, "_FOLD_GROUP", 1)
+    agg, single, launches = run()
+    assert launches == agg.encoding_stats.agg_fold_launches == n
+    _exactly(grouped, single)
+
+    _buffered(monkeypatch)
+    agg, buffered, launches = run()
+    assert agg._streamed is False and launches == 0
+    _exactly(grouped, buffered)
+
+
+def test_a_failing_row_mid_group_raises_before_the_sink_finishes():
+    """A division by zero in the third batch of a group of five: nothing is
+    launched until the source ends, and the error still surfaces at the
+    pre-finish barrier, before the sink's stream is marked finished."""
+    assert GROUP >= 5
+    batches = []
+    for s in range(5):
+        b = _batch(s, n=512)
+        v = np.asarray(b.columns[2].data) | 1             # odd everywhere
+        if s == 2:
+            v = v.copy()
+            v[np.flatnonzero(np.asarray(b.live))[7]] = 4  # ... but here
+        batches.append(ColumnBatch(
+            NAMES, b.columns[:2] + [Column(BIGINT, v)] + b.columns[3:],
+            b.live))
+    agg = HashAggregationOperator(
+        [0], [AggCall("sum", 1, BIGINT)], ["flag", "s"], [VARCHAR, BIGINT])
+    sink = OutputCollector()
+    ops = [_Source(batches), _division(False), agg, sink]
+    plan_aggregation_feed(ops)
+    t0 = profiler.now()
+    with pytest.raises(QueryError, match="(?i)division"):
+        Driver(ops).run()
+    assert not sink.input_done
+    assert agg.encoding_stats.agg_streamed_batches == 5
+    assert [e["name"] for e in profiler.events_since(t0)
+            if e["kind"] == profiler.LAUNCH].count(FUSED) == 1
 
 
 # ----------------------------------------------------- through the engine
@@ -720,13 +941,18 @@ def test_distributed_partial_streams_final_buffers_and_explain_says_so(
     folded = [e for e in events if e["kind"] == profiler.OPERATOR
               and e["name"] == "HashAggregationOperator"
               and e.get("args", {}).get("fused")]
-    assert launches.count(FUSED) == len(folded) >= 2
+    # lineitem at SF0.01 is one batch a task: a group of one each, launched
+    # at finish (the ``add_input`` event reports 0, ``.finish`` the one)
+    assert launches.count(FUSED) == len(folded) == 2
+    assert [e["args"]["batches"] for e in folded] == [0, 0]
+    assert sorted(a["batches"] for a in _finish_attrs(t0)
+                  if a.get("mode") == "streamed") == [1, 1]
     # one page a PARTIAL task, whatever the number of batches
     assert launches.count("trino_kernels_small_agg_state_out") == 2
     text = "\n".join(r[0] for r in runner.execute(
         "explain analyze " + Q1).rows())
-    per_task = re.findall(r"(\d+) batches streamed \((\d+) aggregations "
-                          r"fused with their filter/project, 0 state seals\)",
-                          text)
-    assert sorted(per_task)[-2:] == [("1", "1"), ("1", "1")]
-    assert sorted(per_task)[:-2] == [("0", "0")] * (len(per_task) - 2)
+    per_task = re.findall(r"(\d+) batches streamed in (\d+) launches "
+                          r"\((\d+) aggregations fused with their "
+                          r"filter/project, 0 state seals\)", text)
+    assert sorted(per_task)[-2:] == [("1", "1", "1")] * 2
+    assert sorted(per_task)[:-2] == [("0", "0", "0")] * (len(per_task) - 2)

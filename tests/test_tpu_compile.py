@@ -209,9 +209,12 @@ def test_static_grouped_agg_as_the_fused_stage_calls_it(
 def test_masked_aggregation_fold_with_a_donated_state(shape, rows):
     """kernels.small_agg_fold as a streaming Q1 PARTIAL task calls it: two
     dictionary keys (3 x 2 codes), the batch's live mask, DECIMAL sums as
-    int64, an avg's scale-free f64 sum and count, count(*); the state is
-    donated, so the [6]-lane state columns (stacked by dtype) alias in and
-    out."""
+    int64, an avg's scale-free f64 sum and count, count(*), for a group of
+    _FOLD_GROUP batches a launch (every slot after the first under
+    ``lax.cond``); the state is donated, so the [6]-lane state columns
+    (stacked by dtype) alias in and out."""
+    from trino_tpu.exec.operators import _FOLD_GROUP
+
     spec = (("sum", 0, -1, "<i8", None), ("sum", 1, -1, "<i8", None),
             ("sum", 0, -1, "<f8", ("scale", 2)), ("count", 0, -1, "<i8", None),
             ("min", 2, -1, "<i4", None), ("count_star", -1, -1, "<i8", None))
@@ -219,15 +222,92 @@ def test_masked_aggregation_fold_with_a_donated_state(shape, rows):
     state = tuple(shape(dims, np.dtype(d))
                   for dims, d in K.small_agg_state_shapes(layout, 6))
     assert len(state) == 3                    # int64, float64, int32 stacks
+    slot = (shape(rows, jnp.int32), shape(rows, jnp.int32),
+            shape(rows, jnp.bool_), shape(rows, jnp.int64),
+            shape(rows, jnp.int64), shape(rows, jnp.int32))
     compiled = K._small_agg_fold_fn(
-        spec, 2, (False, False), True, (3, 2), True).lower(
-        state, shape(rows, jnp.int32), shape(rows, jnp.int32),
-        shape(rows, jnp.bool_), shape(rows, jnp.int64),
-        shape(rows, jnp.int64), shape(rows, jnp.int32)).compile()
+        spec, 2, (False, False), True, (3, 2), _FOLD_GROUP, True).lower(
+        state, shape((), jnp.int32), (slot,) * _FOLD_GROUP).compile()
     assert compiled.memory_analysis().alias_size_in_bytes > 0
+    assert compiled.as_text().count("conditional(") == _FOLD_GROUP - 1
     K._small_agg_zero_fn(layout, 6, True).lower().compile()
     K._small_agg_state_out_fn(spec, (3, 2),
                               (False, False)).lower(*state).compile()
+
+
+TPCH_Q1 = """
+select l_returnflag, l_linestatus, sum(l_quantity) as sum_qty,
+  sum(l_extendedprice) as sum_base_price,
+  sum(l_extendedprice * (1 - l_discount)) as sum_disc_price,
+  sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)) as sum_charge,
+  avg(l_quantity) as avg_qty, avg(l_extendedprice) as avg_price,
+  avg(l_discount) as avg_disc, count(*) as count_order
+from lineitem where l_shipdate <= date '1998-12-01' - interval '90' day
+group by l_returnflag, l_linestatus order by l_returnflag, l_linestatus"""
+TPCH_Q6 = """
+select sum(l_extendedprice * l_discount) as revenue from lineitem
+where l_shipdate >= date '1994-01-01'
+  and l_shipdate < date '1994-01-01' + interval '1' year
+  and l_discount between 0.06 - 0.01 and 0.06 + 0.01 and l_quantity < 24"""
+
+
+def _fused_fold_of(sql, monkeypatch):
+    """(the aggregation operator, a batch its feed handed through) of a
+    PARTIAL task of ``sql``, run once at SF0.01 as one chip runs it (two
+    tasks, no fused stage, no collectives)."""
+    from trino_tpu.connectors.catalog import default_catalog
+    from trino_tpu.exec.operators import HashAggregationOperator
+    from trino_tpu.execution.distributed_runner import DistributedQueryRunner
+    from trino_tpu.runner import Session
+
+    monkeypatch.setenv("TRINO_TPU_FUSED_STAGE", "0")
+    monkeypatch.setenv("TRINO_TPU_RESULT_CACHE", "0")
+    seen = []
+    absorbs = HashAggregationOperator.absorbs
+
+    def spy(self, batch):
+        took = absorbs(self, batch)
+        if took and batch.num_rows:
+            seen.append((self, batch))
+        return took
+
+    monkeypatch.setattr(HashAggregationOperator, "absorbs", spy)
+    DistributedQueryRunner(
+        default_catalog(scale_factor=0.01), worker_count=2,
+        session=Session(node_count=2, use_collectives=False)).execute(sql)
+    assert seen and seen[0][0].step == "PARTIAL"
+    return seen[0]
+
+
+@pytest.mark.parametrize("rows", BUCKETS)
+@pytest.mark.parametrize("sql", [TPCH_Q6, TPCH_Q1], ids=["q6", "q1"])
+def test_grouped_filter_project_agg(shape, monkeypatch, sql, rows):
+    """operators.filter_project_agg as an SF10 scan task launches it since
+    PR 37: Q6's and Q1's own bodies (predicate, projections, the fold)
+    traced for a group of _FOLD_GROUP pinned batches -- live mask present,
+    only the channels the body reads -- into the one donated state."""
+    from trino_tpu.exec import operators as O
+    from trino_tpu.spi.batch import pad_to_bucket
+
+    agg, batch = _fused_fold_of(sql, monkeypatch)
+    # the query ran as the CPU runs it; the program is built as the chip
+    # builds it (kernels.donate_ok asks the backend: the state is donated)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prog, sig, cols, _ = agg.feed.program_inputs(pad_to_bucket(batch))
+    cols = [(d if d is None else shape(rows, d.dtype),
+             v if v is None else shape(rows, v.dtype)) for d, v in cols]
+    live = shape(rows, jnp.bool_)
+    view, has_error = prog.view((rows, sig[1], False), cols, live)
+    ops, _ = O._masked_operands(agg.group_keys, agg.aggs, agg.step, view)
+    state = tuple(shape(dims, np.dtype(d)) for dims, d in ops.state_shapes)
+    if has_error:
+        state += (shape((), jnp.int32),)
+    fold = O._filter_project_agg_program(
+        prog, tuple(agg.group_keys), tuple(agg.aggs), agg.step)
+    compiled = fold.lower(state, shape((), jnp.int32),
+                          ((cols, live),) * O._FOLD_GROUP).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes > 0
+    assert compiled.as_text().count("conditional(") == O._FOLD_GROUP - 1
 
 
 @pytest.mark.parametrize("rows", BUCKETS)

@@ -298,7 +298,8 @@ class MemoryConnector(Connector):
                     if lv is None:
                         lv = jnp.ones(b.num_rows, jnp.bool_)
                     pinned.append(ColumnBatch(b.names, list(b.columns),
-                                              jax.device_put(jnp.asarray(lv))))
+                                              jax.device_put(jnp.asarray(lv)),
+                                              resident=True))
                     total_rows += b.live_count
                     continue
                 b = pad_to_bucket(b.compact())
@@ -317,7 +318,8 @@ class MemoryConnector(Connector):
                     for c in b.columns
                 ]
                 pinned.append(ColumnBatch(
-                    b.names, cols, jax.device_put(jnp.asarray(live))))
+                    b.names, cols, jax.device_put(jnp.asarray(live)),
+                    resident=True))
             self._data[table] = pinned
             self._pinned_rows[table] = total_rows
 

@@ -24,6 +24,7 @@ spec / Trino GroupByHash behavior); equi-join keys never match on NULL.
 
 from __future__ import annotations
 
+import functools
 import os
 from ..caching.executable_cache import jit_memo, program
 from typing import NamedTuple, Optional, Sequence
@@ -665,8 +666,9 @@ def small_grouped_aggregate(key_cols, live, aggs: Sequence[tuple]):
 def small_agg_fold_body(spec: tuple, num_keys: int, has_valid: tuple,
                         has_live: bool, sizes: tuple):
     """``(state, *flat) -> state``: reduce one batch and merge it into the
-    running state -- the traceable body of kernels.small_agg_fold, which an
-    operator's program may trace behind its own work instead."""
+    running state -- what kernels.small_agg_fold traces for each slot of
+    its group, and what an operator's program may trace behind its own
+    work instead."""
     layout = small_agg_state_layout(spec)
 
     def fold(state, *flat):
@@ -677,6 +679,37 @@ def small_agg_fold_body(spec: tuple, num_keys: int, has_valid: tuple,
     return fold
 
 
+def fold_group_body(fold_one, slots: int):
+    """``(state, n, group) -> state``: ``fold_one(state, *group[i])`` for
+    the first ``n`` of ``group``'s ``slots`` entries, one after the other
+    in the order given, into the one state -- a launch for a group of
+    batches where ``fold_one`` alone would take a launch a batch.  Unrolled
+    in the trace (the batches are separate device buffers; stacking them to
+    loop over would copy every row); a slot from the second on runs under
+    ``lax.cond(i < n, ...)``, so ONE program serves every group size up to
+    ``slots``: the caller fills the absent slots with slot 0's arrays
+    again, which are then never read."""
+
+    def run(state, n, group):
+        state = fold_one(state, *group[0])
+        for i in range(1, slots):
+            state = jax.lax.cond(
+                i < n, lambda s, slot=group[i]: fold_one(s, *slot),
+                lambda s: s, state)
+        return state
+
+    return run
+
+
+@functools.lru_cache(maxsize=64)
+def slot_count(n: int):
+    """``n`` as the int32 device scalar fold_group_body's programs take:
+    made once a process, so a launch on the default device uploads nothing
+    (an uncommitted array: beside operands committed to another chip of a
+    mesh each launch copies these four bytes across)."""
+    return jnp.int32(n)
+
+
 def donate_ok() -> bool:
     """Buffer donation saves HBM (and a copy) on real accelerators; the CPU
     backend warns about unusable donations, so only donate off-CPU."""
@@ -685,20 +718,23 @@ def donate_ok() -> bool:
 
 @jit_memo("kernels._small_agg_fold_fn")
 def _small_agg_fold_fn(spec: tuple, num_keys: int, has_valid: tuple,
-                       has_live: bool, sizes: tuple, donate: bool):
-    """The per-batch program of a streaming masked aggregation that has no
-    filter/project to share a launch with: the state is donated, the batch
-    is read once, nothing is buffered, sorted or synced."""
+                       has_live: bool, sizes: tuple, slots: int,
+                       donate: bool):
+    """The program of a streaming masked aggregation that has no
+    filter/project to share a launch with: ``(state, n, group of slots
+    flat operand tuples) -> state``; the state is donated, each batch is
+    read once, nothing is buffered, sorted or synced."""
     return program("kernels.small_agg_fold",
-                   small_agg_fold_body(spec, num_keys, has_valid, has_live,
-                                       sizes),
+                   fold_group_body(
+                       small_agg_fold_body(spec, num_keys, has_valid,
+                                           has_live, sizes), slots),
                    donate_argnums=(0,) if donate else ())
 
 
-def small_agg_fold(state: tuple, ops: MaskedOperands) -> tuple:
-    """Reduce ``ops`` and merge the result into ``state`` (donated): ONE
-    program, zero host syncs.  Returns the new state."""
-    return _small_agg_fold_fn(*ops.static, donate_ok())(state, *ops.flat)
+def small_agg_fold_program(ops: MaskedOperands, slots: int):
+    """kernels.small_agg_fold for ``ops``' layout and ``slots`` batches a
+    launch (fold_group_body's contract; a slot is ``tuple(ops.flat)``)."""
+    return _small_agg_fold_fn(*ops.static, slots, donate_ok())
 
 
 @jit_memo("kernels._small_agg_zero_fn")

@@ -47,6 +47,7 @@ from .prefetch import (
     PrefetchingPageSource,
     encode_scan_batch,
 )
+from .revoking import device_nbytes
 from .stats import EncodingStats, ScanIngestStats
 
 __all__ = [
@@ -822,12 +823,12 @@ def plan_aggregation_feed(pipeline: Sequence[Operator]) -> None:
     """A FilterProjectOperator directly in front of an aggregation that may
     stream (not FINAL, no DISTINCT) is made known to it as its ``feed``: on
     the first batch the aggregation decides whether it streams, and if it
-    does its one program per batch also evaluates the feed's predicate and
-    projections (HashAggregationOperator.absorbs); if not, both run as they
-    did.  Called once per pipeline at local-planning time, after
-    intra-task parallelism has put its exchanges in: with one between the
-    two operators nothing is adjacent and the aggregation, if it streams,
-    folds with a launch of its own."""
+    does its one program per group of batches also evaluates the feed's
+    predicate and projections (HashAggregationOperator.absorbs); if not,
+    both run as they did.  Called once per pipeline at local-planning time,
+    after intra-task parallelism has put its exchanges in: with one between
+    the two operators nothing is adjacent and the aggregation, if it
+    streams, folds with launches of its own."""
     for fp, agg in zip(pipeline, pipeline[1:]):
         if (isinstance(fp, FilterProjectOperator)
                 and isinstance(agg, HashAggregationOperator)
@@ -867,6 +868,16 @@ _COUNT_SYNC_S = 1.0e-3                # the blocking exec.compact-count fetch
 _COMPACT_S_PER_LANE = 3.6e-9          # kernels.compact: stable sort + gathers
 _MASKED_S_PER_LANE_REDUCTION = 150e-12       # small_agg: one more state column
 _MASKED_S_PER_LANE_GROUP_REDUCTION = 2.7e-12  # ... in one more group
+# batches the streamed aggregation folds in one launch (kernels.
+# fold_group_body), as read on a v5e (PERF.md section 6, PR 37).  Alone
+# (tools/fold_group_probe.py) one launch of Q6's / Q1's body costs the host
+# 119 / 228 us, one that folds 4 batches 142 / 279 us, 8 batches 217 / 339
+# us (0.23 / 0.19 of eight singles), 16 batches 273 / 494 us.  In the
+# engine, one process and one load, Q6 over SF10 takes 44.6 ms a query at
+# 8 and 47.3 ms at 4 (+6.2 %), three streams and Q1 are level; the cold
+# compile of Q6's program is 82 s at 8, 37 s at 4, 11 s at 1.  16 would hold
+# twice the batches for 10 us a batch
+_FOLD_GROUP = 8
 
 
 def _compaction_candidate(batch: ColumnBatch) -> bool:
@@ -1201,16 +1212,21 @@ class _MaskedStream:
     generation of input dictionaries: the device-resident ``state``
     (kernels.small_agg_state_layout's columns stacked by dtype, then the
     feed's error scalar when its body can raise), and the statics that
-    finalization and the
-    next batch's check read.  ``feed`` is the (program, input structure) of
-    the filter/project whose body ``program`` traces in front of the fold;
-    both None when the aggregation folds with a launch of its own."""
+    finalization and the next batch's check read.  ``program`` folds a
+    group of up to _FOLD_GROUP batches into the state in one launch;
+    ``key`` is what the batches of one group share -- the program and the
+    structure of its inputs: with ``fused`` the (program, input structure)
+    of the filter/project whose body ``program`` traces in front of each
+    fold.  ``pending`` holds the group's slots until it is launched,
+    ``held_bytes`` what they occupy on the device meanwhile, beside the
+    state's own ``state_bytes``."""
 
     __slots__ = ("ops", "layout", "shapes", "columns", "slots", "compaction",
-                 "lanes", "state", "feed", "program")
+                 "lanes", "state", "key", "fused", "program", "pending",
+                 "held_bytes", "state_bytes")
 
-    def __init__(self, ops, columns, slots, compaction, state,
-                 feed=None, program=None):
+    def __init__(self, ops, columns, slots, compaction, state, key, fused,
+                 program):
         self.ops = ops._replace(flat=[])  # the layout, not the batch
         self.layout = ops.layout
         self.shapes = ops.state_shapes
@@ -1219,8 +1235,13 @@ class _MaskedStream:
         self.compaction = compaction
         self.lanes = 0
         self.state = state
-        self.feed = feed
+        self.key = key
+        self.fused = fused
         self.program = program
+        self.pending: list = []
+        self.held_bytes = 0
+        self.state_bytes = sum(rows * lanes * np.dtype(d).itemsize
+                               for (rows, lanes), d in self.shapes)
 
     @property
     def has_error(self) -> bool:
@@ -1237,41 +1258,54 @@ class _MaskedStream:
                 and all(self.columns[i].dictionary is columns[i].dictionary
                         for i in channels))
 
-    def attrs(self) -> dict:
+    def attrs(self, batches: int) -> dict:
+        """``batches``: how many the call that reports this launched (a
+        group; 0 where its batch was only held)."""
         return {"path": "masked", "compaction": self.compaction,
                 "lanes": self.lanes, "mode": "streamed",
-                "fused": self.feed is not None}
+                "fused": self.fused, "batches": batches}
+
+
+def _filter_project_fold_body(prog: _FilterProjectProgram, group_keys: tuple,
+                              aggs: tuple, step: str):
+    """``(state, cols, live) -> state``: one batch through the
+    filter/project's own body (predicate, projections, error lanes), the
+    aggregation's specs built over its outputs the way the unfused operator
+    builds them over a batch, and the fold into the state; the body's error
+    scalar rides in the state as a running max."""
+
+    def fold_one(state, cols, live):
+        outs, live, err_code = prog.body(cols, live)
+        ops, _ = _masked_operands(group_keys, aggs, step,
+                                  prog.output_batch(outs, live))
+        n = len(ops.state_shapes)
+        merged = K.small_agg_fold_body(*ops.static)(state[:n], *ops.flat)
+        if err_code is not None:
+            merged += (jnp.maximum(state[n], err_code),)
+        return merged
+
+    return fold_one
 
 
 def _filter_project_agg_program(prog: _FilterProjectProgram,
                                 group_keys: tuple, aggs: tuple, step: str):
-    """ONE program per batch for filter/project + masked aggregation:
-    ``(state, cols, live) -> state`` traces the filter/project's own body
-    (predicate, projections, error lanes), builds the aggregation's specs
-    over its outputs the way the unfused operator does over a batch, and
-    folds them into the donated state; the body's error scalar rides in
-    the state as a running max.  Kept with the filter/project's compiled
-    program, so it lives and dies with the dictionaries it was built for."""
+    """ONE program per group of up to _FOLD_GROUP batches for
+    filter/project + masked aggregation: ``(state, n, ((cols, live), ...))
+    -> state`` (kernels.fold_group_body) runs _filter_project_fold_body for
+    each slot in arrival order into the donated state.  Kept with the
+    filter/project's compiled program, so it lives and dies with the
+    dictionaries it was built for."""
     donate = K.donate_ok()
-    key = (group_keys, aggs, step, donate)
+    key = (group_keys, aggs, step, _FOLD_GROUP, donate)
     with FilterProjectOperator._PROGRAM_CACHE_LOCK:
         hit = prog.consumers.get(key)
-        if hit is not None:
-            return hit
-
-        def run(state, cols, live):
-            outs, live, err_code = prog.body(cols, live)
-            ops, _ = _masked_operands(group_keys, aggs, step,
-                                      prog.output_batch(outs, live))
-            n = len(ops.state_shapes)
-            merged = K.small_agg_fold_body(*ops.static)(state[:n], *ops.flat)
-            if err_code is not None:
-                merged += (jnp.maximum(state[n], err_code),)
-            return merged
-
-        hit = prog.consumers[key] = program(
-            "operators.filter_project_agg", run,
-            donate_argnums=(0,) if donate else ())
+        if hit is None:
+            hit = prog.consumers[key] = program(
+                "operators.filter_project_agg",
+                K.fold_group_body(
+                    _filter_project_fold_body(prog, group_keys, aggs, step),
+                    _FOLD_GROUP),
+                donate_argnums=(0,) if donate else ())
     return hit
 
 
@@ -1292,7 +1326,11 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
       With a FilterProjectOperator directly in front (``feed``, set by
       plan_aggregation_feed) that one program also evaluates the predicate
       and the projections (operators.filter_project_agg), and the filter
-      operator hands its input through untouched.
+      operator hands its input through untouched.  A launch folds a GROUP
+      of up to _FOLD_GROUP batches: a batch's operands are held (``_hold``)
+      until the group is full or something ends it -- a batch of another
+      structure or under other dictionaries, finish_input, close -- and are
+      folded in arrival order.
     - **buffered** -- everything else (the paths that sort, DISTINCT, long
       decimals, RLE folds, every FINAL step) accumulates ``_batches`` and
       reduces them at finish, as before.  PARTIAL steps with group keys
@@ -1330,6 +1368,7 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
         # body the per-batch program traces when this aggregation streams
         self.feed: Optional[FilterProjectOperator] = None
         self._fused: Optional[bool] = None
+        self._launched = 0     # batches launched since the last report
         self.pending_errors: list = []
         # input channels whose dictionaries finalization decodes through
         self._channels = sorted(set(self.group_keys).union(
@@ -1522,34 +1561,74 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
                 self.encoding_stats.agg_fused_feed += 1
         return bool(self._fused)
 
-    def _open_stream(self, inp: ColumnBatch, ops, slots, has_error=False,
-                     feed=None, program=None) -> _MaskedStream:
+    def _open_stream(self, inp: ColumnBatch, ops, slots, key, program,
+                     has_error=False, fused=False) -> _MaskedStream:
         """The stream the batch ``inp`` (arrays or shapes) folds into: the
-        running one if its state can take the batch, else a fresh one --
-        after sealing the old, whose dictionaries this batch left behind."""
+        running one's state if it can take the batch, else a fresh one --
+        after sealing the old, whose dictionaries this batch left behind.
+        Either way the batches held for the old stream's program are folded
+        first, so the state sees every batch in arrival order."""
+        self._launch_group()
         old = self._stream
         columns = [_ColumnMeta(c.type, c.dictionary) for c in inp.columns]
-        if old is not None and old.continues(ops, columns, self._channels,
-                                             has_error):
-            state = old.state
-        else:
-            if old is not None:
-                self._seal()
-            state = K.small_agg_zero_state(ops, has_error)
-            if self._mem is not None:
-                self._mem.update(self, sum(
-                    rows * lanes * np.dtype(d).itemsize
-                    for (rows, lanes), d in ops.state_shapes))
-        self._stream = _MaskedStream(
+        fresh = old is None or not old.continues(ops, columns,
+                                                 self._channels, has_error)
+        if fresh and old is not None:
+            self._seal()
+        st = self._stream = _MaskedStream(
             ops, columns, slots,
-            "skipped" if _compaction_candidate(inp) else "none", state,
-            feed, program)
-        return self._stream
+            "skipped" if _compaction_candidate(inp) else "none",
+            K.small_agg_zero_state(ops, has_error) if fresh else old.state,
+            key, fused, program)
+        if fresh and self._mem is not None:
+            self._mem.update(self, st.state_bytes)
+        return st
+
+    def _hold(self, st: _MaskedStream, slot: tuple, lanes: int,
+              resident: bool) -> None:
+        """One batch's operands into the stream's pending group; the group
+        is launched when it is full (else by whatever ends it:
+        _open_stream, _close_stream).  What the group newly keeps alive on
+        the device is reserved while it is held: a staged batch or another
+        operator's output is, a ``resident`` batch (ColumnBatch.resident: a
+        pinned table's own storage) is there anyway and costs nothing
+        here."""
+        st.lanes = lanes
+        st.pending.append(slot)
+        self.encoding_stats.agg_streamed_batches += 1
+        if len(st.pending) == _FOLD_GROUP:
+            self._launch_group()
+        elif self._mem is not None and not resident:
+            held = sum(map(device_nbytes, {
+                id(a): a for a in jax.tree_util.tree_leaves(slot)}.values()))
+            if held:
+                st.held_bytes += held
+                self._mem.update(self, st.state_bytes + st.held_bytes)
+        self.trace_attrs = st.attrs(self._launched)
+        self._launched = 0
+
+    def _launch_group(self) -> None:
+        """The pending group into the state, in arrival order: ONE launch
+        (no-op when nothing is held).  Slots the group does not fill are
+        fed its first batch again; the program reads ``n`` of them."""
+        st = self._stream
+        if st is None or not st.pending:
+            return
+        group, st.pending = st.pending, []
+        n = len(group)
+        group.extend(group[:1] * (_FOLD_GROUP - n))
+        st.state = st.program(st.state, K.slot_count(n), tuple(group))
+        self.encoding_stats.agg_fold_launches += 1
+        self._launched += n
+        if st.held_bytes:
+            st.held_bytes = 0
+            self._mem.update(self, st.state_bytes)
 
     def _fold(self, batch: ColumnBatch) -> None:
-        """One batch into the running state, with a launch of its own."""
+        """One batch towards the running state, by the aggregation's own
+        program (kernels.small_agg_fold)."""
         batch = pad_to_bucket(batch)
-        cols, live = batch.columns, batch.live
+        cols, live, resident = batch.columns, batch.live, batch.resident
         for i in self._channels:
             c = cols[i]
             if c.encoding == "RLE":  # one scalar crosses, not the run
@@ -1563,17 +1642,19 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
             batch = ColumnBatch(batch.names, cols, live)
         ops, slots = _masked_operands(self.group_keys, self.aggs, self.step,
                                       batch)
+        key = (ops.static, batch.num_rows)
         st = self._stream
-        if st is None or not st.continues(ops, cols, self._channels, False):
-            st = self._open_stream(batch, ops, slots)
-        st.lanes = batch.num_rows
-        st.state = K.small_agg_fold(st.state, ops)
-        self.encoding_stats.agg_streamed_batches += 1
-        self.trace_attrs = st.attrs()
+        if (st is None or st.key != key
+                or not st.continues(ops, cols, self._channels, False)):
+            st = self._open_stream(
+                batch, ops, slots, key,
+                K.small_agg_fold_program(ops, _FOLD_GROUP))
+        self._hold(st, tuple(ops.flat), batch.num_rows, resident)
 
     def _fold_fused(self, batch: ColumnBatch) -> None:
-        """One of the feed's input batches through predicate, projections
-        and the fold into the running state: one launch."""
+        """One of the feed's input batches towards predicate, projections
+        and the fold into the running state (operators.
+        filter_project_agg)."""
         if not batch.num_rows:
             return
         fp = self.feed
@@ -1581,19 +1662,16 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
         prog, sig, cols, live = fp.program_inputs(batch)
         fp.observe_encoded(batch, prog.needed)
         st = self._stream
-        if st is None or st.feed != (prog, sig):
+        if st is None or st.key != (prog, sig):
             view, has_error = prog.view(sig, cols, live)
             ops, slots = _masked_operands(self.group_keys, self.aggs,
                                           self.step, view)
             st = self._open_stream(
-                view, ops, slots, has_error, (prog, sig),
+                view, ops, slots, (prog, sig),
                 _filter_project_agg_program(
                     prog, tuple(self.group_keys), tuple(self.aggs),
-                    self.step))
-        st.lanes = batch.num_rows
-        st.state = st.program(st.state, cols, live)
-        self.encoding_stats.agg_streamed_batches += 1
-        self.trace_attrs = st.attrs()
+                    self.step), has_error, True)
+        self._hold(st, (cols, live), batch.num_rows, batch.resident)
 
     def _emit_stream(self, st: _MaskedStream) -> ColumnBatch:
         """A stream's state through finalization: one page."""
@@ -1606,13 +1684,15 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
                           st.columns, st.slots)
 
     def _close_stream(self, as_states: bool) -> ColumnBatch:
-        """Take the running stream out: its error scalar to
-        ``pending_errors``, its state out as a page -- of this step's own
-        output, or (``as_states``, SINGLE) of mergeable partial states."""
+        """Take the running stream out: what it still holds folded, its
+        error scalar to ``pending_errors``, its state out as a page -- of
+        this step's own output, or (``as_states``, SINGLE) of mergeable
+        partial states."""
+        self._launch_group()
         st, self._stream = self._stream, None
         if st.has_error:
             self.pending_errors.append(st.state[-1])
-        self.trace_attrs = st.attrs()
+        self.trace_attrs = st.attrs(self._launched)
         if not as_states:
             return self._emit_stream(st)
         twin = self._partial_twin(st.columns)
@@ -1639,6 +1719,17 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
         else:
             self._result = self._close_stream(False)
         self.release_memory()
+
+    def close(self) -> None:
+        """Downstream needs no more output: what a stream still holds is
+        let go unfolded, and its reservation with it."""
+        super().close()
+        st = self._stream
+        if st is not None and st.pending:
+            st.pending = []
+            if st.held_bytes:
+                st.held_bytes = 0
+                self._mem.update(self, st.state_bytes)
 
     def _reduction_count(self, inp: ColumnBatch) -> int:
         """State columns _compute will ask the reduction kernel for, from

@@ -357,6 +357,7 @@ class EncodingStats:
     agg_compaction_skipped: int = 0  # masked path took the dead lanes as-is
     # the streaming masked aggregation (HashAggregationOperator._streams):
     agg_streamed_batches: int = 0  # batches folded into a running state
+    agg_fold_launches: int = 0  # ... in this many launches (a group each)
     agg_fused_feed: int = 0    # aggregations that absorbed their filter/project
     agg_state_seals: int = 0   # states sealed by a change of dictionaries
 
@@ -391,6 +392,7 @@ class EncodingStats:
         self.agg_compacted += other.agg_compacted
         self.agg_compaction_skipped += other.agg_compaction_skipped
         self.agg_streamed_batches += other.agg_streamed_batches
+        self.agg_fold_launches += other.agg_fold_launches
         self.agg_fused_feed += other.agg_fused_feed
         self.agg_state_seals += other.agg_state_seals
 
@@ -418,7 +420,8 @@ class EncodingStats:
             f"{self.agg_codes_sort} codes-sort / {self.agg_sort} sort, "
             f"{self.agg_compacted} compacted, "
             f"{self.agg_compaction_skipped} compaction skipped, "
-            f"{self.agg_streamed_batches} batches streamed "
+            f"{self.agg_streamed_batches} batches streamed in "
+            f"{self.agg_fold_launches} launches "
             f"({self.agg_fused_feed} aggregations fused with their "
             f"filter/project, {self.agg_state_seals} state seals)"
         )

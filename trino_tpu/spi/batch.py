@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import datetime
 import decimal
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Sequence
 
 import numpy as np
@@ -577,11 +577,16 @@ class ColumnBatch:
     is a dynamic-shape operation XLA cannot fuse — batches stay at their
     padded power-of-two size through the jitted pipeline (the selection-
     vector idiom replacing Trino's Page.getPositions compaction).  Operators
-    either understand ``live`` or call :meth:`compact` first."""
+    either understand ``live`` or call :meth:`compact` first.
+
+    ``resident`` marks a view of storage that outlives the query (a table
+    pinned to the device, connectors/memory.py): an operator that holds such
+    a batch keeps nothing alive that was not, and accounts nothing for it."""
 
     names: list[str]
     columns: list[Column]
     live: np.ndarray | None = None  # None = every row live
+    resident: bool = field(default=False, compare=False, repr=False)
 
     def __post_init__(self):
         assert len(self.names) == len(self.columns)
@@ -705,7 +710,8 @@ class ColumnBatch:
         return ColumnBatch(self.names, [c.filter(mask) for c in self.columns])
 
     def select(self, names: Sequence[str]) -> "ColumnBatch":
-        return ColumnBatch(list(names), [self.column(n) for n in names], self.live)
+        return ColumnBatch(list(names), [self.column(n) for n in names],
+                           self.live, self.resident)
 
     def slice(self, start: int, stop: int) -> "ColumnBatch":
         assert self.live is None, "slice() on a masked batch (compact first)"
@@ -748,7 +754,8 @@ class ColumnBatch:
         return list(zip(*cols)) if cols else []
 
     def rename(self, names: Sequence[str]) -> "ColumnBatch":
-        return ColumnBatch(list(names), self.columns, self.live)
+        return ColumnBatch(list(names), self.columns, self.live,
+                           self.resident)
 
 
 def _same_dictionary(a, b) -> bool:

@@ -166,9 +166,11 @@ def test_only_the_sorting_paths_pay_for_compaction(name, kind):
                 es.agg_compacted) == (1, 1, 0)
         # (since PR 30 these stream: ``lanes`` is the last batch's; since
         # PR 37 a launch takes a group: the one batch, at finish)
+        # no decimal AVG here reads a sum's integers or sums unscaled
         assert op.trace_attrs == {"path": "masked", "compaction": "skipped",
                                   "lanes": LANES, "mode": "streamed",
-                                  "fused": False, "batches": 1}
+                                  "fused": False, "batches": 1,
+                                  "avg_shared": 0, "avg_unscaled": 0}
     else:
         # a sort follows: one count sync each time, and the compaction when
         # under a quarter of the lanes live

@@ -209,14 +209,14 @@ def test_static_grouped_agg_as_the_fused_stage_calls_it(
 def test_masked_aggregation_fold_with_a_donated_state(shape, rows):
     """kernels.small_agg_fold as a streaming Q1 PARTIAL task calls it: two
     dictionary keys (3 x 2 codes), the batch's live mask, DECIMAL sums as
-    int64, an avg's scale-free f64 sum and count, count(*), for a group of
-    _FOLD_GROUP batches a launch (every slot after the first under
-    ``lax.cond``); the state is donated, so the [6]-lane state columns
-    (stacked by dtype) alias in and out."""
+    int64, an unpaired decimal avg's f64 sum of unscaled integers and its
+    count, count(*), for a group of _FOLD_GROUP batches a launch (every
+    slot after the first under ``lax.cond``); the state is donated, so the
+    [6]-lane state columns (stacked by dtype) alias in and out."""
     from trino_tpu.exec.operators import _FOLD_GROUP
 
     spec = (("sum", 0, -1, "<i8", None), ("sum", 1, -1, "<i8", None),
-            ("sum", 0, -1, "<f8", ("scale", 2)), ("count", 0, -1, "<i8", None),
+            ("sum", 0, -1, "<f8", None), ("count", 0, -1, "<i8", None),
             ("min", 2, -1, "<i4", None), ("count_star", -1, -1, "<i8", None))
     layout = K.small_agg_state_layout(spec)
     state = tuple(shape(dims, np.dtype(d))

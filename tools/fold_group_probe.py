@@ -14,9 +14,14 @@ seconds of each.  operators._FOLD_GROUP is read off its output.
     chiprun --timeout 1500 -- python3 tools/fold_group_probe.py
 
 Prints one JSON line per program and a summary; the same goes to
-chiprun_out/fold_group_probe.json as it comes.  A CPU run
-(JAX_PLATFORMS=cpu --lanes-log2 12) rehearses the body and measures nothing
-worth keeping.
+chiprun_out/fold_group_probe.json as it comes.  ``--queries q1 --plan
+cond8 --ops`` narrows the run to one program and also writes, for each
+program timed and for the engine's own ``operators.filter_project_agg``,
+its optimized HLO text (chiprun_out/fold_group_probe_<name>.hlo.txt) and
+the device's time in each of its top-level operations a launch
+(``ops_us_a_launch`` in the JSON): which fusion of the fold costs what.
+A CPU run (JAX_PLATFORMS=cpu --lanes-log2 12) rehearses the body and
+measures nothing worth keeping.
 """
 
 from __future__ import annotations
@@ -137,24 +142,49 @@ def group_program(site: str, prog, agg, variant: str, slots: int,
         donate_argnums=(0,) if donate else ())
 
 
-def device_times(trace_dir: str) -> dict:
-    """{program name: [seconds of each execution]} from the trace's device
-    planes (``XLA Modules``); empty where the backend has none (the CPU)."""
+def device_times(trace_dir: str) -> tuple:
+    """({program name: [seconds of each execution]}, {program name: {op:
+    seconds over all executions}}) from the trace's device planes (``XLA
+    Modules`` and the ``XLA Ops`` each execution holds); empty where the
+    backend has none (the CPU)."""
+    import bisect
+
     import jax.profiler
 
     out: dict = {}
+    ops: dict = {}
     for path in glob.glob(os.path.join(
             trace_dir, "plugins", "profile", "*", "*.xplane.pb")):
         for plane in jax.profiler.ProfileData.from_file(path).planes:
             if not plane.name.startswith("/device:TPU:"):
                 continue
+            runs, events = [], []
             for line in plane.lines:
-                if line.name != "XLA Modules":
-                    continue
-                for e in line.events:
-                    out.setdefault(e.name.split("(")[0], []).append(
-                        e.duration_ns / 1e9)
-    return out
+                if line.name == "XLA Modules":
+                    for e in line.events:
+                        name = e.name.split("(")[0]
+                        out.setdefault(name, []).append(e.duration_ns / 1e9)
+                        runs.append((e.start_ns, e.start_ns + e.duration_ns,
+                                     name))
+                elif line.name == "XLA Ops":
+                    events.extend((e.start_ns, e.duration_ns, e.name)
+                                  for e in line.events)
+            runs.sort()
+            starts = [r[0] for r in runs]
+            for s, d, op in events:
+                i = bisect.bisect_right(starts, s) - 1
+                if i >= 0 and s < runs[i][1]:
+                    mine = ops.setdefault(runs[i][2], {})
+                    mine[op] = mine.get(op, 0.0) + d / 1e9
+    return out, ops
+
+
+def write_hlo(name: str, fn, *args) -> None:
+    """``fn``'s optimized HLO for ``args`` into chiprun_out."""
+    text = fn.lower(*args).compile().as_text()
+    with open(os.path.join(os.path.dirname(OUT),
+                           f"fold_group_probe_{name}.hlo.txt"), "w") as f:
+        f.write(text)
 
 
 def main(argv=None) -> int:
@@ -166,7 +196,18 @@ def main(argv=None) -> int:
     ap.add_argument("--passes", type=int, default=30)
     ap.add_argument("--budget-s", type=float, default=1200.0,
                     help="start no new program after this many seconds")
+    ap.add_argument("--queries", default=",".join(QUERIES),
+                    help="comma-separated, of " + ", ".join(QUERIES))
+    ap.add_argument("--plan", default=None,
+                    help="comma-separated variants to time, e.g. cond8 "
+                         "(default: every one, in the order that decides)")
+    ap.add_argument("--ops", action="store_true",
+                    help="also write each program's HLO and its device "
+                         "time by operation")
     args = ap.parse_args(argv)
+    queries = tuple(args.queries.split(","))
+    plan = PLAN if args.plan is None else tuple(
+        (v, k) for v, k in PLAN if f"{v}{k}" in args.plan.split(","))
     t_start = time.monotonic()
 
     import jax
@@ -176,7 +217,8 @@ def main(argv=None) -> int:
     from trino_tpu.caching import result_cache
     from trino_tpu.caching.executable_cache import init_compile_cache
     from trino_tpu.exec import kernels as K
-    from trino_tpu.exec.operators import _masked_operands
+    from trino_tpu.exec.operators import (
+        _FOLD_GROUP, _filter_project_agg_program, _masked_operands)
     from trino_tpu.spi.batch import pad_to_bucket
 
     init_compile_cache()
@@ -185,7 +227,7 @@ def main(argv=None) -> int:
     catalog = pinned_lineitem(args.sf, lanes, args.batches)
     taken = {}
     with result_cache.disabled():
-        for q in QUERIES:
+        for q in queries:
             with open(os.path.join(ROOT, "benchmark", "queries",
                                    q + ".sql")) as f:
                 taken[q] = captured_fold(catalog, f.read())
@@ -203,6 +245,7 @@ def main(argv=None) -> int:
     built: list = []
     donate = K.donate_ok()
     counts = [jnp.int32(i) for i in range(max(k for _, k in PLAN) + 1)]
+    os.makedirs(os.path.dirname(OUT), exist_ok=True)
 
     def emit(point: dict) -> None:
         point["platform"], point["device_kind"] = dev.platform, dev.device_kind
@@ -212,8 +255,8 @@ def main(argv=None) -> int:
         with open(OUT, "w") as f:
             json.dump({"points": points}, f, indent=1)
 
-    for variant, slots in PLAN:
-        for q in QUERIES:
+    for variant, slots in plan:
+        for q in queries:
             if time.monotonic() - t_start > args.budget_s:
                 print(f"# over budget: {q} {variant} {slots} not run",
                       flush=True)
@@ -285,6 +328,15 @@ def main(argv=None) -> int:
                 point["host_us_a_launch_5_of_8"] = (
                     statistics.median(five) * 1e6)
             built.append((site, one_pass))
+            if args.ops:
+                write_hlo(site, fn, first_state, n, groups[0])
+                if variant == "cond" and slots == _FOLD_GROUP:
+                    engine = _filter_project_agg_program(
+                        prog, tuple(agg.group_keys), tuple(agg.aggs),
+                        agg.step)
+                    write_hlo(f"{q}_engine", engine,
+                              K.small_agg_zero_state(ops, has_error), n,
+                              groups[0])
             emit(point)
 
     # the device's time of one execution of each, from one traced pass
@@ -298,16 +350,21 @@ def main(argv=None) -> int:
             one_pass()
     finally:
         jax.profiler.stop_trace()
-    times = device_times(trace_dir)
+    times, by_op = device_times(trace_dir)
     for p in points:
-        runs = times.get("jit_trino_tools_fold_group_probe_"
-                         f"{p['query']}_{p['variant']}{p['slots']}")
+        name = ("jit_trino_tools_fold_group_probe_"
+                f"{p['query']}_{p['variant']}{p['slots']}")
+        runs = times.get(name)
         if runs:
             p["device_us_a_launch"] = statistics.median(runs) * 1e6
             p["device_us_a_batch"] = p["device_us_a_launch"] / p["slots"]
+        if args.ops and runs:
+            p["ops_us_a_launch"] = {
+                op: t * 1e6 / len(runs) for op, t in sorted(
+                    by_op.get(name, {}).items(), key=lambda kv: -kv[1])}
 
     summary = {}
-    for q in QUERIES:
+    for q in queries:
         single = next((p for p in points if p["query"] == q
                        and p["slots"] == 1), None)
         if single is None:

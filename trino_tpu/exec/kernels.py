@@ -437,12 +437,9 @@ def _small_agg_reduce(spec: tuple, num_keys: int, has_valid: tuple,
                 outs.append(count(m))
                 continue
             x = aggs_flat[data_idx]
-            if pre is not None:
-                if pre[0] == "scale":
-                    x = x.astype(jnp.float64) / (10.0 ** pre[1])
-                elif pre[0] == "square":
-                    x64 = x.astype(jnp.float64)
-                    x = x64 * x64
+            if pre is not None and pre[0] == "square":
+                x64 = x.astype(jnp.float64)
+                x = x64 * x64
             v = aggs_flat[valid_idx] if valid_idx >= 0 else None
             mv = m if v is None else (m & v)
             if fname == "count":
@@ -913,8 +910,8 @@ def _reduce_fn(spec: tuple, cap: int):
     per aggregate; data_idx/valid_idx index the DEDUPED flat input arrays
     (-1 = absent), so aggregates sharing a column or a validity/live mask
     share one prefix scan.  ``pre`` applies elementwise prep INSIDE the
-    compiled program (("scale", s) = scale-free f64 avg state; ("square",) =
-    x^2 f64 variance state) — the hot path never runs eager full-size ops.
+    compiled program (("square",) = x^2 f64 variance state) — the hot path
+    never runs eager full-size ops.
 
     All reductions are prefix-scan + boundary-gather over the sorted rows
     (gid is nondecreasing): XLA scatters serialize on TPU; the scan path is
@@ -1016,12 +1013,9 @@ def _reduce_fn(spec: tuple, cap: int):
             # integer-sourced values can never be NaN/Inf: their float sums
             # skip the IEEE rescue scans entirely
             src_float = np.dtype(data.dtype).kind == "f"
-            if pre is not None:
-                if pre[0] == "scale":
-                    data = data.astype(jnp.float64) / (10.0 ** pre[1])
-                elif pre[0] == "square":
-                    x64 = data.astype(jnp.float64)
-                    data = x64 * x64
+            if pre is not None and pre[0] == "square":
+                x64 = data.astype(jnp.float64)
+                data = x64 * x64
             valid = sorted_of(valid_idx) if valid_idx >= 0 else None
             skey = (data_idx, valid_idx, np.dtype(dtype_str).str, pre)
             if distinct:
@@ -1102,7 +1096,8 @@ def _finalize_fn(plan: tuple):
 
     plan: per output column, one of
       ("copy", dtype_str|None, has_valid)            passthrough + cast
-      ("avg_final", dtype_str, has_valid)            sum/count -> mean
+      ("unscale", scale, has_valid)                  f64(sum) / 10^scale
+      ("avg_final", dtype_str, has_valid, scale)     sum/count/10^scale
       ("stat_final", fn, dtype_str, has_valid)       (s, sq, n) -> var/stddev
       ("count", None, has_valid)                     cast int64, drop valid
     inputs: flat (data [, valid]) per plan entry's source arity."""
@@ -1124,6 +1119,18 @@ def _finalize_fn(plan: tuple):
                 if dtype_str is not None:
                     d = d.astype(jnp.dtype(dtype_str))
                 outs.append((d, v))
+            elif kind == "unscale":
+                # a decimal AVG's sum of unscaled integers -> the scale-free
+                # f64 state a PARTIAL page carries (correctly rounded: 10^s
+                # is exact in f64)
+                _, scale, has_valid = entry
+                d = flat[i].astype(jnp.float64) / (10.0 ** scale)
+                i += 1
+                v = None
+                if has_valid:
+                    v = flat[i]
+                    i += 1
+                outs.append((d, v))
             elif kind == "count":
                 _, _, has_valid = entry
                 d = flat[i]
@@ -1132,7 +1139,7 @@ def _finalize_fn(plan: tuple):
                     i += 1  # counts are never NULL
                 outs.append((d.astype(jnp.int64), None))
             elif kind == "avg_final":
-                _, dtype_str, has_valid = entry
+                _, dtype_str, has_valid, scale = entry
                 s = flat[i]
                 i += 1
                 sv = None
@@ -1141,8 +1148,10 @@ def _finalize_fn(plan: tuple):
                     i += 1
                 c = flat[i]
                 i += 1
-                cnt = jnp.maximum(c, 1)
-                vals = s / cnt
+                # one division by count x 10^scale: exact operands below
+                # 2^53 give the correctly rounded mean
+                cnt = jnp.maximum(c, 1).astype(jnp.float64) * (10.0 ** scale)
+                vals = s.astype(jnp.float64) / cnt
                 valid = c > 0
                 if sv is not None:
                     valid = valid & sv

@@ -1105,14 +1105,68 @@ def _is_long_decimal_agg(a: AggCall, inp: ColumnBatch) -> bool:
     return isinstance(t, DecimalType) and t.precision > 18
 
 
+def _short_decimal_scale(a: AggCall, inp: ColumnBatch) -> Optional[int]:
+    """The scale of ``a``'s argument where it is a short DECIMAL (int64
+    unscaled integers), else None."""
+    if a.arg < 0:
+        return None
+    t = inp.columns[a.arg].type
+    if isinstance(t, DecimalType) and t.precision <= 18:
+        return t.scale
+    return None
+
+
+def _avg_partners(aggs: Sequence[AggCall], step: str, inp: ColumnBatch
+                  ) -> tuple[dict, int]:
+    """({avg index: sum index}, unscaled): the AVGs over a short DECIMAL
+    that read the int64 state of a SUM over the same channel instead of
+    summing their own (neither DISTINCT: the same rows, the same validity,
+    an exact total whose rightness that SUM's own answer depends on
+    already), and how many other short-decimal AVGs sum the unscaled
+    integers in f64.  Either way the division by 10^scale is left to
+    finalization, once per group.  A FINAL step merges states and has
+    neither."""
+    if step == "FINAL":
+        return {}, 0
+    sums: dict = {}
+    for i, a in enumerate(aggs):
+        if (a.fn == "sum" and not a.distinct
+                and _short_decimal_scale(a, inp) is not None):
+            sums.setdefault(a.arg, i)
+    partners, unscaled = {}, 0
+    for i, a in enumerate(aggs):
+        if a.fn != "avg" or _short_decimal_scale(a, inp) is None:
+            continue
+        if not a.distinct and a.arg in sums:
+            partners[i] = sums[a.arg]
+        else:
+            unscaled += 1
+    return partners, unscaled
+
+
+class _Slots(NamedTuple):
+    """Which state columns each AggCall's answer reads, by its index in the
+    call list.  ``avg``: (the index of the spec of the sum it reads -- its
+    own, or a paired SUM's --, the decimal scale finalization divides by);
+    its count is the last spec of its own.  ``stat``, ``ld``: the first
+    spec of its run.  ``avg_shared`` / ``avg_unscaled``: how many AVGs take
+    each of _avg_partners' two forms."""
+
+    avg: dict
+    stat: dict
+    ld: dict
+    avg_shared: int
+    avg_unscaled: int
+
+
 def _aggregation_specs(aggs: Sequence[AggCall], step: str, inp: ColumnBatch,
                        use_masked: bool) -> tuple:
-    """(specs, (avg_slots, stat_slots, ld_slots)): the reduction kernels'
-    aggs list for ``aggs`` over ``inp``, and which AggCall expanded into
-    which run of state columns.  Reads only what the columns hold (arrays,
-    tracers inside a program, or shapes when only the layout is asked
-    for), so the buffered path, the per-batch fold and the fused
-    filter/project program build the same specs from the same code."""
+    """(specs, _Slots): the reduction kernels' aggs list for ``aggs`` over
+    ``inp``, and which AggCall expanded into which run of state columns.
+    Reads only what the columns hold (arrays, tracers inside a program, or
+    shapes when only the layout is asked for), so the buffered path, the
+    per-batch fold and the fused filter/project program build the same
+    specs from the same code."""
     live = inp.live
 
     def fold_live(valid):
@@ -1128,8 +1182,11 @@ def _aggregation_specs(aggs: Sequence[AggCall], step: str, inp: ColumnBatch,
     # family to (sum, sumsq, count) triples.  FINAL merges partial
     # states: count -> sum of counts, others same fn.
     specs, avg_slots, stat_slots, ld_slots = [], {}, {}, {}
+    partners, unscaled = _avg_partners(aggs, step, inp)
+    first_spec = {}  # AggCall index -> the index of its first spec
 
     for idx, a in enumerate(aggs):
+        first_spec[idx] = len(specs)
         if _is_long_decimal_agg(a, inp):
             ld_col = inp.columns[a.arg]
             # exact wide-decimal SUM/AVG: int64 limb-plane sums on
@@ -1151,7 +1208,7 @@ def _aggregation_specs(aggs: Sequence[AggCall], step: str, inp: ColumnBatch,
             c = inp.columns[a.arg]
             data, valid = c.data, fold_live(c.valid)
             if a.fn == "avg":
-                avg_slots[idx] = len(specs)
+                avg_slots[idx] = (idx, 0)
                 c2 = inp.columns[a.arg + 1]
                 specs.append(("sum", data, valid, np.float64, False))
                 specs.append(("sum", c2.data, fold_live(None), np.int64, False))
@@ -1170,15 +1227,13 @@ def _aggregation_specs(aggs: Sequence[AggCall], step: str, inp: ColumnBatch,
         s = _agg_spec(a, inp, a.type)
         s = (s[0], s[1], fold_live(s[2]), s[3], s[4])
         if s[0] == "avg":
-            avg_slots[idx] = len(specs)
-            scale = 0
-            if a.arg >= 0 and isinstance(inp.columns[a.arg].type, DecimalType):
-                scale = inp.columns[a.arg].type.scale
-            # scale-free f64 sum state; the division happens INSIDE the
-            # compiled reduce program (pre tag), never as an eager
-            # full-size op with a launch (and a compile) of its own
-            specs.append(("sum", s[1], s[2], np.float64, s[4],
-                          ("scale", scale)))
+            # the sum of the stored values -- a decimal's unscaled integers
+            # -- in f64, or a paired SUM's exact int64 one; finalization
+            # divides by 10^scale once per group, not once per row
+            avg_slots[idx] = (partners.get(idx, idx),
+                              _short_decimal_scale(a, inp) or 0)
+            if idx not in partners:
+                specs.append(("sum", s[1], s[2], np.float64, s[4]))
             specs.append(("count", s[1], s[2], np.int64, s[4]))
         elif s[0] in STAT_AGGS:
             stat_slots[idx] = len(specs)
@@ -1188,7 +1243,10 @@ def _aggregation_specs(aggs: Sequence[AggCall], step: str, inp: ColumnBatch,
             specs.append(("count", s[1], s[2], np.int64, False))
         else:
             specs.append(s)
-    return specs, (avg_slots, stat_slots, ld_slots)
+    avg_slots = {i: (first_spec[j], scale)
+                 for i, (j, scale) in avg_slots.items()}
+    return specs, _Slots(avg_slots, stat_slots, ld_slots, len(partners),
+                         unscaled)
 
 
 def _masked_operands(group_keys: Sequence[int], aggs: Sequence[AggCall],
@@ -1263,7 +1321,9 @@ class _MaskedStream:
         group; 0 where its batch was only held)."""
         return {"path": "masked", "compaction": self.compaction,
                 "lanes": self.lanes, "mode": "streamed",
-                "fused": self.fused, "batches": batches}
+                "fused": self.fused, "batches": batches,
+                "avg_shared": self.slots.avg_shared,
+                "avg_unscaled": self.slots.avg_unscaled}
 
 
 def _filter_project_fold_body(prog: _FilterProjectProgram, group_keys: tuple,
@@ -1679,7 +1739,8 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
             st.state[:len(st.shapes)], st.ops)
         if self.group_keys:
             self.encoding_stats.code_group_batches += 1
-        self.encoding_stats.count_aggregation("masked", st.compaction)
+        self.encoding_stats.count_aggregation("masked", st.compaction,
+                                              st.slots)
         return self._emit(reduced, presence, keys_out, num_groups,
                           st.columns, st.slots)
 
@@ -1734,14 +1795,16 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
     def _reduction_count(self, inp: ColumnBatch) -> int:
         """State columns _compute will ask the reduction kernel for, from
         the aggregate list and the input's types alone: avg is (sum,
-        count), the variance family (sum, sum of squares, count), a
-        long-decimal sum or avg six limb sums and a count."""
+        count), or its count alone where it reads a paired SUM's state, the
+        variance family (sum, sum of squares, count), a long-decimal sum or
+        avg six limb sums and a count."""
+        partners, _ = _avg_partners(self.aggs, self.step, inp)
         total = 0
-        for a in self.aggs:
+        for i, a in enumerate(self.aggs):
             if _is_long_decimal_agg(a, inp):
                 total += 7
             elif a.fn == "avg":
-                total += 2
+                total += 1 if i in partners else 2
             elif a.fn in STAT_AGGS:
                 total += 3
             else:
@@ -1942,10 +2005,13 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
             if inp is not padded:
                 compaction = "compacted"
                 key_cols = [inp.columns[i] for i in self.group_keys]
-        self.encoding_stats.count_aggregation(path, compaction)
+        specs, slots = _aggregation_specs(self.aggs, self.step, inp,
+                                          use_masked)
+        self.encoding_stats.count_aggregation(path, compaction, slots)
         self.trace_attrs = {"path": path, "compaction": compaction,
                             "lanes": inp.num_rows, "mode": "buffered",
-                            "fused": False}
+                            "fused": False, "avg_shared": slots.avg_shared,
+                            "avg_unscaled": slots.avg_unscaled}
         live = inp.live  # None = all rows real
         n = inp.num_rows
 
@@ -1973,8 +2039,6 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
             gid = jnp.zeros(n, jnp.int32)
             num_groups = 1
 
-        specs, slots = _aggregation_specs(self.aggs, self.step, inp,
-                                          use_masked)
         if use_masked:
             reduced, presence, keys_out, num_groups = (
                 K.small_grouped_aggregate(key_cols, live, specs))
@@ -1985,12 +2049,12 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
                           inp.columns, slots)
 
     def _emit(self, reduced, presence, keys_out, num_groups: int, columns,
-              slots: tuple) -> ColumnBatch:
+              slots: _Slots) -> ColumnBatch:
         """The output page from the reduced per-group arrays.  ``columns``
         gives the input's types and dictionaries (their values are not
         read): a batch's own columns, or a stream's _ColumnMeta."""
         nk = len(self.group_keys)
-        avg_slots, stat_slots, ld_slots = slots
+        avg_slots, stat_slots, ld_slots = slots.avg, slots.stat, slots.ld
         key_cols = [columns[i] for i in self.group_keys]
 
         # finalization (avg division, variance combine, output casts) runs
@@ -2064,12 +2128,15 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
                 ncols += 1
                 continue
             if idx in avg_slots:
-                s_data, s_valid = reduced[ri]
-                c_data, _ = reduced[ri + 1]
-                ri += 2
+                sum_at, scale = avg_slots[idx]
+                if sum_at == ri:
+                    ri += 1  # its own sum; else a paired SUM's
+                s_data, s_valid = reduced[sum_at]
+                c_data, _ = reduced[ri]
+                ri += 1
                 if self.step == "PARTIAL":
                     # emit mergeable states: scale-free sum + count
-                    emit(("copy", "<f8", s_valid is not None),
+                    emit(("unscale", scale, s_valid is not None),
                          [s_data] + ([s_valid] if s_valid is not None else []),
                          t)
                     emit(("count", None, False), [c_data],
@@ -2077,7 +2144,7 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
                     ncols += 2
                     continue
                 emit(("avg_final", np.dtype(t.storage_dtype).str,
-                      s_valid is not None),
+                      s_valid is not None, scale),
                      [s_data] + ([s_valid] if s_valid is not None else [])
                      + [c_data], t)
                 ncols += 1

@@ -360,10 +360,16 @@ class EncodingStats:
     agg_fold_launches: int = 0  # ... in this many launches (a group each)
     agg_fused_feed: int = 0    # aggregations that absorbed their filter/project
     agg_state_seals: int = 0   # states sealed by a change of dictionaries
+    # decimal AVGs (operators._avg_partners), once per aggregation computed:
+    agg_avg_shared: int = 0    # read a paired SUM's int64 state
+    agg_avg_unscaled: int = 0  # summed their unscaled integers in f64
 
-    def count_aggregation(self, path: str, compaction: str) -> None:
+    def count_aggregation(self, path: str, compaction: str, slots) -> None:
         """path: masked | codes-sort | sort; compaction: compacted |
-        skipped | none (not a candidate, or counted and found dense)."""
+        skipped | none (not a candidate, or counted and found dense);
+        slots: the aggregation's operators._Slots."""
+        self.agg_avg_shared += slots.avg_shared
+        self.agg_avg_unscaled += slots.avg_unscaled
         if path == "masked":
             self.agg_masked += 1
         elif path == "codes-sort":
@@ -395,6 +401,8 @@ class EncodingStats:
         self.agg_fold_launches += other.agg_fold_launches
         self.agg_fused_feed += other.agg_fused_feed
         self.agg_state_seals += other.agg_state_seals
+        self.agg_avg_shared += other.agg_avg_shared
+        self.agg_avg_unscaled += other.agg_avg_unscaled
 
     @property
     def any(self) -> bool:
@@ -423,7 +431,9 @@ class EncodingStats:
             f"{self.agg_streamed_batches} batches streamed in "
             f"{self.agg_fold_launches} launches "
             f"({self.agg_fused_feed} aggregations fused with their "
-            f"filter/project, {self.agg_state_seals} state seals)"
+            f"filter/project, {self.agg_state_seals} state seals); "
+            f"decimal avg: {self.agg_avg_shared} read their sum's integers, "
+            f"{self.agg_avg_unscaled} summed unscaled"
         )
 
 

@@ -128,6 +128,24 @@ class DeviceJoinTable:
         pair total — the provable padded-expand cap (ExpandPlanner)."""
         return self._fetch()[2]
 
+    @property
+    def path(self) -> str:
+        """How a single-key probe finds its row: ``dense`` (one gather from
+        the direct-address table) or ``hashed`` (the sorted hashes)."""
+        return "dense" if self.dense is not None else "hashed"
+
+    def known_live_rows(self) -> Optional[int]:
+        """Live rows where the host already holds them, else None: never a
+        sync (a build on one integer key fetched them to weigh the dense
+        table; a build on two keys has not)."""
+        return self._fetched[1] if self._fetched is not None else None
+
+
+def lane_bytes(cols) -> int:
+    """Bytes one lane of [(data, valid|None), ...] holds: each column's
+    value and a byte for its validity mask."""
+    return sum(np.dtype(d.dtype).itemsize + (v is not None) for d, v in cols)
+
 
 class JoinHashTable:
     """Open-addressing index over the build side's 64-bit key hashes

@@ -144,7 +144,7 @@ class LocalPlanner:
                     "INNER", "LEFT", "SINGLE") and op.left_keys:
                 return LookupJoinOperator(
                     op.bridge, op.left_keys, op.join_type, op.residual,
-                    op.output_names, op.output_types)
+                    op.output_names, op.output_types, op.place)
             if isinstance(op, SemiJoinOperator) and op.source_keys:
                 return SemiJoinOperator(
                     op.bridge, op.source_keys, op.null_aware, op.residual,
@@ -326,7 +326,8 @@ class LocalPlanner:
                 chain[0].dynamic_filters.extend(scan_attach)
             chain.append(LookupJoinOperator(
                 bridge, node.left_keys, node.join_type, node.residual,
-                node.output_names, node.output_types))
+                node.output_names, node.output_types,
+                sum(isinstance(op, LookupJoinOperator) for op in chain)))
             return chain
 
         if isinstance(node, P.SemiJoin):

@@ -37,6 +37,8 @@ from ..spi.connector import Connector, ConnectorPageSink, Split
 from ..spi.types import BIGINT, BOOLEAN, DOUBLE, DecimalType, Type, is_string
 from ..sql.ir import InputRef, RowExpression, referenced_inputs
 from ..planner.plan import AggCall, SortKey, WindowFunc
+from ..telemetry import metrics as tm
+from ..telemetry import profiler
 from . import kernels as K
 from . import syncguard as SG
 from . import window_kernels as WK
@@ -260,8 +262,6 @@ class ScanOperator(Operator):
 
     def _stage(self, batch: ColumnBatch) -> ColumnBatch:
         if self.ingest_cfg.stage_device:
-            from ..telemetry import profiler
-
             if profiler.enabled():
                 t0 = profiler.now()
                 staged = self._stager.stage(batch)
@@ -2278,6 +2278,7 @@ class JoinBuildSink(BufferedInputMixin, Operator):
     def finish_input(self) -> None:
         from . import join_exec as JX
 
+        t0 = profiler.now()
         super().finish_input()
         if self.buffered_batches():
             # no live-compaction here: the build program sorts dead rows
@@ -2300,9 +2301,14 @@ class JoinBuildSink(BufferedInputMixin, Operator):
         self.bridge.batch = batch
         self.bridge.key_dicts = [
             batch.columns[ch].dictionary for ch in self.key_channels]
-        self.bridge.table = JX.build_table(
+        table = self.bridge.table = JX.build_table(
             keys, live=live, num_rows=batch.num_rows)
         self.release_memory()
+        if keys:
+            profiler.event(profiler.JOIN_BUILD, "JoinBuildSink", t0,
+                           path=table.path, rows=table.known_live_rows(),
+                           lanes=table.num_rows, keys=len(keys),
+                           key_bytes=JX.lane_bytes(keys))
 
     def is_finished(self) -> bool:
         return self.input_done
@@ -2392,13 +2398,17 @@ class LookupJoinOperator(Operator):
 
     def __init__(self, bridge: JoinBridge, left_keys: Sequence[int],
                  join_type: str, residual: Optional[RowExpression],
-                 output_names: Sequence[str], output_types: Sequence[Type]):
+                 output_names: Sequence[str], output_types: Sequence[Type],
+                 place: int = 0):
         self.bridge = bridge
         self.left_keys = list(left_keys)
         self.join_type = join_type
         self.residual = residual
         self.output_names = list(output_names)
         self.output_types = list(output_types)
+        # how many probes come before this one in its pipeline (the
+        # recorder's ``join-probe`` event says which join a page went to)
+        self.place = place
         from collections import deque
 
         from . import join_exec as JX
@@ -2463,6 +2473,7 @@ class LookupJoinOperator(Operator):
         if not self.left_keys:  # cross join (nested-loop fallback)
             self._add_cross_input(probe)
             return
+        t0 = profiler.now()
         # the probe programs are compiled per lane count: a dense page (a
         # remote producer's, a host operator's) takes its bucket first, so
         # they see powers of two from every source
@@ -2484,13 +2495,21 @@ class LookupJoinOperator(Operator):
             if self.join_type in ("INNER", "RIGHT"):
                 # FK->PK probe: ranges+verify, then a width-adaptive gather
                 # (exec/join_exec.py r5 design notes)
-                self._add_inner_unique(probe, table, build, keys, remaps)
+                gather = self._add_inner_unique(
+                    probe, table, build, keys, remaps)
             else:
                 # LEFT/SINGLE/FULL keep every probe row: the wide one-program
                 # path with zero per-batch syncs
-                self._add_unique_input(probe, table, build, keys, remaps)
-            return
-        self._add_pairs(probe, table, build, keys, remaps)
+                gather = self._add_unique_input(
+                    probe, table, build, keys, remaps)
+        else:
+            gather = self._add_pairs(probe, table, build, keys, remaps)
+        path = table.path
+        tm.observe_join_probe(path)
+        profiler.event(profiler.JOIN_PROBE, "LookupJoinOperator", t0,
+                       join=self.place, path=path, keys=len(keys),
+                       lanes=probe.num_rows, key_bytes=JX.lane_bytes(keys),
+                       gather_lanes=gather[0], gather_bytes=gather[1])
 
     def _null_extended(self, probe: ColumnBatch, build: ColumnBatch,
                        un_live) -> ColumnBatch:
@@ -2507,23 +2526,24 @@ class LookupJoinOperator(Operator):
             self.output_names, list(probe.columns) + right_cols, un_live)
 
     def _add_pairs(self, probe: ColumnBatch, table, build,
-                   keys, remaps) -> None:
+                   keys, remaps) -> tuple:
         """General (non-unique build) probe: candidate ranges + padded
         expand.  The expand bucket comes from build-side statistics
         (ExpandPlanner) and overflow checks are deferred to async flag
-        polls, so the steady state never blocks on the candidate total."""
+        polls, so the steady state never blocks on the candidate total.
+        Returns the gather's (lanes, bytes a lane) as launched."""
         from . import join_exec as JX
 
         need_matched = self.join_type in ("LEFT", "SINGLE", "FULL")
         if self.join_type in ("RIGHT", "FULL"):
             self._probe_dicts = [c.dictionary for c in probe.columns]
         if probe.num_rows == 0:
-            return
+            return 0, 0
         if table.num_rows == 0:  # empty build: no pairs, all probes unmatched
             if need_matched:
                 self._pending.append(
                     self._null_extended(probe, build, probe.live))
-            return
+            return 0, 0
         probe_cols = [(c.data, c.valid) for c in probe.columns]
         build_cols = [(c.data, c.valid) for c in build.columns]
         pair_types = ([c.type for c in probe.columns]
@@ -2563,13 +2583,14 @@ class LookupJoinOperator(Operator):
                 table, keys, remaps, probe.live)
             cap, provable = self._planner.plan(probe.num_rows, table.max_run)
             self._planner.observe_async(total_a)
+            gather = cap, JX.lane_bytes(probe_cols) + JX.lane_bytes(build_cols)
             res = JX.run_pairs(
                 table, lo, counts, total_a, keys, remaps, probe_cols,
                 build_cols, pair_types, pair_dicts, self.residual,
                 need_matched, cap=cap, donate=provable)
             if provable:  # cap >= any possible total: no overflow, no retry
                 commit(res)
-                return
+                return gather
 
             def retry():
                 # rare: the estimated bucket truncated candidates — re-run
@@ -2585,18 +2606,20 @@ class LookupJoinOperator(Operator):
                 SG.async_scalar(res[5], "join.expand-overflow"),
                 res, retry, commit)
             self._inflight.drain()
+        return gather
 
     def _add_inner_unique(self, probe: ColumnBatch, table, build,
-                          keys, remaps) -> None:
+                          keys, remaps) -> tuple:
         """INNER/RIGHT probe against a unique build: ranges and the match
         count stay on device, and the gather's width is sized from a match
         count in every batch — an earlier batch's, the seed an earlier
         execution of the same plan shape left, or (neither known) this
-        batch's own, fetched once."""
+        batch's own, fetched once.  Returns the gather's (lanes, bytes a
+        lane) as launched."""
         from . import join_exec as JX
 
         if probe.num_rows == 0:
-            return
+            return 0, 0
         ok_live, bid, cnt_a = JX.run_unique_ranges_device(
             table, keys, remaps, probe.live)
         if self.join_type == "RIGHT":
@@ -2647,6 +2670,10 @@ class LookupJoinOperator(Operator):
         # cap == lanes: the wide leg
         self.trace_attrs = {"cap": lanes if cap is None else cap,
                             "lanes": lanes, "estimate": origin}
+        # the wide leg gathers the build columns, the compact leg the probe
+        # columns too
+        gather = ((lanes, JX.lane_bytes(build_cols)) if cap is None else
+                  (cap, JX.lane_bytes(probe_cols) + JX.lane_bytes(build_cols)))
         with SG.hot_region():
             res = JX.run_unique_gather(
                 table, ok_live, bid, cap, probe_cols, build_cols,
@@ -2655,7 +2682,7 @@ class LookupJoinOperator(Operator):
                 # the wide path cannot overflow, nor can a cap sized from
                 # the batch's own count
                 commit(res)
-                return
+                return gather
 
             def retry():
                 # compact bucket overflowed: re-run wide (provably safe)
@@ -2668,6 +2695,7 @@ class LookupJoinOperator(Operator):
                 SG.async_scalar(res[4], "join.unique-overflow"),
                 res, retry, commit)
             self._inflight.drain()
+        return gather
 
     def finish_input(self) -> None:
         super().finish_input()
@@ -2679,12 +2707,14 @@ class LookupJoinOperator(Operator):
         self._uplanner.land()
 
     def _add_unique_input(self, probe: ColumnBatch, table, build,
-                          keys, remaps) -> None:
+                          keys, remaps) -> tuple:
         """Unique-build probe: ONE program, probe columns pass through, the
         output rides the probe batch's shape with the match mask as live.
         Covers every join type: LEFT/SINGLE/FULL keep unmatched probe rows
         as NULL-extended lanes of the same batch (no second batch), SINGLE
-        can never violate cardinality (<=1 match by construction)."""
+        can never violate cardinality (<=1 match by construction).  Returns
+        the gather's (lanes, bytes a lane): the build columns over every
+        probe lane."""
         from . import join_exec as JX
 
         need_res_cols = self.residual is not None
@@ -2720,6 +2750,7 @@ class LookupJoinOperator(Operator):
             out_live = probe.live
         self._pending.append(ColumnBatch(
             self.output_names, list(probe.columns) + right_cols, out_live))
+        return probe.num_rows, JX.lane_bytes(build_cols)
 
     _dense_build: Optional[ColumnBatch] = None  # set by the cross path
 

@@ -381,6 +381,12 @@ EXCHANGE_PAGES_DENSIFIED = REGISTRY.counter(
 EXCHANGE_DENSIFIED_BYTES = REGISTRY.counter(
     "trino_exchange_pages_densified_bytes_total",
     "bytes of the exchange pages densified on the host")
+JOIN_PROBES_DENSE = REGISTRY.counter(
+    "trino_join_probes_dense_total",
+    "join probe pages through a direct-address (dense) build table")
+JOIN_PROBES_HASHED = REGISTRY.counter(
+    "trino_join_probes_hashed_total",
+    "join probe pages through a sorted-hash build table")
 UNIQUE_GATHER_SEEDED = REGISTRY.counter(
     "trino_exec_unique_gather_seeded_total",
     "unique-build probe gathers sized from an earlier execution's seed")
@@ -789,6 +795,12 @@ def observe_exchange_page(device: bool, nbytes: int = 0) -> None:
     else:
         EXCHANGE_PAGES_DENSIFIED.inc()
         EXCHANGE_DENSIFIED_BYTES.inc(nbytes)
+
+
+def observe_join_probe(path: str) -> None:
+    """One probe page through a LookupJoinOperator, by its ``path``
+    (``dense`` or ``hashed``), counted as it goes like an exchange page."""
+    (JOIN_PROBES_DENSE if path == "dense" else JOIN_PROBES_HASHED).inc()
 
 
 def observe_sync(sync) -> None:

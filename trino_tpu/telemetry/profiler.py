@@ -24,12 +24,14 @@ start of its tasks; from the joined tasks to the ``QueryResult``),
 ``query-close`` with
 the recorder's own ``harvest`` inside it, and ``deliver`` (result rows to
 protocol values); those that end after the query's rings were harvested are
-written straight into its stored profile (``stored_event``).  The coarse
+written straight into its stored profile (``stored_event``).  The hash join
+records ``join-build`` (a build table made) and ``join-probe`` (one probe
+page), each with its path (``dense`` or ``hashed``) and sizes.  The coarse
 kinds (``ANNOTATED``) also enter a
 ``jax.profiler.TraceAnnotation("trino.<kind>[:<name>]")``, so an open
 profiler session shows the program's rows beside the device's on the
 session's own clock; ``operator``, ``launch``, ``source-wait``, ``query``,
-``harvest`` and ``deliver`` do not.  Three attributes say how queries in
+``harvest``, ``deliver`` and the join's two kinds do not.  Three attributes say how queries in
 flight together shared the process: ``task`` carries ``cpu_s`` (thread-CPU
 seconds of the task's thread and its pipeline-group threads; what is left
 of the wall after it and the task's ``host-sync`` / ``exchange-wait``
@@ -76,7 +78,8 @@ __all__ = [
     "SPECULATION", "TASK", "ADAPTIVE", "RECOVERY", "QUERY", "EXECUTE",
     "PLAN", "SCHEDULE", "HOST_SYNC", "LAUNCH", "COMPILE", "SOURCE_WAIT",
     "TASK_BUILD", "TASK_CLOSE", "STAGE_SETUP", "RESULT", "QUERY_CLOSE",
-    "HARVEST", "DELIVER", "ANNOTATED", "level", "enabled", "is_full",
+    "HARVEST", "DELIVER", "JOIN_BUILD", "JOIN_PROBE", "ANNOTATED", "level",
+    "enabled", "is_full",
     "set_level", "event", "instant", "span", "stored_span", "annotate",
     "stored_event", "events_since", "dropped_since", "find",
     "now", "set_context", "capture_context", "apply_context", "sync_batch",
@@ -118,6 +121,16 @@ QUERY_CLOSE = "query-close"  # after execute: the query's record, memory
 HARVEST = "harvest"          # the rings swept for one query; rings, events,
 #                              kept
 DELIVER = "deliver"          # result rows -> protocol values; rows
+# the hash join: what each build and each probe page did, from what the
+# operator holds on the host (no sync)
+JOIN_BUILD = "join-build"    # JoinBuildSink's table made; path, rows (live,
+#                              None where the host does not hold them),
+#                              lanes, keys, key_bytes (a lane's key columns)
+JOIN_PROBE = "join-probe"    # one probe page of a LookupJoinOperator; join
+#                              (its place among the probes of its
+#                              pipeline), path, keys, lanes, key_bytes,
+#                              gather_lanes (the cap it ran at),
+#                              gather_bytes (a gathered lane's columns)
 
 # kinds that also enter a jax.profiler.TraceAnnotation (a few dozen a
 # query); operator, launch and source-wait are too many for a trace viewer,

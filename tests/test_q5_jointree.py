@@ -1,14 +1,16 @@
-"""TPC-H Q5 (six tables; five probes chained on each lineitem page, two of
-them through the general hashed join: ``l_orderkey = o_orderkey`` over one
-year's orders, whose keys span too wide a range for the direct-address
-table, and the two-column ``(o_custkey, s_nationkey) = (c_custkey,
-c_nationkey)``) through the runner the benchmark serves on one chip, on the
-CPU: its answer against ``benchmark/reference/q5.py`` with exact decimals,
-the hashed join against a plain numpy join, and the flight recorder's
-``join-build`` / ``join-probe`` events, which add no host sync.
+"""TPC-H Q5 (six tables; five probes chained on each lineitem page, among
+them ``l_orderkey = o_orderkey`` over one year's orders and the two-column
+``(o_custkey, s_nationkey) = (c_custkey, c_nationkey)``) through the runner
+the benchmark serves on one chip, on the CPU: its answer against
+``benchmark/reference/q5.py`` with exact decimals, and the flight
+recorder's ``join-build`` / ``join-probe`` events, which add no host sync.
+Every build of Q5 takes the direct-address table, the two-column key packed
+mixed-radix, so the general hashed join is held against a plain numpy join
+on seeded tables whose keys are drawn from a sparse space (and one
+two-column case against the packed table).
 
-The tables are SF0.05: below it one year's order keys fit the dense
-table's 2^16-slot floor, and that join would take the dense path."""
+The tables are SF0.05, the size the benchmark's CPU rehearsal of the cell
+uses."""
 
 import importlib.util
 import os
@@ -119,25 +121,27 @@ def test_q5_trace_has_two_hashed_joins_and_three_dense_and_no_sync(
         assert 0 <= a["gather_lanes"] <= a["lanes"]
         paths.setdefault(a["join"], set()).add((a["path"], a["keys"]))
     assert sorted(paths) == [0, 1, 2, 3, 4]
+    # the name is from when two of the five places took the hashed join:
+    # orders' year and the two-column customer key are dense too now
     kinds = Counter(p for v in paths.values() for p in v)
-    assert kinds == {("dense", 1): 3, ("hashed", 1): 1, ("hashed", 2): 1}
+    assert kinds == {("dense", 1): 4, ("dense", 2): 1}
     builds = _of(evs, profiler.JOIN_BUILD)
     assert Counter((b["args"]["path"], b["args"]["keys"]) for b in builds) \
-        == {("dense", 1): 6, ("hashed", 1): 2, ("hashed", 2): 2}
+        == {("dense", 1): 8, ("dense", 2): 2}
     for b in builds:
-        assert b["args"]["lanes"] >= (b["args"]["rows"] or 0)
-        assert b["args"]["key_bytes"] == 8 * b["args"]["keys"]
-    # the two-column build's live rows would take a sync: not read
-    assert {b["args"]["rows"] for b in builds
-            if b["args"]["keys"] == 2} == {None}
+        a = b["args"]
+        assert a["lanes"] >= a["rows"] > 0
+        assert a["key_bytes"] == 8 * a["keys"]
+        # the table holds every live row, in at most 16 x the key bytes
+        assert a["rows"] <= a["slots"] <= 16 * a["lanes"] * a["key_bytes"] / 4
     # the recorder's events cost no host sync: off and on alike
     assert syncs[0] == syncs[1] and syncs[1][0]
-    # both runs counted their pages, by path
+    # both runs counted their pages, by path: none hashed
     n = Counter(e["args"]["path"] for e in probes)
+    assert n["hashed"] == 0 and n["dense"] == len(probes)
     assert tm.JOIN_PROBES_DENSE.value() - probes_before["dense"] \
         == 2 * n["dense"]
-    assert tm.JOIN_PROBES_HASHED.value() - probes_before["hashed"] \
-        == 2 * n["hashed"]
+    assert tm.JOIN_PROBES_HASHED.value() - probes_before["hashed"] == 0
 
 
 # ---------------------------------------------- the hashed join, by case
@@ -169,13 +173,19 @@ def _case(name, rng):
     """(probe keys, build keys): [(values, valid)] a key column."""
     n_p, n_b = 3000, 800
     ones_p, ones_b = np.ones(n_p, bool), np.ones(n_b, bool)
-    sparse = rng.choice(10 ** 12, n_b, replace=False)  # range >> 4 x rows
-    if name == "two_column_key":
-        pairs = rng.choice(64 * 64, n_b, replace=False)
-        b1, b2 = pairs // 64, pairs % 64
+    # keys drawn from 10^12 values: no direct-address table holds them
+    sparse = rng.choice(10 ** 12, n_b, replace=False)
+    if name in ("two_column_key", "two_column_dense"):
+        if name == "two_column_key":
+            b1, b2 = sparse, rng.choice(10 ** 12, n_b, replace=False)
+        else:  # 64 x 64 slots: the packed table
+            pairs = rng.choice(64 * 64, n_b, replace=False)
+            b1, b2 = pairs // 64, pairs % 64
         pick = rng.integers(0, n_b, n_p)
         p1 = b1[pick]
-        p2 = np.where(rng.random(n_p) < 0.3, rng.integers(0, 64, n_p),
+        # 30 % of the lanes hold a first column that is there and a second
+        # that is some other row's
+        p2 = np.where(rng.random(n_p) < 0.3, b2[rng.integers(0, n_b, n_p)],
                       b2[pick])
         return ([(p1, ones_p), (p2, ones_p)], [(b1, ones_b), (b2, ones_b)])
     if name == "sparse_single_key":
@@ -195,7 +205,7 @@ def _case(name, rng):
 
 
 CASES = ["two_column_key", "sparse_single_key", "duplicate_probe_keys",
-         "misses", "null_keys"]
+         "misses", "null_keys", "two_column_dense"]
 
 
 @pytest.mark.parametrize("case", CASES)
@@ -220,4 +230,4 @@ def test_hashed_join_matches_a_numpy_join(case, pinned):
     assert sorted((int(a), int(b)) for a, b in got) == want
     paths = {e["args"]["path"] for e in profiler.events_since(t0)
              if e["kind"] == profiler.JOIN_PROBE}
-    assert paths == {"hashed"}
+    assert paths == {"dense" if case.endswith("_dense") else "hashed"}

@@ -26,6 +26,7 @@ Blocking host interaction per probe batch in the steady state: none.
 
 from __future__ import annotations
 
+import math
 import threading
 from ..caching.executable_cache import jit_memo, program
 from typing import Optional, Sequence
@@ -64,14 +65,15 @@ class DeviceJoinTable:
     """Sorted-hash build side, all arrays device-resident.
 
     The planner-visible scalars (has_null_key, live_rows, max duplicate run)
-    stay on device until first access: building the table costs ZERO blocking
-    host syncs, and the one combined scalar fetch happens lazily — per build,
-    never per probe batch (a per-batch blocking sync would drain the dispatch
-    pipeline once per batch)."""
+    stay on device until first access, and the one combined scalar fetch
+    happens per build, never per probe batch (a per-batch blocking sync
+    would drain the dispatch pipeline once per batch).  A build on integer
+    keys makes it at build time, to weigh the direct-address table; any
+    other build at its first probe."""
 
     __slots__ = ("sorted_hash", "perm", "key_datas",
                  "num_rows", "_scalars", "_fetched", "dense", "dense_lo",
-                 "hash_idx")
+                 "dense_ranges", "hash_idx")
 
     def __init__(self, sorted_hash, perm, key_datas,
                  num_rows: int, scalars):
@@ -79,15 +81,18 @@ class DeviceJoinTable:
         self.perm = perm
         self.key_datas = key_datas  # unsorted, for exact verify
         self.num_rows = num_rows  # physical slots (incl. dead padding)
-        # (has_null, live_rows, max_run[, kmin, kmax]) device scalars OR a
-        # host tuple
+        # (has_null, live_rows, max_run[, kmin, kmax per key]) device
+        # scalars OR a host tuple
         self._scalars = scalars
         self._fetched: Optional[tuple] = None
-        # direct-address table for a unique single-int-key build whose key
-        # range is dense: dense[key - dense_lo] = build row (or -1).  Probes
-        # become ONE gather — no hashing, no binary search, no verify.
+        # direct-address table for a unique build on integer keys whose
+        # packed range fits (maybe_build_dense): dense[slot] = build row (or
+        # -1), slot = sum of (key_i - dense_lo[i]) x stride_i, stride_i the
+        # product of dense_ranges after i.  Probes become ONE gather — no
+        # hashing, no binary search, no verify.
         self.dense = None
-        self.dense_lo = 0
+        self.dense_lo: tuple = ()
+        self.dense_ranges: tuple = ()
         # open-addressing index over the build hashes (TRINO_TPU_HASH_IMPL):
         # probe_ranges_device dispatches on it; every downstream program is
         # shared
@@ -130,14 +135,19 @@ class DeviceJoinTable:
 
     @property
     def path(self) -> str:
-        """How a single-key probe finds its row: ``dense`` (one gather from
-        the direct-address table) or ``hashed`` (the sorted hashes)."""
+        """How a unique probe finds its row: ``dense`` (one gather from the
+        direct-address table) or ``hashed`` (the sorted hashes)."""
         return "dense" if self.dense is not None else "hashed"
+
+    @property
+    def slots(self) -> int:
+        """Slots of the direct-address table; 0 on the hashed path."""
+        return int(self.dense.shape[0]) if self.dense is not None else 0
 
     def known_live_rows(self) -> Optional[int]:
         """Live rows where the host already holds them, else None: never a
-        sync (a build on one integer key fetched them to weigh the dense
-        table; a build on two keys has not)."""
+        sync (a build on integer keys fetched them to weigh the dense
+        table; a build on other keys has not)."""
         return self._fetched[1] if self._fetched is not None else None
 
 
@@ -249,41 +259,72 @@ def _build_fn(num_keys: int, has_valid: tuple, has_live: bool,
             max_run = jnp.zeros((), jnp.int64)
         if not want_range:
             return sh, perm, has_null, live_rows, max_run
-        # live non-null key min/max, for the dense direct-address table
+        # each key column's min/max over the live rows whose key has no
+        # NULL, for the dense direct-address table
         big = jnp.asarray(1 << 62, jnp.int64)
-        if n:
-            k0 = datas[0].astype(jnp.int64)
-            elig = jnp.ones(k0.shape, jnp.bool_)
-            if valids[0] is not None:
-                elig = elig & valids[0]
-            if live is not None:
-                elig = elig & live
-            kmin = jnp.min(jnp.where(elig, k0, big))
-            kmax = jnp.max(jnp.where(elig, k0, -big))
-        else:
-            kmin, kmax = big, -big
-        return sh, perm, has_null, live_rows, max_run, kmin, kmax
+        if not n:
+            return (sh, perm, has_null, live_rows, max_run) + (
+                big, -big) * num_keys
+        ks = [d.astype(jnp.int64) for d in datas]
+        elig = jnp.ones(ks[0].shape, jnp.bool_)
+        for v in valids:
+            if v is not None:
+                elig = elig & v
+        if live is not None:
+            elig = elig & live
+        bounds = []
+        for k in ks:
+            bounds += [jnp.min(jnp.where(elig, k, big)),
+                       jnp.max(jnp.where(elig, k, -big))]
+        return (sh, perm, has_null, live_rows, max_run) + tuple(bounds)
 
     return fn
 
 
+def _dense_slot(keys, los: tuple, ranges: tuple, has_remap=None):
+    """Traced: each lane's slot in the direct-address table, the key packed
+    mixed-radix, sum of (k_i - lo_i) x stride_i with stride_i the product of
+    the ranges after i, and whether every column lies in its range (checked
+    in 64-bit before packing, so an out-of-range lane never aliases a
+    slot).  A remapped dictionary code of -1 (absent from the build) is out
+    of range.  With one key the slot is ``key - lo``."""
+    slot = in_range = None
+    stride = math.prod(ranges)
+    for k, (d, lo, r) in enumerate(zip(keys, los, ranges)):
+        stride //= r
+        idx = d.astype(jnp.int64) - lo
+        ok = (idx >= 0) & (idx < r)
+        if has_remap is not None and has_remap[k]:
+            ok = ok & (d >= 0)
+        term = idx if stride == 1 else idx * stride
+        slot = term if slot is None else slot + term
+        in_range = ok if in_range is None else in_range & ok
+    return slot, in_range
+
+
 @jit_memo("join._dense_build_fn")
-def _dense_build_fn(size: int, has_valid: bool, has_live: bool, lo: int):
-    """Scatter live build rows into dense[key - lo] (one scatter; -1 =
-    empty slot).  Exactness needs no verify: direct addressing cannot
-    collide, and uniqueness was already proven by max_run == 1."""
+def _dense_build_fn(ranges: tuple, los: tuple, has_valid: tuple,
+                    has_live: bool):
+    """Scatter live build rows into dense[slot] (one scatter; -1 = empty
+    slot).  Exactness needs no verify: direct addressing on the whole key
+    cannot collide, and uniqueness was already proven by max_run == 1."""
+    size = math.prod(ranges)
 
     @program("join.dense_build")
-    def fn(key, *rest):
+    def fn(*flat):
         i = 0
-        valid = rest[i] if has_valid else None
-        i += 1 if has_valid else 0
-        live = rest[i] if has_live else None
-        n = key.shape[0]
-        idx = key.astype(jnp.int64) - lo
-        elig = (idx >= 0) & (idx < size)
-        if valid is not None:
-            elig = elig & valid
+        keys, valids = [], []
+        for k in range(len(ranges)):
+            keys.append(flat[i])
+            i += 1
+            if has_valid[k]:
+                valids.append(flat[i])
+                i += 1
+        live = flat[i] if has_live else None
+        n = keys[0].shape[0]
+        idx, elig = _dense_slot(keys, los, ranges)
+        for v in valids:
+            elig = elig & v
         if live is not None:
             elig = elig & live
         slot = jnp.where(elig, idx, size)  # trash slot for ineligible rows
@@ -295,37 +336,38 @@ def _dense_build_fn(size: int, has_valid: bool, has_live: bool, lo: int):
 
 
 DENSE_MAX_SLOTS = 1 << 27  # 128M * 4B = 512MB hard cap
-DENSE_SLACK = 4  # range may exceed live rows by this factor
+# the table's bytes may be up to this many times the bytes the build's key
+# columns hold: its only cost is device memory (filling it is one pass at
+# HBM speed, and a gather costs the same whatever the table's size)
+DENSE_KEY_BYTES_FACTOR = 16
 
 
-def maybe_build_dense(table: DeviceJoinTable, keys, live) -> None:
-    """Attach a direct-address table when the single int-like build key is
-    unique and densely ranged (every TPC-H PK/FK edge qualifies).  Costs the
-    build's ONE combined scalar fetch (which LEFT/semi probes and dynamic
-    filters want anyway) plus one scatter program."""
-    if len(keys) != 1 or table.num_rows == 0:
+def maybe_build_dense(table: DeviceJoinTable, keys, flat: list,
+                      has_live: bool) -> None:
+    """Attach a direct-address table when the build on integer keys is
+    unique and its packed key range is small enough: 4 bytes a slot, at most
+    DENSE_KEY_BYTES_FACTOR x (build lanes x key bytes a lane), and at most
+    DENSE_MAX_SLOTS slots (every TPC-H PK/FK edge and the composite
+    (custkey, nationkey) qualify; keys drawn from a sparse space do not).
+    Costs the build's ONE combined scalar fetch (which every probe, LEFT/semi
+    probes and dynamic filters want anyway) plus one scatter program over
+    the build program's operands (``flat``)."""
+    if table.num_rows == 0:
         return
-    d, v = keys[0]
-    kind = np.dtype(jnp.asarray(d).dtype).kind
-    if kind not in "iu":
+    _, _, max_run, *bounds = table._fetch()
+    los, his = tuple(bounds[0::2]), tuple(bounds[1::2])
+    if max_run != 1 or any(hi < lo for lo, hi in zip(los, his)):
         return
-    f = table._fetch()
-    if len(f) < 5:
+    ranges = tuple(hi - lo + 1 for lo, hi in zip(los, his))
+    slots = math.prod(ranges)
+    table_bytes = slots * np.dtype(np.int32).itemsize
+    if (slots > DENSE_MAX_SLOTS or table_bytes > DENSE_KEY_BYTES_FACTOR
+            * table.num_rows * lane_bytes(keys)):
         return
-    _, live_rows, max_run, kmin, kmax = f[:5]
-    if max_run != 1 or kmax < kmin:
-        return
-    size = kmax - kmin + 1
-    if size > DENSE_MAX_SLOTS or size > max(DENSE_SLACK * live_rows, 1 << 16):
-        return
-    flat = [jnp.asarray(d)]
-    if v is not None:
-        flat.append(jnp.asarray(v))
-    if live is not None:
-        flat.append(jnp.asarray(live))
     table.dense = _dense_build_fn(
-        int(size), v is not None, live is not None, int(kmin))(*flat)
-    table.dense_lo = int(kmin)
+        ranges, los, tuple(v is not None for _, v in keys), has_live)(*flat)
+    table.dense_lo = los
+    table.dense_ranges = ranges
 
 
 def build_table(keys: Sequence[tuple], live=None,
@@ -356,8 +398,7 @@ def build_table(keys: Sequence[tuple], live=None,
             flat.append(jnp.asarray(v))
     if live is not None:
         flat.append(jnp.asarray(live))
-    want_range = (len(keys) == 1
-                  and np.dtype(datas[0].dtype).kind in "iu")
+    want_range = all(np.dtype(d.dtype).kind in "iu" for d in datas)
     outs = _build_fn(len(keys), has_valid, live is not None,
                      want_range)(*flat)
     sh, perm = outs[0], outs[1]
@@ -377,7 +418,7 @@ def build_table(keys: Sequence[tuple], live=None,
         table.hash_idx = JoinHashTable(
             *_hash_index_fn(S, n, K.hash_interpret())(sh), S)
     if want_range:
-        maybe_build_dense(table, keys, live)
+        maybe_build_dense(table, keys, flat, live is not None)
     return table
 
 
@@ -996,30 +1037,33 @@ def _uranges_fn(num_keys: int, has_pvalid: tuple, has_remap: tuple,
 
 
 @jit_memo("join._dense_uranges_fn")
-def _dense_uranges_fn(size: int, lo: int, has_pvalid: bool, has_remap: bool,
-                      has_live: bool):
+def _dense_uranges_fn(ranges: tuple, los: tuple, has_pvalid: tuple,
+                      has_remap: tuple, has_live: bool):
     """Program A over a direct-address build: ONE gather per probe row —
-    no hashing, no binary search, no verify (direct addressing is exact)."""
+    no hashing, no binary search, no verify (direct addressing on the whole
+    key is exact)."""
+    size = math.prod(ranges)
 
     @program("join.dense_uranges")
     def fn(dense, *flat):
         i = 0
-        d = flat[i]
-        i += 1
-        if has_remap:
-            d = flat[i][d]
+        keys, valids = [], []
+        for k in range(len(ranges)):
+            d = flat[i]
             i += 1
-        valid = flat[i] if has_pvalid else None
-        i += 1 if has_pvalid else 0
+            if has_remap[k]:
+                d = flat[i][d]
+                i += 1
+            keys.append(d)
+            if has_pvalid[k]:
+                valids.append(flat[i])
+                i += 1
         live = flat[i] if has_live else None
-        idx = d.astype(jnp.int64) - lo
-        in_range = (idx >= 0) & (idx < size)
-        if has_remap:
-            in_range = in_range & (d >= 0)
+        idx, in_range = _dense_slot(keys, los, ranges, has_remap)
         bid = dense[jnp.clip(idx, 0, size - 1)]
         ok = in_range & (bid >= 0)
-        if valid is not None:
-            ok = ok & valid
+        for v in valids:
+            ok = ok & v
         if live is not None:
             ok = ok & live
         return ok, bid.astype(jnp.int64), jnp.sum(ok)
@@ -1036,20 +1080,6 @@ def run_unique_ranges_device(table: DeviceJoinTable, probe_keys, remaps,
     entry point silently drops matches."""
     has_pvalid = tuple(v is not None for _, v in probe_keys)
     has_remap = tuple(r is not None for r in remaps)
-    if table.dense is not None and len(probe_keys) == 1:
-        d, v = probe_keys[0]
-        flat = [jnp.asarray(d)]
-        if remaps[0] is not None:
-            flat.append(jnp.asarray(remaps[0]))
-        if v is not None:
-            flat.append(jnp.asarray(v))
-        if live is not None:
-            flat.append(jnp.asarray(live))
-        ok, bid, cnt = _dense_uranges_fn(
-            int(table.dense.shape[0]), table.dense_lo,
-            has_pvalid[0], has_remap[0], live is not None)(
-            table.dense, *flat)
-        return ok, bid, SG.async_scalar(cnt, "join.unique-count")
     flat = []
     for (d, v), r in zip(probe_keys, remaps):
         flat.append(jnp.asarray(d))
@@ -1057,6 +1087,13 @@ def run_unique_ranges_device(table: DeviceJoinTable, probe_keys, remaps,
             flat.append(jnp.asarray(r))
         if v is not None:
             flat.append(jnp.asarray(v))
+    if table.dense is not None:
+        if live is not None:
+            flat.append(jnp.asarray(live))
+        ok, bid, cnt = _dense_uranges_fn(
+            table.dense_ranges, table.dense_lo, has_pvalid, has_remap,
+            live is not None)(table.dense, *flat)
+        return ok, bid, SG.async_scalar(cnt, "join.unique-count")
     flat.extend(table.key_datas)
     if live is not None:
         flat.append(jnp.asarray(live))
@@ -1313,17 +1350,16 @@ def _make_unique_fn(num_keys: int, has_pvalid: tuple, has_remap: tuple,
 
         if dense is not None:
             # direct-address lookup: sorted_hash carries the dense table
-            size, dlo = dense
+            ranges, los = dense
+            size = math.prod(ranges)
             nb = bkeys[0].shape[0] if bkeys else 0
-            idx = pkeys[0].astype(jnp.int64) - dlo
-            in_range = (idx >= 0) & (idx < size)
-            if has_remap[0]:
-                in_range = in_range & (pkeys[0] >= 0)
+            idx, in_range = _dense_slot(pkeys, los, ranges, has_remap)
             slot = sorted_hash[jnp.clip(idx, 0, size - 1)]
             ok = in_range & (slot >= 0)
             bid = jnp.clip(slot.astype(jnp.int64), 0, max(nb - 1, 0))
-            if pkvalids[0] is not None:
-                ok = ok & pkvalids[0]
+            for v in pkvalids:
+                if v is not None:
+                    ok = ok & v
         else:
             h = K.hash_combine(pkeys)
             pnull = None
@@ -1395,8 +1431,8 @@ def run_unique(table: DeviceJoinTable, probe_keys, remaps,
     pcol_has_valid = tuple(v is not None for _, v in probe_cols)
     bcol_has_valid = tuple(v is not None for _, v in build_cols)
     dense = None
-    if table.dense is not None and len(probe_keys) == 1:
-        dense = (int(table.dense.shape[0]), table.dense_lo)
+    if table.dense is not None:
+        dense = (table.dense_ranges, table.dense_lo)
     with _PAIR_LOCK:
         key = ("unique", len(probe_keys), has_pvalid, has_remap,
                tuple(str(t) for t in pair_types),

@@ -2308,7 +2308,7 @@ class JoinBuildSink(BufferedInputMixin, Operator):
             profiler.event(profiler.JOIN_BUILD, "JoinBuildSink", t0,
                            path=table.path, rows=table.known_live_rows(),
                            lanes=table.num_rows, keys=len(keys),
-                           key_bytes=JX.lane_bytes(keys))
+                           key_bytes=JX.lane_bytes(keys), slots=table.slots)
 
     def is_finished(self) -> bool:
         return self.input_done

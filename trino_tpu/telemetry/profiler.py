@@ -125,7 +125,8 @@ DELIVER = "deliver"          # result rows -> protocol values; rows
 # operator holds on the host (no sync)
 JOIN_BUILD = "join-build"    # JoinBuildSink's table made; path, rows (live,
 #                              None where the host does not hold them),
-#                              lanes, keys, key_bytes (a lane's key columns)
+#                              lanes, keys, key_bytes (a lane's key columns),
+#                              slots (the direct-address table's; 0 hashed)
 JOIN_PROBE = "join-probe"    # one probe page of a LookupJoinOperator; join
 #                              (its place among the probes of its
 #                              pipeline), path, keys, lanes, key_bytes,

@@ -8,7 +8,7 @@ import string
 import numpy as np
 import pytest
 
-from trino_tpu.ops.like_dfa import VECTOR_THRESHOLD, like_mask
+from trino_tpu.ops.like_dfa import VECTOR_THRESHOLD, like_match
 from trino_tpu.ops.expr import like_to_regex
 
 
@@ -38,7 +38,8 @@ def test_vector_matches_re(pattern):
             for _ in range(VECTOR_THRESHOLD + 500)]
     vals.extend(["", "a", "abc", "aXc", "abcabc", "ab", "a" * 70])
     d = _dict(sorted(set(vals)))
-    got = like_mask(d, pattern)
+    got, _, path = like_match(d, pattern)
+    assert path == "vector"
     want = _oracle(d, pattern)
     diff = np.nonzero(got != want)[0]
     assert not len(diff), (pattern, [d[i] for i in diff[:5]])
@@ -46,12 +47,12 @@ def test_vector_matches_re(pattern):
 
 def test_escape_and_unicode_fallback():
     d = _dict(["100%", "100x", "naïve", "a_c", "abc"])
-    got = like_mask(d, "100\\%", "\\")
+    got = like_match(d, "100\\%", "\\")[0]
     assert got.tolist() == [True, False, False, False, False]
     # unicode literal falls back to re (codepoint >= 255 guard)
     big = _dict(sorted({f"naïve{i}" if i % 3 else f"x{i}"
                         for i in range(VECTOR_THRESHOLD + 10)}))
-    got = like_mask(big, "naïve%")
+    got = like_match(big, "naïve%")[0]
     want = _oracle(big, "naïve%")
     assert (got == want).all()
 
@@ -76,7 +77,8 @@ def test_embedded_nul_falls_back_to_exact():
     vals |= {"a\x00b", "a\x00", "\x00", "ab", "a", "a\x00bXtail", "k1\x00"}
     d = _dict(sorted(vals))
     for pattern in ["a_b", "a%", "_", "ab", "a\x00b%"]:
-        got = like_mask(d, pattern)
+        got, _, path = like_match(d, pattern)
+        assert path == "re"
         want = _oracle(d, pattern)
         diff = np.nonzero(got != want)[0]
         assert not len(diff), (pattern, [repr(d[i]) for i in diff[:5]])

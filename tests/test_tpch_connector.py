@@ -6,6 +6,7 @@ import pytest
 
 from trino_tpu.connectors.catalog import default_catalog
 from trino_tpu.connectors.memory import BlackholeConnector, MemoryConnector
+from trino_tpu.connectors import tpch as tpch_mod
 from trino_tpu.connectors.tpch import TpchConnector
 from trino_tpu.spi import BIGINT, VARCHAR, ColumnBatch, ColumnSchema, TableSchema
 from trino_tpu.testing.oracle import SqliteOracle, assert_same_rows, transpile
@@ -69,6 +70,44 @@ def test_fk_integrity(conn):
     # orderkeys dense 1..N
     ok = np.asarray(li.column("l_orderkey").data)
     assert set(np.unique(ok)) == set(range(1, 15001))
+
+
+def _part_names(conn):
+    col = read_all(conn, "part", ["p_name"]).column("p_name")
+    return [str(v) for v in np.asarray(col.dictionary)[np.asarray(col.data)]]
+
+
+def test_part_name_is_five_distinct_colours(conn):
+    # spec 4.2.3: five distinct words of the colour list, single spaces
+    colours = set(tpch_mod._COLORS)
+    names = _part_names(conn)
+    assert len(names) == conn.row_count("part")
+    for name in names:
+        words = name.split(" ")
+        assert len(words) == 5 and len(set(words)) == 5, name
+        assert set(words) <= colours, name
+    # seeded: the same names in every read
+    assert names == _part_names(conn)
+
+
+def test_green_keeps_five_of_ninety_two_parts():
+    names = _part_names(TpchConnector(scale_factor=0.05))
+    share = sum("green" in n for n in names) / len(names)
+    assert 0.04 <= share <= 0.07     # 5/92 = 5.4 %
+    # nearly every name is its own dictionary entry
+    assert len(set(names)) > 0.99 * len(names)
+
+
+@pytest.mark.parametrize("n,k", [(92, 5), (6, 6), (3, 1)])
+def test_distinct_picks_draw_without_replacement(n, k):
+    keys = np.arange(1, 5001, dtype=np.uint64)
+    picks = tpch_mod._distinct_picks(keys, range(80, 80 + k), n)
+    assert picks.shape == (5000, k)
+    assert picks.min() >= 0 and picks.max() < n
+    assert all(len(set(row)) == k for row in picks.tolist())
+    # every value is drawn at every position, about equally often
+    counts = np.bincount(picks[:, 0], minlength=n)
+    assert counts.min() > 0.5 * 5000 / n
 
 
 def test_customers_without_orders(conn):

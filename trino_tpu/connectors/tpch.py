@@ -61,6 +61,18 @@ def _randint(keys: np.ndarray, stream: int, lo: int, hi: int) -> np.ndarray:
     return (_h64(keys, stream) % _U(hi - lo + 1)).astype(np.int64) + lo
 
 
+def _distinct_picks(keys: np.ndarray, streams, n: int) -> np.ndarray:
+    """len(streams) distinct integers in [0, n) per key, in the order
+    drawn: the k-th draw picks among the n - k values not yet taken."""
+    picks = np.zeros((len(keys), len(streams)), np.int64)
+    for k, stream in enumerate(streams):
+        r = (_h64(keys, stream) % _U(n - k)).astype(np.int64)
+        for taken in np.sort(picks[:, :k], axis=1).T:
+            r += r >= taken
+        picks[:, k] = r
+    return picks
+
+
 def _uniform(keys: np.ndarray, stream: int) -> np.ndarray:
     return (_h64(keys, stream) >> _U(11)).astype(np.float64) / float(1 << 53)
 
@@ -553,13 +565,12 @@ class TpchConnector(Connector):
             if c == "p_partkey":
                 out.append(Column(BIGINT, ik))
             elif c == "p_name":
-                w = len(_COLORS)
-                i1 = _h64(keys, 52) % _U(w)
-                i2 = _h64(keys, 53) % _U(w)
-                i3 = _h64(keys, 54) % _U(w)
-                names = np.array(
-                    [f"{_COLORS[a]} {_COLORS[b]} {_COLORS[c2]}"
-                     for a, b, c2 in zip(i1, i2, i3)], dtype=object)
+                # spec 4.2.3: five distinct words of the colour list, joined
+                # by single spaces, in the order drawn
+                picks = _distinct_picks(keys, (52, 53, 54, 59, 60),
+                                        len(_COLORS))
+                words = np.array(_COLORS, dtype=object)[picks]
+                names = np.array([" ".join(w) for w in words], dtype=object)
                 out.append(self._dict_column("part", "p_name", names))
             elif c == "p_mfgr":
                 out.append(self._dict_column(

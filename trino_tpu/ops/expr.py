@@ -51,6 +51,8 @@ from ..spi.types import (
 )
 from ..spi.batch import rescale_scaled_int
 from ..sql.ir import Call, InputRef, Literal, RowExpression
+from ..telemetry import metrics as tm
+from ..telemetry import profiler
 
 __all__ = ["CompiledExpression", "compile_expression", "compile_projection"]
 
@@ -728,10 +730,17 @@ def _like_handler(out_type, args):
         raise NotImplementedError("LIKE requires a dictionary column and literal pattern")
     escape = str(esc.dictionary[0]) if esc is not None and esc.dictionary is not None else None
     # bit-parallel NFA over the whole dictionary (ops/like_dfa.py — the
-    # DenseDfaMatcher.java:23 role); small dictionaries keep the re loop
-    from .like_dfa import like_mask
+    # DenseDfaMatcher.java:23 role); small dictionaries keep the re loop.
+    # Host work at lowering: its event and counter touch no device value
+    from .like_dfa import like_match
 
-    mask = like_mask(col.dictionary, str(pat.dictionary[0]), escape)
+    t0 = profiler.now()
+    mask, tokens, path = like_match(col.dictionary, str(pat.dictionary[0]),
+                                    escape)
+    tm.LIKE_DICTIONARY_ENTRIES.inc(len(mask))
+    profiler.event(profiler.LIKE_MATCH, "like", t0, entries=len(mask),
+                   matched=int(np.count_nonzero(mask)), tokens=tokens,
+                   path=path)
 
     def fn(cols: Cols):
         codes, valid = col.fn(cols)

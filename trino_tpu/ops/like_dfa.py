@@ -29,7 +29,7 @@ from typing import Optional
 
 import numpy as np
 
-__all__ = ["like_mask", "like_tokens"]
+__all__ = ["like_match", "like_tokens"]
 
 VECTOR_THRESHOLD = 1024  # below this, the re loop is cheap enough
 
@@ -65,17 +65,27 @@ def _re_fallback(dictionary, pattern: str, escape: Optional[str]):
     return np.array([rx.fullmatch(str(v)) is not None for v in dictionary])
 
 
-def like_mask(dictionary, pattern: str, escape: Optional[str] = None
-              ) -> np.ndarray:
-    """Boolean match mask over every dictionary entry."""
+def like_match(dictionary, pattern: str, escape: Optional[str] = None
+               ) -> tuple:
+    """(boolean match mask over every dictionary entry, the pattern's
+    token count, the path that matched: ``vector`` or ``re``)."""
     toks = like_tokens(pattern, escape)
     if toks is None:
         raise ValueError(f"invalid LIKE escape in pattern {pattern!r}")
+    m = len(toks)
+    mask = _vector_mask(dictionary, toks)
+    if mask is None:
+        return _re_fallback(dictionary, pattern, escape), m, "re"
+    return mask, m, "vector"
+
+
+def _vector_mask(dictionary, toks: list) -> Optional[np.ndarray]:
+    """The bit-parallel NFA's mask, or None where it does not apply."""
     n = len(dictionary)
     m = len(toks)
     if (n < VECTOR_THRESHOLD or m > 63
             or any(t[0] == "c" and ord(t[1]) >= 255 for t in toks)):
-        return _re_fallback(dictionary, pattern, escape)
+        return None
 
     # padded codepoint matrix: numpy's fixed-width unicode layout IS a
     # codepoint matrix (zero-copy view); padding slots read 0
@@ -92,7 +102,7 @@ def like_mask(dictionary, pattern: str, escape: Optional[str] = None
     # equality implies elementwise equality, one scalar vs a per-entry
     # python-length array on this hot path
     if sum(len(str(v)) for v in dictionary) != int(lengths.sum()):
-        return _re_fallback(dictionary, pattern, escape)
+        return None
 
     pct_bits = np.uint64(0)
     any_bits = np.uint64(0)  # tokens consuming any char: '_' and '%'

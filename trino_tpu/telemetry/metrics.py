@@ -387,6 +387,9 @@ JOIN_PROBES_DENSE = REGISTRY.counter(
 JOIN_PROBES_HASHED = REGISTRY.counter(
     "trino_join_probes_hashed_total",
     "join probe pages through a sorted-hash build table")
+LIKE_DICTIONARY_ENTRIES = REGISTRY.counter(
+    "trino_like_dictionary_entries_total",
+    "dictionary entries a LIKE pattern was matched over, once a lowering")
 UNIQUE_GATHER_SEEDED = REGISTRY.counter(
     "trino_exec_unique_gather_seeded_total",
     "unique-build probe gathers sized from an earlier execution's seed")

@@ -26,12 +26,14 @@ the recorder's own ``harvest`` inside it, and ``deliver`` (result rows to
 protocol values); those that end after the query's rings were harvested are
 written straight into its stored profile (``stored_event``).  The hash join
 records ``join-build`` (a build table made) and ``join-probe`` (one probe
-page), each with its path (``dense`` or ``hashed``) and sizes.  The coarse
+page), each with its path (``dense`` or ``hashed``) and sizes; a LIKE
+records ``like-match`` (its pattern matched over a column's dictionary, once
+a lowering).  The coarse
 kinds (``ANNOTATED``) also enter a
 ``jax.profiler.TraceAnnotation("trino.<kind>[:<name>]")``, so an open
 profiler session shows the program's rows beside the device's on the
 session's own clock; ``operator``, ``launch``, ``source-wait``, ``query``,
-``harvest``, ``deliver`` and the join's two kinds do not.  Three attributes say how queries in
+``harvest``, ``deliver``, the join's two kinds and ``like-match`` do not.  Three attributes say how queries in
 flight together shared the process: ``task`` carries ``cpu_s`` (thread-CPU
 seconds of the task's thread and its pipeline-group threads; what is left
 of the wall after it and the task's ``host-sync`` / ``exchange-wait``
@@ -78,7 +80,8 @@ __all__ = [
     "SPECULATION", "TASK", "ADAPTIVE", "RECOVERY", "QUERY", "EXECUTE",
     "PLAN", "SCHEDULE", "HOST_SYNC", "LAUNCH", "COMPILE", "SOURCE_WAIT",
     "TASK_BUILD", "TASK_CLOSE", "STAGE_SETUP", "RESULT", "QUERY_CLOSE",
-    "HARVEST", "DELIVER", "JOIN_BUILD", "JOIN_PROBE", "ANNOTATED", "level",
+    "HARVEST", "DELIVER", "JOIN_BUILD", "JOIN_PROBE", "LIKE_MATCH",
+    "ANNOTATED", "level",
     "enabled", "is_full",
     "set_level", "event", "instant", "span", "stored_span", "annotate",
     "stored_event", "events_since", "dropped_since", "find",
@@ -132,6 +135,9 @@ JOIN_PROBE = "join-probe"    # one probe page of a LookupJoinOperator; join
 #                              pipeline), path, keys, lanes, key_bytes,
 #                              gather_lanes (the cap it ran at),
 #                              gather_bytes (a gathered lane's columns)
+LIKE_MATCH = "like-match"    # a LIKE pattern matched over a dictionary on
+#                              the host, once a lowering; entries, matched,
+#                              tokens, path (vector or re)
 
 # kinds that also enter a jax.profiler.TraceAnnotation (a few dozen a
 # query); operator, launch and source-wait are too many for a trace viewer,

@@ -114,13 +114,20 @@ def test_q5_trace_has_two_hashed_joins_and_three_dense_and_no_sync(
         syncs[level] = (d.host_syncs, d.async_polls, d.by_tag)
     evs = profiler.events_for("q5-level1")
     probes = _of(evs, profiler.JOIN_PROBE)
-    paths = {}
+    paths, widths = {}, {}
     for e in probes:
         a = e["args"]
         assert a["lanes"] > 0 and a["key_bytes"] >= 8 * a["keys"]
         assert 0 <= a["gather_lanes"] <= a["lanes"]
         paths.setdefault(a["join"], set()).add((a["path"], a["keys"]))
+        widths.setdefault(a["join"], set()).add(
+            (a["lanes"], a["gather_lanes"]))
     assert sorted(paths) == [0, 1, 2, 3, 4]
+    # every place gathers as it would with no riders: the probes behind
+    # place 3 read too little a lane to make it compact, and the last one
+    # narrows on its own
+    assert widths == {j: {(BATCH_ROWS, BATCH_ROWS)} for j in range(4)} | {
+        4: {(BATCH_ROWS, 256)}}
     # the name is from when two of the five places took the hashed join:
     # orders' year and the two-column customer key are dense too now
     kinds = Counter(p for v in paths.values() for p in v)

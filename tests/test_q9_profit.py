@@ -149,6 +149,41 @@ def test_q9_trace_has_one_hashed_join_behind_four_dense_and_no_sync(
         == 2 * n["hashed"]
 
 
+def test_q9_part_probe_narrows_the_page_for_the_probes_behind_it(pinned):
+    """`part` keeps about 5 % of a page: its own gather of one build column
+    would stay wide, but the nation, partsupp and orders probes behind it
+    read their keys and tables over every lane it hands on, so it compacts
+    the page and they run over its narrow output; the rows stay exact
+    (above)."""
+    runner = _runner(pinned)
+    for _ in range(2):      # the estimate seeds settle: later runs alike
+        runner.execute(Q9)
+    before = SG.snapshot()
+    runner.execute(Q9, query_id="q9-riders")
+    d = SG.take_delta(before)
+    evs = profiler.events_for("q9-riders")
+    pages = {}
+    for e in _of(evs, profiler.JOIN_PROBE):
+        a = e["args"]
+        pages.setdefault(a["join"], []).append(
+            (a["lanes"], a["gather_lanes"]))
+    part = pages[1]
+    assert all(g < lanes for lanes, g in part)
+    narrow = Counter(g for _, g in part)
+    for place in (2, 3, 4):
+        assert Counter(lanes for lanes, _ in pages[place]) == narrow
+    # the riders decided it, once a page of `part`
+    assert d.unique_gather_compact_riders == len(part)
+    # the full-width places carry riders; the last of the narrow ones none
+    riders = {}
+    for e in _of(evs, profiler.OPERATOR):
+        a = e.get("args") or {}
+        if e["name"] == "LookupJoinOperator" and "riders" in a:
+            riders.setdefault(a["lanes"] == BATCH_ROWS, set()).add(
+                a["riders"])
+    assert min(riders[True]) > 0 and 0 in riders[False]
+
+
 def test_like_match_moves_once_per_lowering(pinned, monkeypatch):
     calls = []
     real = like_dfa.like_match

@@ -162,16 +162,28 @@ def test_planner_unknown_max_run_never_provable():
     assert not provable and cap == K.bucket(64)
 
 
-@pytest.mark.parametrize("lanes,count,probe_words,build_words,cap", [
-    (1024, 10, 7, 7, 16),              # sparse: compact, to a power of four
-    (1024, 800, 7, 7, None),           # dense: stay wide
+@pytest.mark.parametrize("lanes,count,probe_words,build_words,riders,cap", [
+    (1024, 10, 7, 7, 0, 16),           # sparse: compact, to a power of four
+    (1024, 800, 7, 7, 0, None),        # dense: stay wide
     # where the chip's costs cross at 2^20 lanes for Q3's two builds (PR 33)
-    (1 << 20, 1 << 18, 7, 7, 1 << 18), (1 << 20, 1 << 19, 7, 7, None),
-    (1 << 20, 1 << 16, 7, 3, 1 << 16), (1 << 20, 1 << 17, 7, 3, None),
-    (1024, 10, 7, 0, None),            # no build column: nothing to gather
+    (1 << 20, 1 << 18, 7, 7, 0, 1 << 18), (1 << 20, 1 << 19, 7, 7, 0, None),
+    (1 << 20, 1 << 16, 7, 3, 0, 1 << 16), (1 << 20, 1 << 17, 7, 3, 0, None),
+    (1024, 10, 7, 0, 0, None),         # no build column: nothing to gather
+    # ... unless later probes ride the output's width
+    (1024, 10, 7, 0, 6, 16),
+    # TPC-H Q9's `part` probe at SF1 (5.4 % of a 2^20-lane page, EST_HEADROOM
+    # x the matches): its own gather of one column stays wide, the 12 words
+    # a lane the nation, partsupp and orders probes behind it read make the
+    # page narrow
+    (1 << 20, 114_032, 15, 2, 0, None), (1 << 20, 114_032, 15, 2, 12, 1 << 18),
+    # Q3's two places (SF0.25) and Q5's places 2 and 3 (SF1) with the riders
+    # each has: the decisions they make without
+    (1 << 20, 53_030, 7, 7, 4, 1 << 16), (1 << 16, 10_744, 18, 3, 0, None),
+    (1 << 20, 319_168, 22, 5, 11, None), (1 << 20, 12_842, 30, 4, 4, 1 << 14),
 ])
-def test_plan_unique_cap(lanes, count, probe_words, build_words, cap):
-    assert JX.plan_unique_cap(lanes, count, probe_words, build_words) == cap
+def test_plan_unique_cap(lanes, count, probe_words, build_words, riders, cap):
+    assert JX.plan_unique_cap(
+        lanes, count, probe_words, build_words, riders) == cap
 
 
 # ---------------------------------------------------------------------------

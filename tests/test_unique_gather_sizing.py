@@ -111,7 +111,9 @@ def test_one_batch_probe_sizes_its_gather(
     before = SG.snapshot()
     rows, attrs = _run(_operator(bridge), [batch])
     d = SG.take_delta(before)
-    assert attrs == [{"cap": cap, "lanes": LANES, "estimate": origin}]
+    # a probe no later probe rides
+    assert attrs == [{"cap": cap, "lanes": LANES, "estimate": origin,
+                      "riders": 0}]
     assert rows == want  # exact, whatever the estimate was worth
     assert (d.unique_gather_wide, d.unique_gather_compact,
             d.unique_gather_seeded) == (wide, compact, seeded)
@@ -136,7 +138,7 @@ def test_one_batch_probe_leaves_its_seed_for_the_next_operator(bridge):
     rows, attrs = _run(_operator(bridge), [batch])
     assert rows == want
     assert attrs[0] == {"cap": 256, "lanes": LANES,
-                        "estimate": "seed"}
+                        "estimate": "seed", "riders": 0}
     with JX._EST_SEEDS_LOCK:
         assert JX._EST_SEEDS[SEED_KEY] == 90
     est, origin = _operator(bridge)._uplanner.estimate()
@@ -248,5 +250,72 @@ def test_q3_twice_second_run_gathers_compact_from_seeds(monkeypatch):
     # columns wide over it: compacting those lanes again would cost more)
     assert all(g["lanes"] * 8 <= Q3_BATCH_ROWS for g in gathers
                if g not in first_join)
+    # riders change no width here: the customer probe's key and table ride
+    # the first join's output, which compacts without them
+    assert {(g["lanes"], g["cap"], g["riders"]) for g in gathers} == {
+        (Q3_BATCH_ROWS, 1024, 4), (1024, 1024, 0)}
     assert d.unique_gather_wide + d.unique_gather_compact == len(gathers)
     assert d.expand_retries == 0
+
+
+def _scan(table: str, columns: dict):
+    from trino_tpu.planner import plan as P
+
+    return P.TableScan(tuple(columns), tuple(columns.values()),
+                       catalog="tpch", table=table, columns=tuple(columns))
+
+
+def _join(left, right, left_key: int):
+    from trino_tpu.planner import plan as P
+
+    return P.Join(left.output_names + right.output_names,
+                  left.output_types + right.output_types, left=left,
+                  right=right, left_keys=(left_key,), right_keys=(0,))
+
+
+@pytest.mark.parametrize("task_concurrency", [1, 4])
+def test_local_planner_gives_each_probe_the_riders_behind_it(
+        task_concurrency):
+    """Three probes chained on lineitem: each carries the 32-bit words a
+    lane that the probes after it read whatever width they gather at (their
+    keys, a mask on a key out of a build side, and a read of the table),
+    the last none — and the clones of a forked pipeline carry the same."""
+    from trino_tpu.connectors.catalog import default_catalog
+    from trino_tpu.exec.local_planner import LocalPlanner
+    from trino_tpu.spi import DATE, VARCHAR
+
+    lineitem = _scan("lineitem", {"l_orderkey": BIGINT, "l_suppkey": BIGINT})
+    orders = _scan("orders", {"o_orderkey": BIGINT, "o_orderdate": DATE})
+    supplier = _scan("supplier", {"s_suppkey": BIGINT, "s_nationkey": BIGINT})
+    nation = _scan("nation", {"n_nationkey": BIGINT, "n_name": VARCHAR})
+    # orders on l_orderkey: 2 + 1 words; supplier on l_suppkey: 2 + 1;
+    # nation on s_nationkey, a build column with its mask: (2 + 1) + 1
+    root = _join(_join(_join(lineitem, orders, 0), supplier, 1), nation, 5)
+    plan = LocalPlanner(default_catalog(scale_factor=0.01),
+                        splits_per_node=8,
+                        task_concurrency=task_concurrency).plan(root)
+    chains = [[op for op in p if isinstance(op, LookupJoinOperator)]
+              for p in plan.pipelines]
+    chains = [c for c in chains if c]
+    for probes in chains:
+        assert [op.place for op in probes] == [0, 1, 2]
+        assert [op.riders for op in probes] == [7, 4, 0]
+    # one chain, or the scan forked into clones of it
+    assert (len(chains) > 1) == (task_concurrency > 1)
+
+
+def test_compactions_for_riders_fold_into_their_counter():
+    from trino_tpu.telemetry import metrics as tm
+
+    before = SG.snapshot()
+    SG.count_unique_gather(compact=True, seeded=True, riders=True)
+    SG.count_unique_gather(compact=True, seeded=False)
+    d = SG.take_delta(before)
+    assert (d.unique_gather_compact, d.unique_gather_seeded,
+            d.unique_gather_compact_riders) == (2, 1, 1)
+    assert "1 for riders" in d.text()
+    was = tm.UNIQUE_GATHER_COMPACT_RIDERS.value()
+    tm.observe_sync(d)
+    assert tm.UNIQUE_GATHER_COMPACT_RIDERS.value() - was == 1
+    assert "trino_exec_unique_gather_compact_riders_total" in \
+        tm.REGISTRY.render_prometheus()

@@ -1192,12 +1192,16 @@ _INDEX_S_PER_LANE = 3.5e-9        # the compact leg's index of the live lanes
 _COMPACT_EXTRA_WORDS = 3          # ... which also gathers the mask and bid
 
 
+def column_words(dtype, masked: bool) -> int:
+    """32-bit gathers a lane of one column costs: the chip has no 64-bit
+    vector path, so an 8-byte column is two (15.9-23.2 ms against 9.8 ms at
+    2^20 lanes), and a validity mask is one more."""
+    return (2 if np.dtype(dtype).itemsize > 4 else 1) + masked
+
+
 def gather_words(cols) -> int:
-    """32-bit gathers a lane that [(data, valid|None), ...] costs: the chip
-    has no 64-bit vector path, so an 8-byte column is two (15.9-23.2 ms
-    against 9.8 ms at 2^20 lanes), and a validity mask is one more."""
-    return sum((2 if d.dtype.itemsize > 4 else 1) + (v is not None)
-               for d, v in cols)
+    """32-bit gathers a lane that [(data, valid|None), ...] costs."""
+    return sum(column_words(d.dtype, v is not None) for d, v in cols)
 
 
 def unique_cap_bucket(count: int) -> int:
@@ -1214,7 +1218,7 @@ def unique_cap_bucket(count: int) -> int:
 
 
 def plan_unique_cap(n_lanes: int, count: int, probe_words: int,
-                    build_words: int) -> Optional[int]:
+                    build_words: int, rider_words: int = 0) -> Optional[int]:
     """Compact-vs-wide decision for program B: the cap to compact to, or
     None to stay wide.  ``count`` is the caller's estimate of the matches
     with its headroom (an earlier batch's or execution's count, guarded by
@@ -1225,15 +1229,20 @@ def plan_unique_cap(n_lanes: int, count: int, probe_words: int,
     above.  For TPC-H Q3's probes (7 probe words; 7 and 3 build words) the
     costs cross between a cap of lanes/4 and lanes/2, and between lanes/8
     and lanes/4 — where the chip put them (43.5 against 60.4 ms and 83
-    against 60; 15.9 against 26.9 and 33.1 against 26.9).  A join that
-    emits no build column stays wide: its probe columns pass through for
-    nothing."""
+    against 60; 15.9 against 26.9 and 33.1 against 26.9).  ``rider_words``
+    is the work a lane that rides the output's width: the words the later
+    probes of the same pipeline read over every lane they are handed
+    (LookupJoinOperator.riders), paid over all the lanes on the wide leg
+    and over the cap on the compact one.  Without riders a join that emits no
+    build column stays wide: its probe columns pass through for nothing."""
     cap = unique_cap_bucket(count)
     if cap >= n_lanes:
         return None
-    wide_s = n_lanes * build_words * _GATHER_S_PER_WORD_LANE
+    wide_s = (n_lanes * (build_words + rider_words)
+              * _GATHER_S_PER_WORD_LANE)
     compact_s = (n_lanes * _INDEX_S_PER_LANE
-                 + cap * (probe_words + build_words + _COMPACT_EXTRA_WORDS)
+                 + cap * (probe_words + build_words + _COMPACT_EXTRA_WORDS
+                          + rider_words)
                  * _GATHER_S_PER_WORD_LANE)
     return cap if compact_s <= wide_s else None
 

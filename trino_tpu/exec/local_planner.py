@@ -23,6 +23,7 @@ from ..planner import plan as P
 from ..spi.batch import Column, ColumnBatch
 from ..spi.types import Type
 from ..sql.ir import InputRef
+from . import join_exec as JX
 from .dynamic_filter import DynamicFilterHolder
 from .revoking import TaskMemoryContext
 from .operators import (
@@ -144,7 +145,7 @@ class LocalPlanner:
                     "INNER", "LEFT", "SINGLE") and op.left_keys:
                 return LookupJoinOperator(
                     op.bridge, op.left_keys, op.join_type, op.residual,
-                    op.output_names, op.output_types, op.place)
+                    op.output_names, op.output_types, op.place, op.riders)
             if isinstance(op, SemiJoinOperator) and op.source_keys:
                 return SemiJoinOperator(
                     op.bridge, op.source_keys, op.null_aware, op.residual,
@@ -324,6 +325,8 @@ class LocalPlanner:
             chain = self._chain(node.left)
             if scan_attach and isinstance(chain[0], ScanOperator):
                 chain[0].dynamic_filters.extend(scan_attach)
+            if node.left_keys:
+                _add_riders(chain, _probe_words(node))
             chain.append(LookupJoinOperator(
                 bridge, node.left_keys, node.join_type, node.residual,
                 node.output_names, node.output_types,
@@ -438,35 +441,61 @@ class LocalPlanner:
         raise NotImplementedError(f"no operator for {type(node).__name__}")
 
 
+def _trace_down(node: P.PlanNode, ch: int) -> tuple[P.PlanNode, int]:
+    """Follow an output channel down the probe-side left spine while it
+    passes through unchanged: the node and channel where it stops (a scan,
+    a computed value, a join's build side, a remote or union boundary)."""
+    while True:
+        if isinstance(node, (P.Filter, P.Exchange)):
+            node = node.source
+        elif (isinstance(node, P.Project)
+              and isinstance(node.expressions[ch], InputRef)):
+            node, ch = node.source, node.expressions[ch].index
+        elif (isinstance(node, P.Join)
+              and ch < len(node.left.output_types)):
+            node = node.left
+        elif (isinstance(node, P.SemiJoin)
+              and ch < len(node.source.output_types)):
+            node = node.source
+        else:
+            return node, ch
+
+
 def _trace_to_scan_col(node: P.PlanNode, ch: int) -> Optional[int]:
     """Map an output channel down the probe-side left spine to a TableScan
     column index, or None if the channel is computed / crosses a remote or
     union boundary.  Descends only paths whose rows pass through unchanged
     (a dropped probe row cannot change other rows' results)."""
-    while True:
-        if isinstance(node, P.TableScan):
-            return ch
-        if isinstance(node, (P.Filter, P.Exchange)):
-            node = node.source
-            continue
-        if isinstance(node, P.Project):
-            e = node.expressions[ch]
-            if isinstance(e, InputRef):
-                node, ch = node.source, e.index
-                continue
-            return None
-        if isinstance(node, P.Join):
-            lw = len(node.left.output_types)
-            if ch < lw:
-                node = node.left
-                continue
-            return None
-        if isinstance(node, P.SemiJoin):
-            if ch < len(node.source.output_types):
-                node = node.source
-                continue
-            return None
-        return None
+    node, ch = _trace_down(node, ch)
+    return ch if isinstance(node, P.TableScan) else None
+
+
+def _probe_words(node: P.Join) -> int:
+    """The 32-bit words a lane that the probe of ``node`` reads whatever
+    width it gathers at, counted from the plan's types as
+    join_exec.gather_words counts columns: its key columns (a validity mask
+    on a key out of a join's build side, whose output carries the match
+    mask) and one read of its join table.  Its build columns are left out:
+    a probe that compacts gathers them over its own cap, not over the lanes
+    it is handed, and the plan cannot tell which probes will."""
+    def words(ch: int) -> int:
+        stop, _ = _trace_down(node.left, ch)
+        return JX.column_words(node.left.output_types[ch].storage_dtype,
+                               isinstance(stop, P.Join))
+
+    return sum(words(ch) for ch in node.left_keys) + 1
+
+
+def _add_riders(chain: list[Operator], words: int) -> None:
+    """A probe appended to ``chain`` rides the output width of the probes
+    before it: its ``words`` join their riders, through the filters and
+    projections between (which mask lanes, never drop them), up to the
+    first operator of another kind."""
+    for op in reversed(chain):
+        if isinstance(op, LookupJoinOperator) and op.left_keys:
+            op.riders += words
+        elif not isinstance(op, FilterProjectOperator):
+            return
 
 
 def _values_batch(node: P.Values) -> ColumnBatch:

@@ -2399,7 +2399,7 @@ class LookupJoinOperator(Operator):
     def __init__(self, bridge: JoinBridge, left_keys: Sequence[int],
                  join_type: str, residual: Optional[RowExpression],
                  output_names: Sequence[str], output_types: Sequence[Type],
-                 place: int = 0):
+                 place: int = 0, riders: int = 0):
         self.bridge = bridge
         self.left_keys = list(left_keys)
         self.join_type = join_type
@@ -2409,6 +2409,10 @@ class LookupJoinOperator(Operator):
         # how many probes come before this one in its pipeline (the
         # recorder's ``join-probe`` event says which join a page went to)
         self.place = place
+        # the 32-bit words a lane that the later probes of its pipeline read
+        # over this one's output width (local_planner counts them from the
+        # plan's types): plan_unique_cap weighs them with its own gather
+        self.riders = riders
         from collections import deque
 
         from . import join_exec as JX
@@ -2662,14 +2666,18 @@ class LookupJoinOperator(Operator):
             self._uplanner.observe(est)
         else:
             self._uplanner.observe_async(cnt_a)
-        cap = JX.plan_unique_cap(
-            lanes, est * JX.EST_HEADROOM, JX.gather_words(probe_cols),
-            JX.gather_words(build_cols))
-        SG.count_unique_gather(compact=cap is not None,
-                               seeded=origin == "seed")
+        sizing = (lanes, est * JX.EST_HEADROOM, JX.gather_words(probe_cols),
+                  JX.gather_words(build_cols))
+        cap = JX.plan_unique_cap(*sizing, self.riders)
+        SG.count_unique_gather(
+            compact=cap is not None, seeded=origin == "seed",
+            # compacted for the riders: its own gather alone stays wide
+            riders=(cap is not None and self.riders > 0
+                    and JX.plan_unique_cap(*sizing) is None))
         # cap == lanes: the wide leg
         self.trace_attrs = {"cap": lanes if cap is None else cap,
-                            "lanes": lanes, "estimate": origin}
+                            "lanes": lanes, "estimate": origin,
+                            "riders": self.riders}
         # the wide leg gathers the build columns, the compact leg the probe
         # columns too
         gather = ((lanes, JX.lane_bytes(build_cols)) if cap is None else

@@ -72,7 +72,10 @@ class SyncStats:
     probe's gathers (join_exec.run_unique_gather) by the width they ran at:
     the probe batch's lanes, or a cap sized from a match count (an overflow's
     wide re-run counts as wide); ``unique_gather_seeded`` the subset whose
-    estimate was the seed an earlier execution left.
+    estimate was the seed an earlier execution left, and
+    ``unique_gather_compact_riders`` the compact ones that the later probes
+    riding the output's width decided (the probe's own gather alone would
+    have stayed wide).
     ``exchange_pages_device``/``exchange_pages_densified`` count the pages an
     exchange sink (execution/task.PartitionedOutputSink) handed on as they
     were -- on the device, bucket-shaped, masked -- and those it pulled to
@@ -88,6 +91,7 @@ class SyncStats:
     unique_gather_wide: int = 0
     unique_gather_compact: int = 0
     unique_gather_seeded: int = 0
+    unique_gather_compact_riders: int = 0
     exchange_pages_device: int = 0
     exchange_pages_densified: int = 0
     exchange_densified_bytes: int = 0
@@ -112,7 +116,8 @@ class SyncStats:
             f"expand overflow {self.expand_overflows}/"
             f"retry {self.expand_retries}, unique gathers "
             f"{self.unique_gather_wide} wide/{self.unique_gather_compact} "
-            f"compact ({self.unique_gather_seeded} seeded), exchange pages "
+            f"compact ({self.unique_gather_seeded} seeded, "
+            f"{self.unique_gather_compact_riders} for riders), exchange pages "
             f"{self.exchange_pages_device} device/"
             f"{self.exchange_pages_densified} densified "
             f"({self.exchange_densified_bytes} B)"
@@ -190,7 +195,8 @@ def count_overflow(retried: bool = True) -> None:
             _STATS.expand_retries += 1
 
 
-def count_unique_gather(compact: bool, seeded: bool) -> None:
+def count_unique_gather(compact: bool, seeded: bool,
+                        riders: bool = False) -> None:
     with _LOCK:
         if compact:
             _STATS.unique_gather_compact += 1
@@ -198,6 +204,8 @@ def count_unique_gather(compact: bool, seeded: bool) -> None:
             _STATS.unique_gather_wide += 1
         if seeded:
             _STATS.unique_gather_seeded += 1
+        if riders:
+            _STATS.unique_gather_compact_riders += 1
 
 
 def count_exchange_page(device: bool, nbytes: int) -> None:

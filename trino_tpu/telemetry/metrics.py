@@ -393,6 +393,10 @@ LIKE_DICTIONARY_ENTRIES = REGISTRY.counter(
 UNIQUE_GATHER_SEEDED = REGISTRY.counter(
     "trino_exec_unique_gather_seeded_total",
     "unique-build probe gathers sized from an earlier execution's seed")
+UNIQUE_GATHER_COMPACT_RIDERS = REGISTRY.counter(
+    "trino_exec_unique_gather_compact_riders_total",
+    "unique-build probe gathers compacted for the later probes riding "
+    "their width, where their own gather alone stays wide")
 
 # resilience (retry_policy=QUERY loop, heartbeats, exchange backoff)
 RES_QUERY_RETRIES = REGISTRY.counter(
@@ -826,6 +830,8 @@ def observe_sync(sync) -> None:
         UNIQUE_GATHER_COMPACT.inc(sync.unique_gather_compact)
     if sync.unique_gather_seeded:
         UNIQUE_GATHER_SEEDED.inc(sync.unique_gather_seeded)
+    if sync.unique_gather_compact_riders:
+        UNIQUE_GATHER_COMPACT_RIDERS.inc(sync.unique_gather_compact_riders)
 
 
 def observe_resilience(res) -> None:

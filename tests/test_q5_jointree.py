@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 from trino_tpu.caching import result_cache
+from trino_tpu.exec import operators as O
 from trino_tpu.exec import syncguard as SG
 from trino_tpu.execution.distributed_runner import DistributedQueryRunner
 from trino_tpu.runner import Session
@@ -124,10 +125,11 @@ def test_q5_trace_has_two_hashed_joins_and_three_dense_and_no_sync(
             (a["lanes"], a["gather_lanes"]))
     assert sorted(paths) == [0, 1, 2, 3, 4]
     # every place gathers as it would with no riders: the probes behind
-    # place 3 read too little a lane to make it compact, and the last one
-    # narrows on its own
-    assert widths == {j: {(BATCH_ROWS, BATCH_ROWS)} for j in range(4)} | {
-        4: {(BATCH_ROWS, 256)}}
+    # place 3 read too little a lane to make it compact, and the last one,
+    # customer's, emits both its build columns from the page's own keys:
+    # with nothing left to gather it stays wide (it narrowed to 256 lanes
+    # while it gathered them: test_q5_probes_take_their_build_keys_...)
+    assert widths == {j: {(BATCH_ROWS, BATCH_ROWS)} for j in range(5)}
     # the name is from when two of the five places took the hashed join:
     # orders' year and the two-column customer key are dense too now
     kinds = Counter(p for v in paths.values() for p in v)
@@ -149,6 +151,63 @@ def test_q5_trace_has_two_hashed_joins_and_three_dense_and_no_sync(
     assert tm.JOIN_PROBES_DENSE.value() - probes_before["dense"] \
         == 2 * n["dense"]
     assert tm.JOIN_PROBES_HASHED.value() - probes_before["hashed"] == 0
+
+
+def _probe_pages(runner, query_id):
+    """Run Q5 once: ({place: [join-probe attributes a page]}, SyncGuard
+    delta)."""
+    before = SG.snapshot()
+    runner.execute(Q5, query_id=query_id)
+    d = SG.take_delta(before)
+    pages = {}
+    for e in _of(profiler.events_for(query_id), profiler.JOIN_PROBE):
+        pages.setdefault(e["args"]["join"], []).append(e["args"])
+    return pages, d
+
+
+def test_q5_probes_take_their_build_keys_from_the_page(pinned, monkeypatch):
+    """Every place emits its build's key columns as the page's own keys —
+    two at customer's, the last place of the plan this size takes without
+    history — so each page's gather moves 8 bytes a lane less a key than
+    gathering every build column does, with no host sync added; the answer
+    stays exact (above).  Places 0-3 run at the width they would if they
+    gathered every build column.  The last one gathered customer's two
+    columns, 4 words a lane: 65,536 x 4 x 8.3 ns = 2.2 ms wide against
+    65,536 x 3.5 ns + 256 x (35 + 4 + 3) x 8.3 ns = 0.32 ms compact, so it
+    narrowed to 256 lanes; with nothing left to gather it stays wide and
+    launches no gather at all."""
+    monkeypatch.setattr(SG, "_is_ready", lambda x: True)
+    runner = _runner(pinned)
+    for _ in range(2):      # the estimate seeds settle: later runs alike
+        runner.execute(Q5)
+    taken, d = _probe_pages(runner, "q5-keys")
+    with monkeypatch.context() as m:
+        m.setattr(O, "_build_keys_from_probe",
+                  lambda probe, build, *_: (None,) * build.num_columns)
+        gathered, d_all = _probe_pages(runner, "q5-keys-gathered")
+    assert {j: {a["keys_from_probe"] for a in pages}
+            for j, pages in taken.items()} == {
+        0: {1}, 1: {1}, 2: {1}, 3: {1}, 4: {2}}
+    assert {a["keys_from_probe"] for pages in gathered.values()
+            for a in pages} == {0}
+
+    def widths(pages):
+        return Counter((a["lanes"], a["gather_lanes"]) for a in pages)
+
+    for j in range(4):
+        assert widths(taken[j]) == widths(gathered[j])
+        for a, b in zip(taken[j], gathered[j]):
+            assert b["gather_bytes"] - a["gather_bytes"] \
+                == 8 * a["keys_from_probe"]
+    assert widths(gathered[4]) == {(BATCH_ROWS, 256): len(gathered[4])}
+    assert widths(taken[4]) == {(BATCH_ROWS, BATCH_ROWS): len(taken[4])}
+    assert {a["gather_bytes"] for a in taken[4]} == {0}
+    assert d.unique_gather_keys_from_probe == sum(
+        a["keys_from_probe"] for pages in taken.values() for a in pages)
+    assert d_all.unique_gather_keys_from_probe == 0
+    # no host sync added
+    assert d.host_syncs == d_all.host_syncs
+    assert d.by_tag == d_all.by_tag
 
 
 # ---------------------------------------------- the hashed join, by case

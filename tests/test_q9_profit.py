@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 from trino_tpu.caching import result_cache
+from trino_tpu.exec import operators as O
 from trino_tpu.exec import syncguard as SG
 from trino_tpu.exec.operators import FilterProjectOperator
 from trino_tpu.execution.distributed_runner import DistributedQueryRunner
@@ -182,6 +183,62 @@ def test_q9_part_probe_narrows_the_page_for_the_probes_behind_it(pinned):
             riders.setdefault(a["lanes"] == BATCH_ROWS, set()).add(
                 a["riders"])
     assert min(riders[True]) > 0 and 0 in riders[False]
+
+
+def _probe_pages(runner, sql, query_id):
+    """Run ``sql`` once: ({place: [join-probe attributes a page]},
+    SyncGuard delta)."""
+    before = SG.snapshot()
+    runner.execute(sql, query_id=query_id)
+    d = SG.take_delta(before)
+    pages = {}
+    for e in _of(profiler.events_for(query_id), profiler.JOIN_PROBE):
+        pages.setdefault(e["args"]["join"], []).append(e["args"])
+    return pages, d
+
+
+def _gathering_every_build_column(monkeypatch):
+    """The probe as it was: every build column gathered, its keys too."""
+    monkeypatch.setattr(O, "_build_keys_from_probe",
+                        lambda probe, build, *_: (None,) * build.num_columns)
+
+
+def test_q9_probes_take_their_build_keys_from_the_page(pinned, monkeypatch):
+    """Every place emits its build's key columns as the page's own keys
+    (s_suppkey; p_partkey; n_nationkey; ps_partkey and ps_suppkey;
+    o_orderkey): each page's gather moves 8 bytes a lane less a key than
+    gathering every build column does, at the width that probe takes, and
+    with no host sync added; the answer stays exact (above)."""
+    monkeypatch.setattr(SG, "_is_ready", lambda x: True)
+    runner = _runner(pinned)
+    for _ in range(2):      # the estimate seeds settle: later runs alike
+        runner.execute(Q9)
+    taken, d = _probe_pages(runner, Q9, "q9-keys")
+    with monkeypatch.context() as m:
+        _gathering_every_build_column(m)
+        gathered, d_all = _probe_pages(runner, Q9, "q9-keys-gathered")
+    assert {j: {a["keys_from_probe"] for a in pages}
+            for j, pages in taken.items()} == {
+        0: {1}, 1: {1}, 2: {1}, 3: {2}, 4: {1}}
+    assert {a["keys_from_probe"] for pages in gathered.values()
+            for a in pages} == {0}
+    for j in taken:
+        # the same width at every place: part still compacts its page
+        widths = [(a["lanes"], a["gather_lanes"]) for a in taken[j]]
+        assert Counter(widths) == Counter(
+            (a["lanes"], a["gather_lanes"]) for a in gathered[j])
+        for a, b in zip(sorted(taken[j], key=lambda a: a["gather_lanes"]),
+                        sorted(gathered[j], key=lambda a: a["gather_lanes"])):
+            assert b["gather_bytes"] - a["gather_bytes"] \
+                == 8 * a["keys_from_probe"]
+    assert all(g < lanes for lanes, g in (
+        (a["lanes"], a["gather_lanes"]) for a in taken[1]))
+    assert d.unique_gather_keys_from_probe == sum(
+        a["keys_from_probe"] for pages in taken.values() for a in pages)
+    assert d_all.unique_gather_keys_from_probe == 0
+    # no host sync added
+    assert (d.host_syncs, d.async_polls, d.by_tag) == \
+        (d_all.host_syncs, d_all.async_polls, d_all.by_tag)
 
 
 def test_like_match_moves_once_per_lowering(pinned, monkeypatch):

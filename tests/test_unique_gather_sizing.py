@@ -92,8 +92,11 @@ CASES = [
     ("seeded", 40, 40, 256, "seed", 0, 1, 1, 0),
     # (c) a seed too small overflows: one counted wide re-run, exact rows
     ("seed-too-small", 4, 40, 16, "seed", 1, 1, 1, 1),
-    # (d) a seed far too large only pads
-    ("seed-too-large", 400, 40, 1024, "seed", 0, 1, 1, 0),
+    # (d) a seed far too large only pads, up to where the compact leg costs
+    # more than the wide one: at a cap of 1024 it would gather 4 probe
+    # words + 2 build words + 3 over 1024 lanes, against `bv`'s 2 words
+    # over 4096 (the build's key `bk` is the probe's, never gathered)
+    ("seed-too-large", 400, 40, LANES, "seed", 1, 0, 1, 0),
     # (f) a dense probe stays wide, from its own count or from a seed
     ("dense", None, LANES, LANES, "count", 1, 0, 0, 0),
     ("dense-seeded", LANES, LANES, LANES, "seed", 1, 0, 1, 0),

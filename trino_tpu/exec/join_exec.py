@@ -43,7 +43,7 @@ from . import syncguard as SG
 __all__ = ["DeviceJoinTable", "JoinHashTable", "build_table",
            "probe_ranges_device", "run_pairs", "run_unique",
            "ExpandPlanner", "OverflowQueue", "plan_unique_cap",
-           "unique_cap_bucket", "key_input"]
+           "unique_cap_bucket", "key_input", "with_probe_keys"]
 
 _SENT_BUILD = 0xFFFFFFFFFFFFFFFF  # build rows with NULL keys / dead rows
 _SENT_PROBE = 0xFFFFFFFFFFFFFFFE  # probe rows with NULL keys
@@ -1118,13 +1118,32 @@ def _live_lanes_first(ok_live, cap: int):
     return jnp.where(key >= n, key - n, key)
 
 
+def with_probe_keys(gathered, from_probe: Optional[tuple], probe_cols):
+    """The build columns in build order: the ``gathered`` ones, and in the
+    place of each build key that a unique probe takes from its own page
+    (``from_probe[i]``: its probe column, None where gathered) that probe
+    column, ``probe_cols[j]``.  On a matched lane a build key equals the
+    probe key it matched — the direct-address table addresses the whole
+    key, the hashed probe verifies it key by key — so the page already
+    holds the value the gather would fetch."""
+    if from_probe is None:
+        return list(gathered)
+    it = iter(gathered)
+    return [next(it) if j is None else probe_cols[j] for j in from_probe]
+
+
 def _make_ugather_fn(cap: Optional[int], pair_types, pair_dicts,
                      n_probe_cols: int, n_build_cols: int,
                      pcol_has_valid: tuple, bcol_has_valid: tuple,
                      residual: Optional[RowExpression],
-                     need_build_matched: bool):
+                     need_build_matched: bool,
+                     from_probe: Optional[tuple] = None,
+                     build_rows: int = 0):
     """Program B.  ``cap`` None = wide (lanes = probe width, probe columns
-    pass through untouched); otherwise compact to ``cap`` lanes first."""
+    pass through untouched); otherwise compact to ``cap`` lanes first.  It
+    gathers the ``n_build_cols`` build columns it is handed; the build keys
+    taken from the probe (``from_probe``) reach the residual as their probe
+    columns, and the caller emits them (with_probe_keys)."""
     res_fn = (compile_expression(residual, list(pair_types), list(pair_dicts))
               if residual is not None else None)
 
@@ -1165,18 +1184,15 @@ def _make_ugather_fn(cap: Optional[int], pair_types, pair_dicts,
         b_out = [(d[bid_c], None if v is None else v[bid_c])
                  for d, v in bcols]
         if res_fn is not None:
-            rd, rv = res_fn(p_out + b_out)
+            rd, rv = res_fn(p_out + with_probe_keys(b_out, from_probe, p_out))
             rmask = rd if rv is None else (rd & rv)
             if getattr(rmask, "ndim", 1) == 0:
                 rmask = jnp.broadcast_to(rmask, ok_c.shape)
             ok_c = ok_c & rmask
         build_matched = None
         if need_build_matched:
-            nb = 0
-            for d, _ in bcols:
-                nb = d.shape[0]
-                break
-            build_matched = jnp.zeros((nb,), jnp.bool_).at[bid_c].max(ok_c)
+            build_matched = jnp.zeros(
+                (build_rows,), jnp.bool_).at[bid_c].max(ok_c)
         b_out = [(d, (ok_c if v is None else (v & ok_c)))
                  for d, v in b_out]
         return tuple(p_out), tuple(b_out), ok_c, build_matched, overflow
@@ -1251,24 +1267,34 @@ def run_unique_gather(table: DeviceJoinTable, ok_live, bid,
                       cap: Optional[int],
                       probe_cols, build_cols, pair_types, pair_dicts,
                       residual: Optional[RowExpression],
-                      need_build_matched: bool):
+                      need_build_matched: bool,
+                      from_probe: Optional[tuple] = None):
     """Program B dispatch at a planner-chosen ``cap`` (None = wide).
+    ``build_cols`` are the build columns to gather: with ``from_probe``
+    (one entry a build column, see with_probe_keys) the others are build
+    keys the caller takes from the probe.
     Returns (probe_out|None, build_out, live, build_matched, overflow) —
     probe_out is None on the wide path (original columns pass through);
-    ``overflow`` is a device bool on the compact path (True = cap truncated
-    matches, caller must re-run wide or bigger) and None on the wide path,
-    which cannot overflow."""
+    build_out holds the gathered columns only; ``overflow`` is a device
+    bool on the compact path (True = cap truncated matches, caller must
+    re-run wide or bigger) and None on the wide path, which cannot
+    overflow."""
     if cap is None and residual is None:
+        if not build_cols and not need_build_matched:
+            # nothing to gather and nothing to mask: no program at all
+            return None, (), ok_live, None, None
         # wide + residual-free: probe columns pass through OUTSIDE the
         # program (feeding them through a jit identity would copy them)
         probe_cols = []
     pcol_has_valid = tuple(v is not None for _, v in probe_cols)
     bcol_has_valid = tuple(v is not None for _, v in build_cols)
+    build_rows = table.num_rows if need_build_matched else 0
     with _PAIR_LOCK:
         key = ("ugather", cap, tuple(str(t) for t in pair_types),
                tuple(_dict_token(d) for d in pair_dicts),
                len(probe_cols), len(build_cols), pcol_has_valid,
-               bcol_has_valid, residual, need_build_matched)
+               bcol_has_valid, residual, need_build_matched, from_probe,
+               build_rows)
         prog = _PAIR_CACHE.pop(key, None)
         if prog is not None:
             _PAIR_CACHE[key] = prog
@@ -1276,7 +1302,8 @@ def run_unique_gather(table: DeviceJoinTable, ok_live, bid,
         prog = _make_ugather_fn(cap, list(pair_types), list(pair_dicts),
                                 len(probe_cols), len(build_cols),
                                 pcol_has_valid, bcol_has_valid,
-                                residual, need_build_matched)
+                                residual, need_build_matched, from_probe,
+                                build_rows)
         with _PAIR_LOCK:
             prog = _PAIR_CACHE.setdefault(key, prog)
             while len(_PAIR_CACHE) > _PAIR_CACHE_MAX:
@@ -1304,7 +1331,8 @@ def _make_unique_fn(num_keys: int, has_pvalid: tuple, has_remap: tuple,
                     residual: Optional[RowExpression],
                     need_build_matched: bool, semi: Optional[tuple],
                     has_live: bool,
-                    dense: Optional[tuple] = None):
+                    dense: Optional[tuple] = None,
+                    from_probe: Optional[tuple] = None):
     """Probe program for builds whose live hashes are all distinct (every
     FK->PK join): each probe row matches at most one build row, so the
     output keeps the PROBE batch's static shape — probe columns pass
@@ -1315,8 +1343,10 @@ def _make_unique_fn(num_keys: int, has_pvalid: tuple, has_remap: tuple,
     here variable cardinality is impossible by construction).
 
     Flat operand order: per probe key: data [remap] [valid];
-    per probe col: data [valid]; per build col: data [valid];
-    build key datas; [live]."""
+    per probe col: data [valid]; per build col gathered: data [valid];
+    build key datas; [live].  The build keys taken from the probe
+    (``from_probe``) reach the residual as their probe columns, and the
+    caller emits them (with_probe_keys)."""
     res_fn = (compile_expression(residual, list(pair_types), list(pair_dicts))
               if residual is not None else None)
 
@@ -1392,7 +1422,8 @@ def _make_unique_fn(num_keys: int, has_pvalid: tuple, has_remap: tuple,
 
         bgather = [(d[bid], None if v is None else v[bid]) for d, v in bcols]
         if res_fn is not None:
-            rd, rv = res_fn(list(pcols) + bgather)
+            rd, rv = res_fn(list(pcols)
+                            + with_probe_keys(bgather, from_probe, pcols))
             rmask = rd if rv is None else (rd & rv)
             if getattr(rmask, "ndim", 1) == 0:
                 rmask = jnp.broadcast_to(rmask, ok.shape)
@@ -1429,11 +1460,13 @@ def run_unique(table: DeviceJoinTable, probe_keys, remaps,
                probe_cols, build_cols, pair_types, pair_dicts,
                residual: Optional[RowExpression],
                need_build_matched: bool, semi: Optional[tuple] = None,
-               live=None):
+               live=None, from_probe: Optional[tuple] = None):
     """Execute the unique-build probe.  Returns (build_out, ok_live,
     build_matched|None, mark|None) — all device, ZERO host syncs.
     ``build_out`` is [(data, valid)] over build cols gathered per probe row
-    (valid already folds the match mask, so unmatched rows read NULL);
+    (valid already folds the match mask, so unmatched rows read NULL):
+    ``build_cols``, which with ``from_probe`` leave out the build keys the
+    caller takes from the probe (with_probe_keys);
     ``ok_live`` is the per-probe match mask & live."""
     has_pvalid = tuple(v is not None for _, v in probe_keys)
     has_remap = tuple(r is not None for r in remaps)
@@ -1448,7 +1481,7 @@ def run_unique(table: DeviceJoinTable, probe_keys, remaps,
                tuple(_dict_token(d) for d in pair_dicts),
                len(probe_cols), len(build_cols), pcol_has_valid,
                bcol_has_valid, residual, need_build_matched, semi,
-               live is not None, dense)
+               live is not None, dense, from_probe)
         prog = _PAIR_CACHE.pop(key, None)
         if prog is not None:
             _PAIR_CACHE[key] = prog
@@ -1458,7 +1491,7 @@ def run_unique(table: DeviceJoinTable, probe_keys, remaps,
                                len(probe_cols), len(build_cols),
                                pcol_has_valid, bcol_has_valid,
                                residual, need_build_matched, semi,
-                               live is not None, dense)
+                               live is not None, dense, from_probe)
         with _PAIR_LOCK:
             prog = _PAIR_CACHE.setdefault(key, prog)
             while len(_PAIR_CACHE) > _PAIR_CACHE_MAX:

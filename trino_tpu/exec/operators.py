@@ -2220,6 +2220,7 @@ class JoinBridge:
     def __init__(self):
         self.table = None  # join_exec.DeviceJoinTable
         self.batch: Optional[ColumnBatch] = None
+        self.key_channels: list[int] = []  # the build's, in key order
         self.key_dicts: list[Optional[np.ndarray]] = []
         self._dense: Optional[ColumnBatch] = None
 
@@ -2252,6 +2253,27 @@ def _probe_key_remap(col: Column, build_dict: Optional[np.ndarray]):
     clipped = np.clip(pos, 0, len(build_dict) - 1)
     ok = build_dict[clipped] == pdict
     return np.where(ok, clipped, -1).astype(np.int32)
+
+
+def _build_keys_from_probe(probe: ColumnBatch, build: ColumnBatch,
+                           probe_keys: Sequence[int],
+                           build_keys: Sequence[int], remaps) -> tuple:
+    """For each build column, the probe channel a unique-build probe emits
+    in its place instead of gathering it, or None to gather it.  A build
+    key column qualifies where its probe key holds the same bits on every
+    matched lane: the same type and storage dtype, an integer, boolean or
+    date-like storage (on floating point 0.0 matches -0.0), no dictionary
+    on either side and no remap, and a flat probe column (an RLE key is
+    expanded on the device for the probe, never held as a page column)."""
+    src: list = [None] * build.num_columns
+    for lch, bch, remap in zip(probe_keys, build_keys, remaps):
+        p, b = probe.columns[lch], build.columns[bch]
+        if (src[bch] is None and remap is None and p.dictionary is None
+                and b.dictionary is None and p.encoding != "RLE"
+                and p.type == b.type and p.data.dtype == b.data.dtype
+                and np.dtype(p.data.dtype).kind in "iub"):
+            src[bch] = lch
+    return tuple(src)
 
 
 class JoinBuildSink(BufferedInputMixin, Operator):
@@ -2299,6 +2321,7 @@ class JoinBuildSink(BufferedInputMixin, Operator):
                 c = batch.columns[self.key_channels[k]]
                 holder.fill_device(c.data, c.valid, live, c.dictionary)
         self.bridge.batch = batch
+        self.bridge.key_channels = list(self.key_channels)
         self.bridge.key_dicts = [
             batch.columns[ch].dictionary for ch in self.key_channels]
         table = self.bridge.table = JX.build_table(
@@ -2513,7 +2536,8 @@ class LookupJoinOperator(Operator):
         profiler.event(profiler.JOIN_PROBE, "LookupJoinOperator", t0,
                        join=self.place, path=path, keys=len(keys),
                        lanes=probe.num_rows, key_bytes=JX.lane_bytes(keys),
-                       gather_lanes=gather[0], gather_bytes=gather[1])
+                       gather_lanes=gather[0], gather_bytes=gather[1],
+                       keys_from_probe=gather[2])
 
     def _null_extended(self, probe: ColumnBatch, build: ColumnBatch,
                        un_live) -> ColumnBatch:
@@ -2535,19 +2559,20 @@ class LookupJoinOperator(Operator):
         expand.  The expand bucket comes from build-side statistics
         (ExpandPlanner) and overflow checks are deferred to async flag
         polls, so the steady state never blocks on the candidate total.
-        Returns the gather's (lanes, bytes a lane) as launched."""
+        Returns the gather's (lanes, bytes a lane, build keys taken from the
+        probe: none here) as launched."""
         from . import join_exec as JX
 
         need_matched = self.join_type in ("LEFT", "SINGLE", "FULL")
         if self.join_type in ("RIGHT", "FULL"):
             self._probe_dicts = [c.dictionary for c in probe.columns]
         if probe.num_rows == 0:
-            return 0, 0
+            return 0, 0, 0
         if table.num_rows == 0:  # empty build: no pairs, all probes unmatched
             if need_matched:
                 self._pending.append(
                     self._null_extended(probe, build, probe.live))
-            return 0, 0
+            return 0, 0, 0
         probe_cols = [(c.data, c.valid) for c in probe.columns]
         build_cols = [(c.data, c.valid) for c in build.columns]
         pair_types = ([c.type for c in probe.columns]
@@ -2587,7 +2612,8 @@ class LookupJoinOperator(Operator):
                 table, keys, remaps, probe.live)
             cap, provable = self._planner.plan(probe.num_rows, table.max_run)
             self._planner.observe_async(total_a)
-            gather = cap, JX.lane_bytes(probe_cols) + JX.lane_bytes(build_cols)
+            gather = (cap, JX.lane_bytes(probe_cols)
+                      + JX.lane_bytes(build_cols), 0)
             res = JX.run_pairs(
                 table, lo, counts, total_a, keys, remaps, probe_cols,
                 build_cols, pair_types, pair_dicts, self.residual,
@@ -2618,18 +2644,24 @@ class LookupJoinOperator(Operator):
         count stay on device, and the gather's width is sized from a match
         count in every batch — an earlier batch's, the seed an earlier
         execution of the same plan shape left, or (neither known) this
-        batch's own, fetched once.  Returns the gather's (lanes, bytes a
-        lane) as launched."""
+        batch's own, fetched once.  The build keys the page holds already
+        are taken from it, not gathered (_build_keys_from_probe).  Returns
+        the gather's (lanes, bytes a lane, build keys taken from the probe)
+        as launched."""
         from . import join_exec as JX
 
         if probe.num_rows == 0:
-            return 0, 0
+            return 0, 0, 0
         ok_live, bid, cnt_a = JX.run_unique_ranges_device(
             table, keys, remaps, probe.live)
         if self.join_type == "RIGHT":
             self._probe_dicts = [c.dictionary for c in probe.columns]
         probe_cols = [(c.data, c.valid) for c in probe.columns]
-        build_cols = [(c.data, c.valid) for c in build.columns]
+        src = _build_keys_from_probe(probe, build, self.left_keys,
+                                     self.bridge.key_channels, remaps)
+        taken = sum(j is not None for j in src)
+        build_cols = [(c.data, c.valid)
+                      for c, j in zip(build.columns, src) if j is None]
         pair_types = ([c.type for c in probe.columns]
                       + [c.type for c in build.columns])
         pair_dicts = ([c.dictionary for c in probe.columns]
@@ -2649,8 +2681,14 @@ class LookupJoinOperator(Operator):
             else:
                 left_cols = [Column(c.type, d, v, c.dictionary)
                              for c, (d, v) in zip(probe.columns, p_out)]
+            # a key taken from the probe: its values as the page holds
+            # them, null wherever the lane did not match
+            from_page = {j: (left_cols[j].data, live)
+                         for j in src if j is not None}
             right_cols = [Column(c.type, d, v, c.dictionary)
-                          for c, (d, v) in zip(build.columns, b_out)]
+                          for c, (d, v) in zip(
+                              build.columns,
+                              JX.with_probe_keys(b_out, src, from_page))]
             self._pending.append(ColumnBatch(
                 self.output_names, left_cols + right_cols, live))
 
@@ -2674,18 +2712,20 @@ class LookupJoinOperator(Operator):
             # compacted for the riders: its own gather alone stays wide
             riders=(cap is not None and self.riders > 0
                     and JX.plan_unique_cap(*sizing) is None))
+        SG.count_keys_from_probe(taken)
         # cap == lanes: the wide leg
         self.trace_attrs = {"cap": lanes if cap is None else cap,
                             "lanes": lanes, "estimate": origin,
                             "riders": self.riders}
         # the wide leg gathers the build columns, the compact leg the probe
         # columns too
-        gather = ((lanes, JX.lane_bytes(build_cols)) if cap is None else
-                  (cap, JX.lane_bytes(probe_cols) + JX.lane_bytes(build_cols)))
+        gather = ((lanes, JX.lane_bytes(build_cols), taken) if cap is None
+                  else (cap, JX.lane_bytes(probe_cols)
+                        + JX.lane_bytes(build_cols), taken))
         with SG.hot_region():
             res = JX.run_unique_gather(
                 table, ok_live, bid, cap, probe_cols, build_cols,
-                pair_types, pair_dicts, self.residual, need_bm)
+                pair_types, pair_dicts, self.residual, need_bm, src)
             if cap is None or origin == "count":
                 # the wide path cannot overflow, nor can a cap sized from
                 # the batch's own count
@@ -2697,7 +2737,7 @@ class LookupJoinOperator(Operator):
                 SG.count_unique_gather(compact=False, seeded=False)
                 return JX.run_unique_gather(
                     table, ok_live, bid, None, probe_cols, build_cols,
-                    pair_types, pair_dicts, self.residual, need_bm)
+                    pair_types, pair_dicts, self.residual, need_bm, src)
 
             self._inflight.push(
                 SG.async_scalar(res[4], "join.unique-overflow"),
@@ -2720,15 +2760,21 @@ class LookupJoinOperator(Operator):
         output rides the probe batch's shape with the match mask as live.
         Covers every join type: LEFT/SINGLE/FULL keep unmatched probe rows
         as NULL-extended lanes of the same batch (no second batch), SINGLE
-        can never violate cardinality (<=1 match by construction).  Returns
-        the gather's (lanes, bytes a lane): the build columns over every
-        probe lane."""
+        can never violate cardinality (<=1 match by construction).  The
+        build keys the page holds already are taken from it, not gathered
+        (_build_keys_from_probe).  Returns the gather's (lanes, bytes a
+        lane, build keys taken from the probe): the gathered build columns
+        over every probe lane."""
         from . import join_exec as JX
 
         need_res_cols = self.residual is not None
         probe_cols = ([(c.data, c.valid) for c in probe.columns]
                       if need_res_cols else [])
-        build_cols = [(c.data, c.valid) for c in build.columns]
+        src = _build_keys_from_probe(probe, build, self.left_keys,
+                                     self.bridge.key_channels, remaps)
+        taken = sum(j is not None for j in src)
+        build_cols = [(c.data, c.valid)
+                      for c, j in zip(build.columns, src) if j is None]
         if need_res_cols:
             pair_types = ([c.type for c in probe.columns]
                           + [c.type for c in build.columns])
@@ -2741,7 +2787,8 @@ class LookupJoinOperator(Operator):
             bgather, ok_live, build_matched, _ = JX.run_unique(
                 table, keys, remaps, probe_cols, build_cols,
                 pair_types, pair_dicts, self.residual, need_bm,
-                live=probe.live)
+                live=probe.live, from_probe=src)
+        SG.count_keys_from_probe(taken)
         if need_bm:
             self._probe_dicts = [c.dictionary for c in probe.columns]
             if self._build_matched is None:
@@ -2749,8 +2796,12 @@ class LookupJoinOperator(Operator):
             else:
                 self._build_matched = (
                     jnp.asarray(self._build_matched) | build_matched)
+        from_page = {j: (probe.columns[j].data, ok_live)
+                     for j in src if j is not None}
         right_cols = [Column(c.type, d, v, c.dictionary)
-                      for c, (d, v) in zip(build.columns, bgather)]
+                      for c, (d, v) in zip(
+                          build.columns,
+                          JX.with_probe_keys(bgather, src, from_page))]
         if self.join_type in ("INNER", "RIGHT"):
             out_live = ok_live
         else:  # LEFT / SINGLE / FULL: unmatched probe rows stay live,
@@ -2758,7 +2809,7 @@ class LookupJoinOperator(Operator):
             out_live = probe.live
         self._pending.append(ColumnBatch(
             self.output_names, list(probe.columns) + right_cols, out_live))
-        return probe.num_rows, JX.lane_bytes(build_cols)
+        return probe.num_rows, JX.lane_bytes(build_cols), taken
 
     _dense_build: Optional[ColumnBatch] = None  # set by the cross path
 

@@ -75,7 +75,9 @@ class SyncStats:
     estimate was the seed an earlier execution left, and
     ``unique_gather_compact_riders`` the compact ones that the later probes
     riding the output's width decided (the probe's own gather alone would
-    have stayed wide).
+    have stayed wide); ``unique_gather_keys_from_probe`` the build key
+    columns those probes took from their own page instead of gathering
+    them (LookupJoinOperator: on a matched lane they equal the probe key).
     ``exchange_pages_device``/``exchange_pages_densified`` count the pages an
     exchange sink (execution/task.PartitionedOutputSink) handed on as they
     were -- on the device, bucket-shaped, masked -- and those it pulled to
@@ -92,6 +94,7 @@ class SyncStats:
     unique_gather_compact: int = 0
     unique_gather_seeded: int = 0
     unique_gather_compact_riders: int = 0
+    unique_gather_keys_from_probe: int = 0
     exchange_pages_device: int = 0
     exchange_pages_densified: int = 0
     exchange_densified_bytes: int = 0
@@ -117,7 +120,9 @@ class SyncStats:
             f"retry {self.expand_retries}, unique gathers "
             f"{self.unique_gather_wide} wide/{self.unique_gather_compact} "
             f"compact ({self.unique_gather_seeded} seeded, "
-            f"{self.unique_gather_compact_riders} for riders), exchange pages "
+            f"{self.unique_gather_compact_riders} for riders, "
+            f"{self.unique_gather_keys_from_probe} keys from the probe), "
+            f"exchange pages "
             f"{self.exchange_pages_device} device/"
             f"{self.exchange_pages_densified} densified "
             f"({self.exchange_densified_bytes} B)"
@@ -206,6 +211,12 @@ def count_unique_gather(compact: bool, seeded: bool,
             _STATS.unique_gather_seeded += 1
         if riders:
             _STATS.unique_gather_compact_riders += 1
+
+
+def count_keys_from_probe(n: int) -> None:
+    if n:
+        with _LOCK:
+            _STATS.unique_gather_keys_from_probe += n
 
 
 def count_exchange_page(device: bool, nbytes: int) -> None:

@@ -397,6 +397,10 @@ UNIQUE_GATHER_COMPACT_RIDERS = REGISTRY.counter(
     "trino_exec_unique_gather_compact_riders_total",
     "unique-build probe gathers compacted for the later probes riding "
     "their width, where their own gather alone stays wide")
+JOIN_BUILD_KEYS_FROM_PROBE = REGISTRY.counter(
+    "trino_join_build_keys_from_probe_total",
+    "build key columns a unique-build probe took from its own page "
+    "instead of gathering them")
 
 # resilience (retry_policy=QUERY loop, heartbeats, exchange backoff)
 RES_QUERY_RETRIES = REGISTRY.counter(
@@ -832,6 +836,8 @@ def observe_sync(sync) -> None:
         UNIQUE_GATHER_SEEDED.inc(sync.unique_gather_seeded)
     if sync.unique_gather_compact_riders:
         UNIQUE_GATHER_COMPACT_RIDERS.inc(sync.unique_gather_compact_riders)
+    if sync.unique_gather_keys_from_probe:
+        JOIN_BUILD_KEYS_FROM_PROBE.inc(sync.unique_gather_keys_from_probe)
 
 
 def observe_resilience(res) -> None:

@@ -134,7 +134,9 @@ JOIN_PROBE = "join-probe"    # one probe page of a LookupJoinOperator; join
 #                              (its place among the probes of its
 #                              pipeline), path, keys, lanes, key_bytes,
 #                              gather_lanes (the cap it ran at),
-#                              gather_bytes (a gathered lane's columns)
+#                              gather_bytes (a gathered lane's columns:
+#                              those the program moved), keys_from_probe
+#                              (build key columns taken from the page)
 LIKE_MATCH = "like-match"    # a LIKE pattern matched over a dictionary on
 #                              the host, once a lowering; entries, matched,
 #                              tokens, path (vector or re)

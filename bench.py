@@ -26,9 +26,7 @@ a fallback; harnesses that spawn worker or coordinator processes force those
 children to JAX_PLATFORMS=cpu (a chip belongs to one process) and say so in
 their output.
 
-Subcommands: ``--scan`` (ingest microbench), ``--ndv [1e3,1e4,...]``
-(TRINO_TPU_HASH_IMPL hash-vs-sort NDV-ladder bake-off, see run_ndv_bench),
-``--qps`` (two-tenant weighted-fair sustained-load harness + OOM drill,
+Subcommands: ``--qps`` (two-tenant weighted-fair sustained-load harness + OOM drill,
 see run_qps_bench; BENCH_QPS_DURATION/BENCH_QPS_SF/BENCH_QPS_CLIENTS),
 ``--warm`` (cache-plane cold/warm/warm-after-mutation ladder, see
 run_warm_bench; BENCH_WARM_SF/BENCH_WARM_REPS), ``--adaptive`` (adaptive
@@ -1440,193 +1438,6 @@ def run_baseline() -> None:
     print(json.dumps(times))
 
 
-def run_scan_bench() -> None:
-    """`bench.py --scan`: the scan-ingest microbench.  Drains TPC-H lineitem
-    through ScanOperator three ways and reports GB/s from the ScanIngestStats
-    counters, so the ingest trajectory is tracked per round independently of
-    the full-query bench:
-
-    - ``legacy``:   the pre-PR synchronous path — string-materializing decode
-                    (TRINO_TPU_TPCH_VECTOR_DECODE=0), no prefetch.  This is
-                    the acceptance baseline.
-    - ``sync``:     vectorized decode, synchronous scan (TRINO_TPU_PREFETCH=0).
-    - ``prefetch``: vectorized decode + async prefetch/coalesce/staging.
-
-    ``vs_baseline`` in the JSON is prefetch over legacy.  Note prefetch vs
-    sync (``vs_sync``) only wins wall-clock when decode can overlap with
-    something — on a single-core host with the now-cheap vectorized decode it
-    hovers near 1.0; the ingest win lives in the decode itself and in
-    transfer/compute overlap during real queries.
-
-    Env knobs: BENCH_SCAN_SF (default 0.2), BENCH_SCAN_SPLITS (default 8),
-    plus the TRINO_TPU_PREFETCH_* family."""
-    from trino_tpu.connectors.catalog import default_catalog
-    from trino_tpu.exec.operators import ScanOperator
-
-    sf = float(os.environ.get("BENCH_SCAN_SF", "0.2"))
-    n_splits = int(os.environ.get("BENCH_SCAN_SPLITS", "8"))
-
-    def drain(tpch) -> tuple[float, "object"]:
-        cols = tpch.get_table_schema("lineitem").column_names()
-        splits = tpch.get_splits("lineitem", n_splits, 1)
-        scan = ScanOperator(tpch, splits, cols)
-        t0 = time.perf_counter()
-        while not scan.is_finished():
-            if scan.get_output() is None:
-                break
-        return time.perf_counter() - t0, scan.ingest_stats
-
-    results = {}
-    for mode, prefetch, vector in (("legacy", "0", "0"), ("sync", "0", "1"),
-                                   ("prefetch", "1", "1")):
-        os.environ["TRINO_TPU_PREFETCH"] = prefetch
-        os.environ["TRINO_TPU_TPCH_VECTOR_DECODE"] = vector
-        # fresh connector per leg: the decode flag is read at construction
-        tpch = default_catalog(scale_factor=sf).connector("tpch")
-        drain(tpch)  # warmup: dictionaries + code tables + jit caches
-        wall, stats = drain(tpch)
-        gbps = stats.scan_bytes / wall / 1e9
-        results[mode] = (wall, gbps, stats)
-        print(f"scan[{mode}]: {stats.scan_bytes/1e6:.1f} MB in "
-              f"{wall*1e3:.1f} ms = {gbps:.2f} GB/s | {stats.text()}",
-              file=sys.stderr)
-    os.environ.pop("TRINO_TPU_TPCH_VECTOR_DECODE", None)
-
-    st = results["prefetch"][2]
-    print(json.dumps({
-        "metric": f"scan_ingest_sf{sf:g}_gb_per_sec",
-        "value": round(results["prefetch"][1], 3),
-        "unit": "GB/s",
-        "vs_baseline": round(results["prefetch"][1] / results["legacy"][1], 3),
-        "vs_sync": round(results["prefetch"][1] / results["sync"][1], 3),
-        "legacy_gb_per_sec": round(results["legacy"][1], 3),
-        "sync_gb_per_sec": round(results["sync"][1], 3),
-        "queue_depth_max": st.queue_depth_max,
-        "queue_depth_avg": round(st.queue_depth_avg, 2),
-        "coalesced_batches": st.coalesced_batches,
-        "source_read_ms": round(st.source_read_s * 1e3, 1),
-        "consumer_wait_ms": round(st.consumer_wait_s * 1e3, 1),
-        "stage_ms": round(st.stage_s * 1e3, 1),
-    }))
-
-
-def run_ndv_bench() -> None:
-    """`bench.py --ndv [1e3,1e4,...]`: the hash-vs-sort NDV-ladder bake-off
-    behind the ROADMAP "Pallas hash build/probe — or a measured waiver" item.
-
-    For each NDV rung, times the two hottest inner loops under every
-    TRINO_TPU_HASH_IMPL implementation:
-
-    - ``agg``:  group-id assignment + one segment-sum over int64 keys
-                (the HashAggregationOperator inner loop).
-    - ``join``: hash-table build + probe-ranges + total fetch
-                (the LookupJoin build/probe inner loop).
-
-    Implementations: ``sort`` (lexsort + searchsorted), ``pallas-interpret``
-    (the open-addressing kernels as pure XLA — runs anywhere, NOT a TPU
-    performance number), and ``pallas`` (compiled kernels — requires a real
-    TPU backend and recorded as ``"skipped"`` otherwise; on a v5e the compiler
-    refuses the kernels, README "Open-addressing hash build/probe").  Keys
-    are drawn from a SPARSE 62-bit domain so the sort leg cannot sneak onto
-    the dense direct-address join fast path.  Emits ONE JSON object with per-leg rows/s + GB/s.
-
-    Env knobs: BENCH_ITERS (default 3), BENCH_NDV_ROWS (default 1e6),
-    BENCH_NDV_INTERPRET_ROWS (default 2e5 — interpret mode executes the
-    probe loops sequentially and would dominate wall time at full width)."""
-    _device()
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from trino_tpu.exec import join_exec as JX
-    from trino_tpu.exec import kernels as K
-
-    arg = ""
-    i = sys.argv.index("--ndv")
-    if i + 1 < len(sys.argv) and not sys.argv[i + 1].startswith("-"):
-        arg = sys.argv[i + 1]
-    ndvs = ([int(float(x)) for x in arg.split(",") if x]
-            or [1_000, 10_000, 100_000, 1_000_000])
-
-    iters = int(os.environ.get("BENCH_ITERS", "3"))
-    full_rows = int(float(os.environ.get("BENCH_NDV_ROWS", "1e6")))
-    interp_rows = int(float(os.environ.get("BENCH_NDV_INTERPRET_ROWS",
-                                           "2e5")))
-    on_tpu = jax.default_backend() == "tpu"
-    impls = [
-        ("sort", {"TRINO_TPU_HASH_IMPL": "sort"}, False),
-        ("pallas-interpret",
-         {"TRINO_TPU_HASH_IMPL": "pallas", "TRINO_TPU_HASH_INTERPRET": "1"},
-         False),
-        ("pallas", {"TRINO_TPU_HASH_IMPL": "pallas"}, True),  # needs TPU
-    ]
-
-    def timed(fn) -> float:
-        fn()  # warmup: compile at this shape
-        samples = []
-        for _ in range(iters):
-            t0 = time.perf_counter()
-            fn()
-            samples.append(time.perf_counter() - t0)
-        samples.sort()
-        return samples[len(samples) // 2]
-
-    rng = np.random.default_rng(0)
-    legs: list[dict] = []
-    for ndv in ndvs:
-        domain = rng.integers(0, 1 << 62, size=ndv, dtype=np.int64)
-        for impl, env, needs_tpu in impls:
-            n = interp_rows if impl == "pallas-interpret" else full_rows
-            nb = max(n // 2, 1)
-            if needs_tpu and not on_tpu:
-                for leg in ("agg", "join"):
-                    legs.append({"leg": leg, "impl": impl, "ndv": ndv,
-                                 "status": "skipped",
-                                 "reason": "no TPU backend"})
-                continue
-            for k in ("TRINO_TPU_HASH_IMPL", "TRINO_TPU_HASH_INTERPRET"):
-                os.environ.pop(k, None)
-            os.environ.update(env)
-            jk = jnp.asarray(domain[rng.integers(0, ndv, size=n)])
-            jv = jnp.asarray(rng.standard_normal(n))
-            jbk = jnp.asarray(domain[rng.integers(0, ndv, size=nb)])
-            jax.block_until_ready((jk, jv, jbk))
-
-            def agg_leg():
-                perm, gid, ng = K.group_ids_auto([(jk, None)], None)
-                jax.block_until_ready(
-                    jax.ops.segment_sum(jv[perm], gid, ng + 1))
-
-            def join_leg():
-                t = JX.build_table([(jbk, None)], num_rows=nb)
-                _lo, _counts, total = JX.probe_ranges_device(
-                    t, [(jk, None)], [None])
-                total.get()
-
-            for leg, fn, nbytes in (
-                    ("agg", agg_leg, n * 16),
-                    ("join", join_leg, (n + nb) * 8)):
-                wall = timed(fn)
-                row = {"leg": leg, "impl": impl, "ndv": ndv, "rows": n,
-                       "wall_ms": round(wall * 1e3, 2),
-                       "rows_per_s": round(n / wall),
-                       "gb_per_s": round(nbytes / wall / 1e9, 3),
-                       "status": "ok"}
-                legs.append(row)
-                print(f"ndv[{ndv}] {leg}/{impl}: {row['wall_ms']} ms = "
-                      f"{row['rows_per_s']:,} rows/s", file=sys.stderr)
-    for k in ("TRINO_TPU_HASH_IMPL", "TRINO_TPU_HASH_INTERPRET"):
-        os.environ.pop(k, None)
-
-    print(json.dumps({
-        "metric": "hash_bakeoff_ndv",
-        "unit": "rows/s",
-        "backend": jax.default_backend(),
-        "iters": iters,
-        "legs": legs,
-    }))
-
-
 _JIT_COUNTER = {"on": False, "jit_calls": 0, "eager_binds": 0}
 _REGION_TLS = None  # threading.local; armed per-thread so one task's stage
 # region doesn't count another task's concurrent scan/feed launches
@@ -2092,8 +1903,7 @@ from lineitem where l_orderkey % 1000000000 = 1""",
 
 def run_encoded_leg() -> None:
     """``--encoded-leg``: one leg of the compressed-execution ladder, run in
-    a fresh interpreter (TRINO_TPU_TPCH_VECTOR_DECODE is read at connector
-    construction, so legs cannot share a process).  Prints one JSON object
+    a fresh interpreter with the leg's environment.  Prints one JSON object
     keyed by query with wall time, rows/s, and staged-bytes accounting from
     the trino_scan_* / trino_encoding_* registry deltas."""
     sf = float(os.environ.get("BENCH_SF", "0.2"))
@@ -2190,26 +2000,20 @@ def run_encoded_leg() -> None:
 
 def run_encoded_bench() -> None:
     """``bench.py --encoded``: the compressed-execution ladder (PR 16).
-    Three legs, each a fresh interpreter over the sf-scaled TPC-H connector:
+    Two legs, each a fresh interpreter over the sf-scaled TPC-H connector:
 
     - **encoded** — TRINO_TPU_ENCODED_EXEC=1: dictionary codes, RLE runs and
       lazy payload columns flow end-to-end.
     - **legacy** — TRINO_TPU_ENCODED_EXEC=0: same vectorized connector, but
       every batch expands at the scan boundary (the bit-for-bit oracle leg).
-    - **legacy_decode_off** — additionally TRINO_TPU_TPCH_VECTOR_DECODE=0:
-      the string-materializing row decoder, i.e. execution with no
-      dictionary anywhere (what a row-oriented engine would stage).
 
-    Acceptance: >=2x rows/s on the dictionary-heavy group-by vs the decoded
-    legacy, and the low-selectivity filter stages <10% of the payload bytes
+    Acceptance: the low-selectivity filter stages <10% of the payload bytes
     the legacy leg stages (>=5x staged-bytes reduction).  Writes
     BENCH_r16.json.  Env knobs: BENCH_SF (default 0.2), BENCH_ITERS (3)."""
     sf = float(os.environ.get("BENCH_SF", "0.2"))
     legs = {
         "encoded": {"TRINO_TPU_ENCODED_EXEC": "1"},
         "legacy": {"TRINO_TPU_ENCODED_EXEC": "0"},
-        "legacy_decode_off": {"TRINO_TPU_ENCODED_EXEC": "0",
-                              "TRINO_TPU_TPCH_VECTOR_DECODE": "0"},
     }
     results: dict[str, dict] = {}
     for leg, env_over in legs.items():
@@ -2228,7 +2032,6 @@ def run_encoded_bench() -> None:
 
     gb_enc = results["encoded"]["dict_groupby"]
     gb_leg = results["legacy"]["dict_groupby"]
-    gb_str = results["legacy_decode_off"]["dict_groupby"]
     lf_enc = results["encoded"]["lazy_filter"]
     lf_leg = results["legacy"]["lazy_filter"]
     # the legacy leg stages every payload byte; encoded stages only the
@@ -2239,8 +2042,6 @@ def run_encoded_bench() -> None:
     summary = {
         "dict_groupby_speedup_vs_legacy": round(
             gb_enc["input_rows_per_sec"] / gb_leg["input_rows_per_sec"], 2),
-        "dict_groupby_speedup_vs_decode_legacy": round(
-            gb_enc["input_rows_per_sec"] / gb_str["input_rows_per_sec"], 2),
         "lazy_filter_payload_staged_fraction": round(staged_frac, 4),
         "lazy_filter_staged_bytes_reduction": round(1 / max(
             staged_frac, 1e-9), 1),
@@ -2256,8 +2057,6 @@ def run_encoded_bench() -> None:
         "legs": results,
         "summary": summary,
         "acceptance": {
-            "dict_groupby_2x": summary[
-                "dict_groupby_speedup_vs_decode_legacy"] >= 2.0,
             "lazy_filter_staged_under_10pct": staged_frac < 0.10,
             "lazy_filter_5x_reduction": summary[
                 "lazy_filter_staged_bytes_reduction"] >= 5.0,
@@ -2276,12 +2075,6 @@ def main() -> None:
         return
     if "--profile" in sys.argv:
         run_profile_bench()
-        return
-    if "--scan" in sys.argv:
-        run_scan_bench()
-        return
-    if "--ndv" in sys.argv:
-        run_ndv_bench()
         return
     if "--fused" in sys.argv:
         run_fused_bench()

@@ -371,9 +371,7 @@ def serve(client, runner, log: CompileLog, label: str, sql: str) -> list:
         f"peak_hbm={_gb(hbm(jax.devices()[0])['peak'])}")
     for line in comp["slow"]:
         say(f"{label}: compile {line}")
-    say(f"{label}: hash_impl={K.hash_impl()} -> "
-        f"{'pallas hash kernels' if K.hash_kernels_selected(1) else 'sort/searchsorted'}; "
-        f"programs {json.dumps(ran, sort_keys=True)}")
+    say(f"{label}: programs {json.dumps(ran, sort_keys=True)}")
     say(f"{label}: edges fused={len(runner._fused_edges)} "
         f"resident={len(runner._resident_edges)} "
         f"collective={len(runner._collective_edges)}; reruns on the "

@@ -25,8 +25,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 @pytest.fixture(autouse=True)
 def _fresh_caches(tmp_path, monkeypatch):
     monkeypatch.setenv("TRINO_TPU_JOURNAL_DIR", str(tmp_path / "journal"))
-    for knob in ("TRINO_TPU_PLAN_CACHE", "TRINO_TPU_RESULT_CACHE",
-                 "TRINO_TPU_HASH_IMPL"):
+    for knob in ("TRINO_TPU_PLAN_CACHE", "TRINO_TPU_RESULT_CACHE"):
         monkeypatch.delenv(knob, raising=False)
     caching.reset_for_test()
     journal.reset_for_test()
@@ -60,15 +59,18 @@ def test_planning_env_flip_misses_plan_cache(runner):
         "group by n_regionkey"
     runner.execute(q)
     assert plan_cache.lookup(q, runner.session, runner.catalog) is not None
-    # TRINO_TPU_HASH_IMPL steers the optimizer — a cached plan built under
-    # the other impl must not be reused
-    flipped = "sort" if os.environ.get("TRINO_TPU_HASH_IMPL") != "sort" \
-        else "hash"
-    os.environ["TRINO_TPU_HASH_IMPL"] = flipped
+    # TRINO_TPU_BROADCAST_ROW_LIMIT steers the optimizer's join
+    # distribution — a plan cached under another limit must not be reused
+    was = os.environ.get("TRINO_TPU_BROADCAST_ROW_LIMIT")
+    os.environ["TRINO_TPU_BROADCAST_ROW_LIMIT"] = \
+        "12345" if was != "12345" else "54321"
     try:
         assert plan_cache.lookup(q, runner.session, runner.catalog) is None
     finally:
-        del os.environ["TRINO_TPU_HASH_IMPL"]
+        if was is None:
+            del os.environ["TRINO_TPU_BROADCAST_ROW_LIMIT"]
+        else:
+            os.environ["TRINO_TPU_BROADCAST_ROW_LIMIT"] = was
     assert plan_cache.lookup(q, runner.session, runner.catalog) is not None
 
 

@@ -40,6 +40,46 @@ def test_static_agg_matches_numpy():
         assert c == len(sel)
 
 
+def test_static_agg_nullable_keys_and_values_match_numpy():
+    # two keys (one nullable), nullable values, a row mask: sum / count(*)
+    # / min per group against numpy; NULL keys form their own group
+    rng = np.random.default_rng(11)
+    n, cap = 3000, 1024
+    k1, v1 = rng.integers(0, 200, n).astype(np.int64), rng.random(n) < 0.9
+    k2 = rng.integers(0, 3, n).astype(np.int64)
+    data, dval = rng.standard_normal(n), rng.random(n) < 0.85
+    mask = rng.random(n) < 0.9
+    r = static_grouped_agg(
+        [jnp.asarray(k1), jnp.asarray(k2)], [jnp.asarray(v1), None],
+        [(AggSpec("sum", jnp.float64), jnp.asarray(data), jnp.asarray(dval)),
+         (AggSpec("count_star", jnp.int64), None, None),
+         (AggSpec("min", jnp.float64), jnp.asarray(data), jnp.asarray(dval))],
+        cap, row_mask=jnp.asarray(mask))
+    want = {}
+    for i in np.flatnonzero(mask):
+        key = (int(k1[i]) if v1[i] else None, int(k2[i]))
+        want.setdefault(key, []).append(i)
+    assert int(r.num_groups) == len(want) <= cap
+    got = {}
+    kv = np.asarray(r.key_valids[0])
+    vals = [np.asarray(x) for x in r.values]
+    valid = [None if x is None else np.asarray(x) for x in r.value_valids]
+    for s in np.flatnonzero(np.asarray(r.slot_used)):
+        key = (int(np.asarray(r.keys[0])[s]) if kv[s] else None,
+               int(np.asarray(r.keys[1])[s]))
+        got[key] = (vals[0][s], valid[0][s], int(vals[1][s]), vals[2][s],
+                    valid[2][s])
+    assert set(got) == set(want)
+    for key, rows in want.items():
+        rows = np.asarray(rows)
+        xs = data[rows][dval[rows]]
+        s_, s_ok, cnt, mn, mn_ok = got[key]
+        assert cnt == len(rows)
+        assert bool(s_ok) == bool(mn_ok) == (len(xs) > 0)
+        if len(xs):
+            assert np.isclose(s_, xs.sum()) and mn == xs.min()
+
+
 def test_static_agg_overflow_signal():
     keys = jnp.arange(32, dtype=jnp.int64)
     x = jnp.ones(32)

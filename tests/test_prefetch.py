@@ -4,9 +4,11 @@ Covers the ingest contracts: split-order preservation under concurrent
 prefetch, queue backpressure under a tiny budget, early close on a satisfied
 pushed-down LIMIT with splits in flight, coalescer correctness across
 dictionary columns and dynamic-filter interaction, crash propagation from a
-prefetch thread, and prefetch on/off result equivalence on real queries.
+prefetch thread, and the scan's rows against the connector's page sources
+read directly.
 """
 
+import os
 import threading
 import time
 
@@ -132,9 +134,7 @@ def test_early_close_drops_unclaimed_splits():
 
 
 def test_scan_limit_early_close(monkeypatch):
-    monkeypatch.setenv("TRINO_TPU_PREFETCH", "1")
     monkeypatch.setenv("TRINO_TPU_COALESCE_TARGET_ROWS", "8")
-    monkeypatch.setenv("TRINO_TPU_STAGE_DEVICE", "0")
     conn = _FakeConnector([
         [_bigint_batch(list(range(8))) for _ in range(2)] for _ in range(8)
     ], delay=0.02)
@@ -152,6 +152,39 @@ def test_scan_limit_early_close(monkeypatch):
     # LIMIT satisfied after the first split: the prefetcher must not have
     # churned through all 8
     assert scan.ingest_stats.splits_opened < 8
+
+
+@pytest.mark.parametrize("cores,threads", [(1, 1), (2, 1), (4, 3), (64, 4)])
+def test_default_threads_follow_the_host(monkeypatch, cores, threads):
+    # cores - 1, at least one (a one-core host prefetches too), at most 4
+    monkeypatch.delenv("TRINO_TPU_PREFETCH_THREADS", raising=False)
+    monkeypatch.setattr(os, "cpu_count", lambda: cores)
+    assert IngestConfig.default_threads() == threads
+    assert IngestConfig.from_env().threads == threads
+
+
+def test_zero_threads_setting_still_prefetches(monkeypatch):
+    monkeypatch.setenv("TRINO_TPU_PREFETCH_THREADS", "0")
+    assert IngestConfig.from_env().threads == 1
+
+
+def test_one_core_host_scan_runs_the_prefetcher(monkeypatch):
+    monkeypatch.delenv("TRINO_TPU_PREFETCH_THREADS", raising=False)
+    monkeypatch.setattr(os, "cpu_count", lambda: 1)
+    conn = _FakeConnector([
+        [_bigint_batch([s * 10 + i]) for i in range(3)] for s in range(3)
+    ])
+    scan = ScanOperator(conn, conn.splits(), ["v"])
+    vals = []
+    while not scan.is_finished():
+        b = scan.get_output()
+        if b is None:
+            break
+        vals.extend(v for (v,) in b.to_pylist())
+    scan.close()
+    assert vals == [s * 10 + i for s in range(3) for i in range(3)]
+    assert len(scan._prefetcher._threads) == 1
+    assert scan.ingest_stats.splits_opened == 3
 
 
 def test_crash_in_prefetch_thread_propagates():
@@ -195,9 +228,7 @@ def test_coalescer_merges_to_target():
 def test_scan_dynamic_filter_with_coalescing(monkeypatch):
     from trino_tpu.exec.dynamic_filter import DynamicFilterHolder
 
-    monkeypatch.setenv("TRINO_TPU_PREFETCH", "1")
     monkeypatch.setenv("TRINO_TPU_COALESCE_TARGET_ROWS", "32")
-    monkeypatch.setenv("TRINO_TPU_STAGE_DEVICE", "0")
     conn = _FakeConnector([
         [_bigint_batch(list(range(s * 10, s * 10 + 10)))] for s in range(4)
     ])
@@ -218,22 +249,40 @@ def test_scan_dynamic_filter_with_coalescing(monkeypatch):
 
 
 def test_prefetch_off_matches_on(monkeypatch):
-    from trino_tpu.runner import StandaloneQueryRunner
+    # the scan (prefetched, coalesced, staged) yields exactly the rows of
+    # the connector's page sources read directly, split by split, in order
+    from trino_tpu.connectors.tpch import TpchConnector
 
-    sql = ("select l_returnflag, count(*), sum(l_quantity) from lineitem "
-           "where l_quantity < 30 group by l_returnflag order by l_returnflag")
-    monkeypatch.setenv("TRINO_TPU_PREFETCH", "0")
-    sync_rows = StandaloneQueryRunner().execute(sql).rows()
-    monkeypatch.setenv("TRINO_TPU_PREFETCH", "1")
     monkeypatch.setenv("TRINO_TPU_COALESCE_TARGET_ROWS", "4096")
-    async_rows = StandaloneQueryRunner().execute(sql).rows()
-    assert sync_rows == async_rows
+    conn = TpchConnector(scale_factor=0.01, batch_rows=1000)
+    cols = ["l_orderkey", "l_linenumber", "l_returnflag", "l_quantity",
+            "l_comment"]
+    splits = conn.get_splits("lineitem", 4, 1)
+    assert len(splits) > 1
+    want = []
+    for split in splits:
+        src = conn.create_page_source(split, cols)
+        while not src.is_finished():
+            b = src.get_next_batch()
+            if b is not None:
+                want.extend(b.to_pylist())
+    scan = ScanOperator(conn, splits, cols)
+    got = []
+    while not scan.is_finished():
+        b = scan.get_output()
+        if b is None:
+            break
+        got.extend(b.to_pylist())
+    scan.close()
+    assert got == want and len(want) > 4096
+    st = scan.ingest_stats
+    assert 0 < st.coalesced_batches < st.scan_batches
+    assert st.staged_batches == st.coalesced_batches
 
 
 def test_scan_stats_in_query_stats(monkeypatch):
     from trino_tpu.runner import StandaloneQueryRunner
 
-    monkeypatch.setenv("TRINO_TPU_PREFETCH", "1")
     r = StandaloneQueryRunner()
     rows = r.execute(
         "explain analyze select count(*) from orders").rows()
@@ -244,7 +293,7 @@ def test_scan_stats_in_query_stats(monkeypatch):
     scan_spans = [s for s in spans
                   if "trino.scan.gb-per-s" in s.attributes]
     assert scan_spans and any(
-        s.attributes.get("trino.scan.prefetch") for s in scan_spans)
+        s.attributes.get("trino.scan.rows", 0) > 0 for s in scan_spans)
 
 
 def _walk(span):

@@ -213,6 +213,79 @@ def test_float_nan_single_group():
     assert h[0] == h[1]
 
 
+def _nullable_ints_with_dead_rows(rng):
+    n = 4096
+    keys = [(rng.integers(-40, 40, n).astype(np.int64), rng.random(n) < 0.85)]
+    return keys, rng.random(n) < 0.9, None
+
+
+def _float_specials(rng):
+    specials = np.array([np.nan, -np.nan, 0.0, -0.0, np.inf, -np.inf,
+                         1.5, -1.5, 1e300, 1e-300])
+    n = 2000
+    keys = [(specials[rng.integers(0, len(specials), n)], None),
+            (rng.integers(0, 3, n).astype(np.int64), None)]
+    return keys, None, 24  # -0 = 0 and NaN is ONE group: 8 values x 3
+
+
+def _all_duplicates(rng):
+    return [(np.zeros(1024, np.int64), None)], None, 1
+
+
+def _nullable_bools(rng):
+    n = 1024
+    keys = [(rng.random(n) < 0.5, rng.random(n) < 0.7)]
+    return keys, None, 3  # True / False / NULL
+
+
+def _every_row_dead(rng):
+    # a fully filtered batch: the aggregation returns no groups for it
+    return [(rng.integers(0, 9, 512).astype(np.int64), None)], \
+        np.zeros(512, bool), 0
+
+
+def _sql_group_key(keys, i):
+    """Row i's GROUP BY key as numpy reads SQL equality: NULL is one value,
+    -0 equals 0, every NaN is one value."""
+    out = []
+    for d, v in keys:
+        if v is not None and not v[i]:
+            out.append(("null",))
+        elif d.dtype.kind == "f":
+            out.append(("nan",) if np.isnan(d[i]) else float(d[i]) + 0.0)
+        else:
+            out.append(d[i].item())
+    return tuple(out)
+
+
+@pytest.mark.parametrize("make", [
+    _nullable_ints_with_dead_rows, _float_specials, _all_duplicates,
+    _nullable_bools, _every_row_dead], ids=[
+        "nullable_ints_dead_rows", "float_specials", "all_duplicates",
+        "nullable_bools", "every_row_dead"])
+def test_group_ids_partitions_match_numpy(make):
+    # two rows share a group exactly when their keys are equal; gid is
+    # nondecreasing over the sorted rows, dead rows last with gid >= n
+    keys, live, want_groups = make(np.random.default_rng(1))
+    rows = len(keys[0][0])
+    perm, gid, n = K.group_ids(keys, live)
+    perm, gid = np.asarray(perm), np.asarray(gid)
+    assert (np.diff(gid) >= 0).all()
+    row_gid = np.empty(rows, np.int64)
+    row_gid[perm] = gid
+    alive = np.ones(rows, bool) if live is None else live
+    assert (row_gid[~alive] >= n).all()
+    by_key, by_gid = {}, {}
+    for i in np.flatnonzero(alive):
+        k, g = _sql_group_key(keys, i), int(row_gid[i])
+        assert by_key.setdefault(k, g) == g, "equal keys split"
+        assert by_gid.setdefault(g, k) == k, "distinct keys merged"
+    assert n == len(by_key) == len(by_gid)
+    assert sorted(by_gid) == list(range(n))
+    if want_groups is not None:
+        assert n == want_groups
+
+
 def test_float_join_nan_and_negzero_match():
     from trino_tpu.exec import join_exec as JX
     from trino_tpu.spi import DOUBLE

@@ -37,6 +37,52 @@ def _expected_pairs(build, probe, bvalid=None, pvalid=None):
 
 
 # ---------------------------------------------------------------------------
+# join_exec.probe_ranges_device: candidate counts against numpy
+
+
+def _misses_nulls_dead_rows(rng):
+    nb, npr = 4000, 6000
+    return (rng.integers(0, 500, nb).astype(np.int64), rng.random(nb) < 0.9,
+            rng.random(nb) < 0.95,
+            rng.integers(0, 700, npr).astype(np.int64),  # some keys miss
+            rng.random(npr) < 0.9, rng.random(npr) < 0.95)
+
+
+def _empty_build(rng):
+    return (np.empty(0, np.int64), None, None,
+            np.arange(50, dtype=np.int64), None, None)
+
+
+@pytest.mark.parametrize("make", [_misses_nulls_dead_rows, _empty_build],
+                         ids=["misses_nulls_dead_rows", "empty_build"])
+def test_probe_ranges_match_numpy(make):
+    # each probe row's run [lo, lo + count) of the sorted build holds
+    # exactly the live, non-NULL build rows whose key equals its own
+    bk, bv, blive, pk, pv, plive = make(np.random.default_rng(7))
+    table = JX.build_table(_keys(bk, bv), live=blive, num_rows=len(bk))
+    lo, counts, total = JX.probe_ranges_device(table, _keys(pk, pv), [None],
+                                               plive)
+    lo, counts = np.asarray(lo), np.asarray(counts)
+    b_ok = np.ones(len(bk), bool)
+    for m in (bv, blive):
+        if m is not None:
+            b_ok &= m
+    p_ok = np.ones(len(pk), bool)
+    for m in (pv, plive):
+        if m is not None:
+            p_ok &= m
+    want = np.array([int((b_ok & (bk == k)).sum()) if ok else 0
+                     for k, ok in zip(pk, p_ok)], np.int64)
+    np.testing.assert_array_equal(counts, want)
+    assert int(total.get()) == int(want.sum())
+    perm = np.asarray(table.perm) if len(bk) else None
+    for i in np.flatnonzero(want):
+        np.testing.assert_array_equal(
+            np.sort(perm[lo[i]:lo[i] + counts[i]]),
+            np.flatnonzero(b_ok & (bk == pk[i])))
+
+
+# ---------------------------------------------------------------------------
 # join_exec.run_pairs: provable / estimated caps vs the exact host total
 
 

@@ -5,9 +5,8 @@ and not attached (on-chip-measurement guide, section 2): these tests lower
 and compile, for ONE described v5e device and from ``ShapeDtypeStruct``s at
 real widths, the programs TPC-H Q1/Q3/Q6 launch on the served path — the
 Pallas segment-sum kernel, the sort-route grouping and join programs, and
-``static_grouped_agg`` with the TPU branch forced — and hold the two
-open-addressing hash kernels as strict xfails with the compiler's refusal.
-Nothing runs; a compile that passes is not a chip run.
+``static_grouped_agg`` with the TPU branch forced.  Nothing runs; a compile
+that passes is not a chip run.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load libtpu, and under xdist every worker imports
@@ -29,8 +28,6 @@ from trino_tpu.exec import join_exec as JX
 from trino_tpu.exec import kernels as K
 from trino_tpu.ops import pallas_kernels as PK
 from trino_tpu.parallel.static_agg import AggSpec, static_grouped_agg
-
-VMEM_REFUSAL = "Cannot store scalars to VMEM"
 
 BUCKETS = [
     pytest.param(1 << 12, id="2^12"),
@@ -79,8 +76,6 @@ def tpu_backend(monkeypatch):
     """Code that asks jax.default_backend() sees the CPU here; steer it to
     the branch the chip takes — from the test, not from a program option."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.delenv("TRINO_TPU_HASH_IMPL", raising=False)
-    monkeypatch.delenv("TRINO_TPU_HASH_INTERPRET", raising=False)
 
 
 def test_segment_sum_kernel_compiles(shape):
@@ -92,44 +87,6 @@ def test_segment_sum_kernel_compiles(shape):
             shape(tile, jnp.float32), shape(tile, jnp.int32),
             shape(tile, jnp.bool_)).compile()
     assert "tpu_custom_call" in compiled.as_text()
-
-
-def _insert(shape):
-    n = 32 * 1024
-    return PK._build_insert(2, 65536, 32, False).lower(
-        shape((2, n), jnp.uint32), shape((1, n), jnp.uint32),
-        shape((1, n), jnp.bool_))
-
-
-def _probe(shape):
-    n, s = 32 * 1024, 65536
-    return PK._build_probe(2, s, 32, False).lower(
-        shape((2, s), jnp.uint32), shape((1, s), jnp.int32),
-        shape((2, n), jnp.uint32), shape((1, n), jnp.uint32),
-        shape((1, n), jnp.bool_))
-
-
-@pytest.mark.xfail(strict=True, raises=ValueError, reason=VMEM_REFUSAL)
-@pytest.mark.parametrize("lower", [_insert, _probe],
-                         ids=["hash_insert", "hash_probe"])
-def test_hash_kernels_compile(shape, lower):
-    """The join index at 32k rows (P=2 hash planes, S=65536 slots).  The day
-    this XPASSes, the kernels compile: give kernels.hash_kernels_selected a
-    TPU branch and measure it (ROADMAP S5)."""
-    with jax.enable_x64(False):
-        try:
-            lower(shape).compile()
-        except ValueError as e:
-            # a different refusal is news, not the expected failure
-            assert VMEM_REFUSAL in str(e), e
-            raise
-
-
-def test_auto_resolves_to_sort_on_tpu(tpu_backend, monkeypatch):
-    assert not K.hash_kernels_selected(1 << 16)
-    monkeypatch.setenv("TRINO_TPU_HASH_IMPL", "pallas")
-    assert K.hash_kernels_selected(1 << 16)
-    assert not K.hash_interpret()  # forced pallas would compile for real
 
 
 @pytest.mark.parametrize("rows", BUCKETS)

@@ -10,7 +10,7 @@ Key = SQL fingerprint (telemetry.runtime.fingerprint, for observability)
 ⊕ the exact statement text (fingerprints normalize case, which would
 merge ``'BUILDING'`` with ``'building'`` — the text disambiguates) ⊕ the
 session properties that shape planning/execution ⊕ the engine env knobs
-that select alternate executables (``TRINO_TPU_HASH_IMPL`` etc., so a
+that select alternate executables (``TRINO_TPU_FUSED_STAGE`` etc., so a
 knob flip can never serve a plan built for the other implementation) ⊕
 the catalog **generation counter** (connectors/catalog.py), which bumps
 on every DDL/ANALYZE so schema or stats changes invalidate wholesale.
@@ -43,8 +43,7 @@ __all__ = [
 # env knobs that change which jitted programs execute a plan (and hence
 # the bitwise result of float aggregation): a flip must miss
 PLANNING_ENV_KNOBS = (
-    "TRINO_TPU_HASH_IMPL", "TRINO_TPU_FUSED_STAGE", "TRINO_TPU_FUSED_CAP",
-    "TRINO_TPU_TPCH_VECTOR_DECODE", "TRINO_TPU_PREFETCH", "TRINO_TPU_HBO",
+    "TRINO_TPU_FUSED_STAGE", "TRINO_TPU_FUSED_CAP", "TRINO_TPU_HBO",
     "TRINO_TPU_JOIN_REORDER_DP_LIMIT", "TRINO_TPU_BROADCAST_ROW_LIMIT",
 )
 

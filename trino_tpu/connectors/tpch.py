@@ -24,7 +24,6 @@ lineitem/orders are ranges of *orders* so each split carries whole orders.
 from __future__ import annotations
 
 import datetime
-import os
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -282,10 +281,6 @@ class TpchConnector(Connector):
         # integer gather instead of materializing python strings and binary-
         # searching an object array per row (GIL-bound, ~75% of decode time)
         self._code_tables: dict[tuple[str, str], tuple] = {}
-        # TRINO_TPU_TPCH_VECTOR_DECODE=0 keeps the legacy string-materializing
-        # decode — only useful as the bench baseline (bench.py --scan)
-        self._vector_decode = os.environ.get(
-            "TRINO_TPU_TPCH_VECTOR_DECODE", "1") != "0"
 
     def data_version(self, table: str):
         """Generated data is a pure function of the scale factor: a
@@ -420,7 +415,7 @@ class TpchConnector(Connector):
     def _code_table(self, table: str, column: str, vocab) -> tuple:
         """(vocab-index -> code table, sorted dictionary), cached.  The
         dictionary is the DATA-derived one from column_dictionary — identical
-        to the legacy decode, so small tables keep small dictionaries (nation
+        to the string-materializing decode, so small tables keep small dictionaries (nation
         comments: 25 entries, not the 59k vocab — dictionary-space ops like
         `||` depend on that).  Vocab entries absent from the data clip to an
         arbitrary valid code; by construction they never occur."""
@@ -437,7 +432,7 @@ class TpchConnector(Connector):
 
     def _vocab_column(self, table: str, column: str, idx: np.ndarray,
                       vocab: list[str]) -> Column:
-        if (table, column) in self._building or not self._vector_decode:
+        if (table, column) in self._building:
             values = np.array(vocab, dtype=object)[np.asarray(idx, np.int64)]
             return self._dict_column(table, column, values)
         tab, d = self._code_table(table, column, vocab)
@@ -449,7 +444,7 @@ class TpchConnector(Connector):
         index arithmetic as _device_comment_codes, mapped through the cached
         code table over _comment_vocab (pure ufunc work — releases the GIL,
         so prefetch threads genuinely parallelize the decode)."""
-        if (table, column) in self._building or not self._vector_decode:
+        if (table, column) in self._building:
             return self._dict_column(
                 table, column, _comments(keys, stream, phrase, phrase_ppm))
         w = len(_COMMENT_WORDS)

@@ -40,7 +40,7 @@ from ..sql.ir import RowExpression
 from . import kernels as K
 from . import syncguard as SG
 
-__all__ = ["DeviceJoinTable", "JoinHashTable", "build_table",
+__all__ = ["DeviceJoinTable", "build_table",
            "probe_ranges_device", "run_pairs", "run_unique",
            "ExpandPlanner", "OverflowQueue", "plan_unique_cap",
            "unique_cap_bucket", "key_input", "with_probe_keys"]
@@ -73,7 +73,7 @@ class DeviceJoinTable:
 
     __slots__ = ("sorted_hash", "perm", "key_datas",
                  "num_rows", "_scalars", "_fetched", "dense", "dense_lo",
-                 "dense_ranges", "hash_idx")
+                 "dense_ranges")
 
     def __init__(self, sorted_hash, perm, key_datas,
                  num_rows: int, scalars):
@@ -93,10 +93,6 @@ class DeviceJoinTable:
         self.dense = None
         self.dense_lo: tuple = ()
         self.dense_ranges: tuple = ()
-        # open-addressing index over the build hashes (TRINO_TPU_HASH_IMPL):
-        # probe_ranges_device dispatches on it; every downstream program is
-        # shared
-        self.hash_idx: Optional["JoinHashTable"] = None
 
     def _fetch(self) -> tuple:
         if self._fetched is None:
@@ -155,62 +151,6 @@ def lane_bytes(cols) -> int:
     """Bytes one lane of [(data, valid|None), ...] holds: each column's
     value and a byte for its validity mask."""
     return sum(np.dtype(d.dtype).itemsize + (v is not None) for d, v in cols)
-
-
-class JoinHashTable:
-    """Open-addressing index over the build side's 64-bit key hashes
-    (TRINO_TPU_HASH_IMPL, ops/pallas_kernels.py): maps a probe hash to the
-    contiguous run of matching rows in sorted-hash order, replacing the two
-    binary searches of probe_ranges_device with one kernel probe plus two
-    gathers.
-    The (lo, counts) it yields are value-identical to the searchsorted
-    implementation — both index the SAME sorted order — so every downstream
-    expand/verify/gather program is shared between implementations, and
-    ``build_id = perm[lo + within]`` holds unchanged."""
-
-    __slots__ = ("table_planes", "slot_gid", "group_lo", "group_counts",
-                 "num_slots")
-
-    def __init__(self, table_planes, slot_gid, group_lo, group_counts,
-                 num_slots: int):
-        self.table_planes = table_planes
-        self.slot_gid = slot_gid
-        self.group_lo = group_lo  # [S] first sorted position per hash group
-        self.group_counts = group_counts  # [S] live run length per group
-        self.num_slots = num_slots
-
-
-def _hash_planes(h):
-    """uint64 hash -> the kernels' [2, N] uint32 planes + uint32 slot hash.
-    Plane equality is exactly 64-bit hash equality, so the index reproduces
-    the searchsorted candidate set bit for bit."""
-    planes = jnp.stack([
-        (h & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32),
-        (h >> jnp.uint64(32)).astype(jnp.uint32)])
-    h32 = (h ^ (h >> jnp.uint64(32))).astype(jnp.uint32)
-    return planes, h32
-
-
-@jit_memo("join._hash_index_fn")
-def _hash_index_fn(S: int, n: int, interpret: bool):
-    from ..ops import pallas_kernels as PK
-
-    @program("join.hash_index")
-    def fn(sorted_hash):
-        live = sorted_hash < jnp.uint64(_SENT_PROBE)
-        planes, h32 = _hash_planes(sorted_hash)
-        row_gid, _count, table, sgid = PK.hash_insert(
-            planes, h32, live, S, interpret=interpret)
-        # the insert ran over the SORTED hashes: each distinct hash is one
-        # contiguous run, so per-group lo/count are one min- and one
-        # sum-scatter over positions (dead rows carry gid S -> trash slot)
-        pos = jnp.arange(n, dtype=jnp.int64)
-        glo = jnp.full((S + 1,), n, jnp.int64).at[row_gid].min(pos)
-        gcnt = jnp.zeros((S + 1,), jnp.int64).at[row_gid].add(
-            live.astype(jnp.int64))
-        return table, sgid, glo[:S], gcnt[:S]
-
-    return fn
 
 
 @jit_memo("join._build_fn")
@@ -410,13 +350,6 @@ def build_table(keys: Sequence[tuple], live=None,
         except Exception:
             pass
     table = DeviceJoinTable(sh, perm, datas, int(datas[0].shape[0]), scalars)
-    n = table.num_rows
-    if K.hash_kernels_selected(n):
-        # open-addressing index over the sorted hashes: pure device
-        # programs, zero extra syncs; a kernel failure fails the build
-        S = K.bucket(2 * n)
-        table.hash_idx = JoinHashTable(
-            *_hash_index_fn(S, n, K.hash_interpret())(sh), S)
     if want_range:
         maybe_build_dense(table, keys, flat, live is not None)
     return table
@@ -425,8 +358,8 @@ def build_table(keys: Sequence[tuple], live=None,
 def _probe_hash(num_keys: int, has_valid: tuple, has_remap: tuple,
                 has_live: bool, flat):
     """Traced: probe-side key hash with NULL/dictionary-miss rows folded to
-    the probe sentinel — the normalization shared by the searchsorted and
-    the open-addressing range implementations.  Returns (h, live)."""
+    the probe sentinel, as every probe program reads it.  Returns
+    (h, live)."""
     i = 0
     datas, valids = [], []
     for k in range(num_keys):
@@ -478,30 +411,6 @@ def _ranges_fn(num_keys: int, has_valid: tuple, has_live: bool,
     return fn
 
 
-@jit_memo("join._hash_ranges_fn")
-def _hash_ranges_fn(num_keys: int, has_valid: tuple, has_live: bool,
-                    has_remap: tuple, S: int, interpret: bool):
-    from ..ops import pallas_kernels as PK
-
-    @program("join.hash_ranges")
-    def fn(table_planes, slot_gid, group_lo, group_counts, *flat):
-        h, live = _probe_hash(num_keys, has_valid, has_remap, has_live, flat)
-        ok = h < jnp.uint64(_SENT_PROBE)
-        if live is not None:
-            ok = ok & live
-        planes, h32 = _hash_planes(h)
-        pgid = PK.hash_probe(table_planes, slot_gid, planes, h32, ok,
-                             interpret=interpret)
-        hit = pgid >= 0  # dead/null/miss probe rows come back -1
-        safe = jnp.where(hit, pgid, 0)
-        lo = group_lo[safe]
-        counts = jnp.where(hit, group_counts[safe],
-                           jnp.zeros((), group_counts.dtype))
-        return lo, counts, jnp.sum(counts)
-
-    return fn
-
-
 def probe_ranges_device(table: DeviceJoinTable, probe_keys: Sequence[tuple],
                         remaps: Sequence[Optional[np.ndarray]], live=None):
     """probe_keys: [(data, valid|None), ...]; ``remaps[k]`` an optional
@@ -520,17 +429,9 @@ def probe_ranges_device(table: DeviceJoinTable, probe_keys: Sequence[tuple],
             flat.append(jnp.asarray(v))
     if live is not None:
         flat.append(jnp.asarray(live))
-    idx = table.hash_idx
-    if idx is not None:
-        lo, counts, total = _hash_ranges_fn(
-            len(probe_keys), has_valid, live is not None, has_remap,
-            idx.num_slots, K.hash_interpret())(
-            idx.table_planes, idx.slot_gid, idx.group_lo,
-            idx.group_counts, *flat)
-    else:
-        lo, counts, total = _ranges_fn(
-            len(probe_keys), has_valid, live is not None, has_remap)(
-            table.sorted_hash, *flat)
+    lo, counts, total = _ranges_fn(
+        len(probe_keys), has_valid, live is not None, has_remap)(
+        table.sorted_hash, *flat)
     return lo, counts, SG.async_scalar(total, "join.pair-total")
 
 

@@ -5,13 +5,10 @@ data structures (reference: operator/FlatHash.java:42, operator/join/
 PagesHash.java, sql/gen/OrderingCompiler.java:70, operator/output/
 PagePartitioner.java:55).  Design rules:
 
-- **Sort-first, hash as the measured alternative.**  Grouping and join build
-  default to a *sort*: XLA lowers ``sort`` to an efficient on-chip bitonic
-  network, and everything downstream (segment reduction, binary-search
-  probe) is dense vector work on the MXU/VPU.  ``TRINO_TPU_HASH_IMPL``
-  selects a second, open-addressing implementation of the same contracts
-  (Pallas linear-probing kernels, ops/pallas_kernels.py) so the two can be
-  baked off per NDV (bench.py --ndv) instead of argued about.
+- **Sort-first.**  Grouping and the join build *sort*: XLA lowers ``sort``
+  to an efficient on-chip bitonic network, and everything downstream
+  (segment reduction, binary-search probe) is dense vector work on the
+  MXU/VPU.
 - **Static shapes via bucketing.**  Data-dependent sizes (group counts, join
   fan-out) are synced to host once per kernel invocation and rounded up to a
   power of two; jitted programs are cached per (spec, shape-bucket), so
@@ -39,10 +36,6 @@ from ..spi.errors import GENERIC_INTERNAL_ERROR, TrinoError
 __all__ = [
     "bucket",
     "group_ids",
-    "group_ids_auto",
-    "hash_group_ids",
-    "hash_impl",
-    "key_planes",
     "grouped_reduce",
     "sort_perm",
     "hash_combine",
@@ -189,158 +182,6 @@ def group_ids(keys: Sequence[tuple], live=None) -> tuple:
     perm, gid, n = _group_ids_fn(num_keys, has_valid, live is not None)(
         *datas, *valids, *extra)
     return perm, gid, int(n)
-
-
-# ---------------------------------------------------------------------------
-# open-addressing grouping (TRINO_TPU_HASH_IMPL): Pallas linear-probing
-# insert/probe kernels as a second implementation of the group_ids contract
-
-
-def hash_impl() -> str:
-    """Resolved TRINO_TPU_HASH_IMPL knob: 'pallas' forces the open-addressing
-    kernels, 'sort' and 'auto' take the lexsort / searchsorted path.  Read per
-    call, not cached: tests and the bench flip it between legs."""
-    mode = os.environ.get("TRINO_TPU_HASH_IMPL", "auto").lower()
-    return mode if mode in ("pallas", "sort") else "auto"
-
-
-def hash_interpret() -> bool:
-    """Interpret-mode pallas (identical kernels as pure XLA) everywhere but
-    a real TPU backend; TRINO_TPU_HASH_INTERPRET=1 forces it for A/B runs."""
-    if os.environ.get("TRINO_TPU_HASH_INTERPRET") == "1":
-        return True
-    return jax.default_backend() != "tpu"
-
-
-def hash_kernels_selected(n_rows: int) -> bool:
-    """THE TRINO_TPU_HASH_IMPL decision, shared by group_ids_auto, the join
-    index build (join_exec.build_table) and parallel/static_agg.
-
-    Only an explicit 'pallas' selects the open-addressing kernels, and a
-    selected kernel that does not compile or run fails the query — nothing
-    swaps implementations behind the caller.  'auto' resolves to sort on
-    every backend, by rule: off-TPU the kernels only exist in interpret
-    mode (a correctness vehicle), and on TPU Mosaic refuses both of them —
-    ``ValueError: Cannot store scalars to VMEM`` (libtpu 0.0.34, compiled
-    for v5e; tests/test_tpu_compile.py holds them as strict xfails).  The
-    day that xfail flips, give 'auto' a TPU branch with a VMEM-fit test."""
-    return n_rows > 0 and hash_impl() == "pallas"
-
-
-def _f64_key_planes(c) -> list:
-    """Four uint32 planes INJECTIVE over canonical float64 values: the same
-    range-reduction as _f64_hash_word (the TPU x64 rewrite compiles no
-    64-bit bitcast) but keeping the w1/w2/w3 words and the class/tag meta
-    word separate instead of mixing them.  scaled = w1 + w2 + w3 exactly
-    (each split removes >= 24 significand bits, 24*3 > 53), and the power-
-    of-two scale is exact, so equal doubles give equal planes and distinct
-    doubles distinct planes: plane equality IS SQL key equality."""
-    fin = jnp.isfinite(c)
-    mag = jnp.abs(c)
-    safe_mag = jnp.where(mag > 0, mag, 1.0)
-    cls = jnp.clip(jnp.floor(jnp.log2(safe_mag) / 120.0), -9.0, 9.0)
-    s = 2.0 ** (-60.0 * cls)  # applied twice; 2**(-120*cls) would overflow
-    scaled = jnp.where(fin, c * s * s, 0.0)
-    w1 = scaled.astype(jnp.float32)
-    r1 = scaled - w1.astype(jnp.float64)
-    w2 = r1.astype(jnp.float32)
-    r2 = r1 - w2.astype(jnp.float64)
-    w3 = r2.astype(jnp.float32)
-    tag = jnp.where(jnp.isnan(c), 3, jnp.where(c == jnp.inf, 1,
-                    jnp.where(c == -jnp.inf, 2, 0)))
-    meta = (cls.astype(jnp.int32) + 16) | (tag.astype(jnp.int32) << 8)
-
-    def u32(w):
-        return jax.lax.bitcast_convert_type(w, jnp.uint32)
-
-    return [u32(w1), u32(w2), u32(w3), meta.astype(jnp.uint32)]
-
-
-def key_planes(keys: Sequence[tuple]) -> list:
-    """Normalize key columns into uint32 planes whose elementwise equality
-    is exactly SQL group-key equality: ints/bools split into lo/hi 32-bit
-    words, floats canonicalized (-0 -> +0, one NaN) then decomposed into the
-    injective w1/w2/w3/meta cascade, nullable keys zero their data planes
-    and append a validity plane (NULL is its own group, distinct from 0)."""
-    out: list = []
-    for d, v in keys:
-        d = jnp.asarray(d)
-        kind = np.dtype(d.dtype).kind
-        if kind == "f":
-            kp = _f64_key_planes(_canon_float(d.astype(jnp.float64)))
-        elif kind == "b":
-            kp = [d.astype(jnp.uint32)]
-        else:
-            x = d.astype(jnp.int64)
-            kp = [(x & 0xFFFFFFFF).astype(jnp.uint32),
-                  ((x >> 32) & 0xFFFFFFFF).astype(jnp.uint32)]
-        if v is not None:
-            vv = jnp.asarray(v)
-            kp = [jnp.where(vv, p, jnp.zeros((), p.dtype)) for p in kp]
-            kp.append(vv.astype(jnp.uint32))
-        out.extend(kp)
-    return out
-
-
-def hash_row_gids(keys: Sequence[tuple], live=None,
-                  num_slots: Optional[int] = None):
-    """Open-addressing core: per-ORIGINAL-row dense group ids in first-
-    occurrence order via the Pallas insert kernel.  Returns (row_gid,
-    count): dead rows get ``num_slots`` (>= any real id), ``count`` stays a
-    DEVICE scalar — zero host syncs, usable inside jitted programs."""
-    from ..ops import pallas_kernels as PK
-
-    datas = [jnp.asarray(d) for d, _ in keys]
-    n = int(datas[0].shape[0])
-    S = int(num_slots) if num_slots else bucket(2 * max(n, 1))
-    planes = key_planes(keys)
-    h = hash_combine(planes)
-    h32 = (h ^ (h >> jnp.uint64(32))).astype(jnp.uint32)
-    lv = None if live is None else jnp.asarray(live)
-    row_gid, count, _table, _sgid = PK.hash_insert(
-        jnp.stack(planes), h32, lv, S, interpret=hash_interpret())
-    return row_gid, count
-
-
-@jit_memo("kernels._hash_finish_fn")
-def _hash_finish_fn():
-    @program("kernels.hash_finish")
-    def fn(row_gid):
-        # jnp.argsort is stable: rows within a group keep input order, and
-        # dead rows (gid = num_slots, beyond every real id) sort last
-        perm = jnp.argsort(row_gid)
-        return perm, row_gid[perm].astype(jnp.int32)
-
-    return fn
-
-
-def hash_group_ids(keys: Sequence[tuple], live=None) -> tuple:
-    """Open-addressing alternative to :func:`group_ids` — same contract:
-    (perm, gid, num_groups) with gid nondecreasing over sorted rows, equal
-    keys adjacent, dead rows last with gid >= num_groups, and ONE host sync
-    for the count.  Group ids come out in first-occurrence order instead of
-    key order; both satisfy the documented contract, operator output is
-    order-canonicalized downstream.  The expensive multi-key 64-bit lexsort
-    becomes one int32 sort over the kernel-assigned ids."""
-    if not keys:
-        raise TrinoError(GENERIC_INTERNAL_ERROR,
-                         "hash_group_ids needs at least one key")
-    n = int(jnp.asarray(keys[0][0]).shape[0])
-    if n == 0:
-        return jnp.arange(0), jnp.zeros(0, jnp.int32), 0
-    row_gid, count = hash_row_gids(keys, live)
-    perm, gid = _hash_finish_fn()(row_gid)
-    return perm, gid, int(count)
-
-
-def group_ids_auto(keys: Sequence[tuple], live=None) -> tuple:
-    """group_ids with the TRINO_TPU_HASH_IMPL knob applied (see
-    hash_kernels_selected); the selected implementation's errors
-    propagate."""
-    n = int(jnp.asarray(keys[0][0]).shape[0]) if keys else 0
-    if keys and hash_kernels_selected(n):
-        return hash_group_ids(keys, live)
-    return group_ids(keys, live)
 
 
 SMALL_CODES_LIMIT = 4096  # max fused-code group space for the no-sort path
@@ -1667,7 +1508,7 @@ def compact_device_batch(batch, live_count: int):
     Dead lanes beyond the bucket are dropped; the (padded) tail keeps a live
     mask.  Called by operators._maybe_compact_device alone, on behalf of
     blocking operators about to do O(lanes log lanes) work (SortOperator,
-    the aggregation's group_ids_codes / group_ids_auto / global-DISTINCT
+    the aggregation's group_ids_codes / group_ids / global-DISTINCT
     paths): a filter or join output riding a fat static shape with few
     survivors would otherwise drag its dead lanes through that sort.  Not
     for the masked aggregation, which reads dead lanes for less."""

@@ -47,7 +47,6 @@ from .prefetch import (
     DeviceStager,
     IngestConfig,
     PrefetchingPageSource,
-    encode_scan_batch,
 )
 from .revoking import device_nbytes
 from .stats import EncodingStats, ScanIngestStats
@@ -122,13 +121,11 @@ class ScanOperator(Operator):
     prune rows before padding/device transfer (the probe side of
     DynamicFilterService — see exec/dynamic_filter.py).
 
-    With ``TRINO_TPU_PREFETCH=1`` (the default) the scan runs the async
-    ingest pipeline of exec/prefetch.py: splits decode on background
-    threads into a bounded queue, small batches coalesce up to the target
-    power-of-two bucket, and the next batch's ``jax.device_put`` is
-    dispatched while the previous one computes downstream.  With
-    ``TRINO_TPU_PREFETCH=0`` the synchronous one-split-at-a-time path below
-    runs bit-for-bit as before."""
+    The scan runs the async ingest pipeline of exec/prefetch.py: splits
+    decode on background threads into a bounded queue, small batches
+    coalesce up to the target power-of-two bucket, and the next batch's
+    ``jax.device_put`` is dispatched while the previous one computes
+    downstream."""
 
     def __init__(self, connector: Connector, splits: Sequence[Split],
                  columns: Sequence[str], dynamic_filters=None,
@@ -148,7 +145,6 @@ class ScanOperator(Operator):
         self._name_to_idx = {n: i for i, n in enumerate(self.columns)}
         self._domain_dict_cache: dict = {}
         self.rows_pruned_by_domain = 0
-        self._source = None
         self.input_done = True
         # -- async ingest state (exec/prefetch.py) --
         self.ingest_cfg = IngestConfig.from_env()
@@ -192,60 +188,6 @@ class ScanOperator(Operator):
             break  # credit once per batch
         return batch.filter(mask)
 
-    def get_output(self) -> Optional[ColumnBatch]:
-        if self.ingest_cfg.enabled:
-            return self._get_output_async()
-        return self._get_output_sync()
-
-    def _get_output_sync(self) -> Optional[ColumnBatch]:
-        while True:
-            if self._closed:
-                return None
-            if self._source is None:
-                if (self.limit is not None
-                        and self._emitted_rows >= self.limit):
-                    # pushed-down LIMIT satisfied: drop remaining splits
-                    self.splits = []
-                if not self.splits:
-                    return None
-                # kwarg only when constrained: wrapper connectors with the
-                # bare (split, columns) signature keep working
-                if self.constraint is not None:
-                    self._source = self.connector.create_page_source(
-                        self.splits.pop(0), self.columns,
-                        constraint=self.constraint)
-                else:
-                    self._source = self.connector.create_page_source(
-                        self.splits.pop(0), self.columns)
-            if self._source.is_finished():
-                self._source.close()
-                self._source = None
-                continue
-            batch = self._source.get_next_batch()
-            if batch is not None:
-                # device-pinned batches (live mask set) skip host-side
-                # dynamic filtering — pulling them down would cost more
-                # than the pruning saves
-                if self.constraint is not None and batch.live is None:
-                    batch = self._apply_constraint(batch)
-                    if batch.num_rows == 0:
-                        continue
-                if self.dynamic_filters and batch.live is None:
-                    batch = self._apply_dynamic_filters(batch)
-                    if batch.num_rows == 0:
-                        continue
-                # bucket scan output shapes so every downstream jitted
-                # program compiles once per (pipeline, bucket)
-                if self.limit is not None and batch.live is None:
-                    self._emitted_rows += batch.num_rows
-                self.ingest_stats.observe_batch(batch.nbytes, batch.num_rows)
-                if encoded_exec():
-                    batch = encode_scan_batch(
-                        batch, self.lazy_channels, self.encoding_stats)
-                return pad_to_bucket(batch)
-
-    # -- async ingest path --------------------------------------------------
-
     def _ensure_ingest(self) -> None:
         if self._prefetcher is not None or self._ingest_done:
             return
@@ -261,21 +203,19 @@ class ScanOperator(Operator):
                                     enc_stats=self.encoding_stats)
 
     def _stage(self, batch: ColumnBatch) -> ColumnBatch:
-        if self.ingest_cfg.stage_device:
-            if profiler.enabled():
-                t0 = profiler.now()
-                staged = self._stager.stage(batch)
-                profiler.event(profiler.STAGE, "scan.stage", t0,
-                               rows=batch.num_rows, bytes=batch.nbytes)
-                return staged
-            return self._stager.stage(batch)
-        return batch
+        if profiler.enabled():
+            t0 = profiler.now()
+            staged = self._stager.stage(batch)
+            profiler.event(profiler.STAGE, "scan.stage", t0,
+                           rows=batch.num_rows, bytes=batch.nbytes)
+            return staged
+        return self._stager.stage(batch)
 
     def _produce_next(self) -> Optional[ColumnBatch]:
         """One coalesced+staged batch, or None at end of input.  Filters run
         consumer-side (holder counters are not thread-safe); a device-pinned
         batch (``live`` set) flushes the coalescer first so row order holds,
-        then passes through like the sync path."""
+        then passes through as it is."""
         if self._ingest_done:
             return None
         self._ensure_ingest()
@@ -310,7 +250,7 @@ class ScanOperator(Operator):
             if self._coalescer.ready():
                 return self._stage(self._coalescer.flush())
 
-    def _get_output_async(self) -> Optional[ColumnBatch]:
+    def get_output(self) -> Optional[ColumnBatch]:
         if self._closed:
             return None
         if self._hold_back is not None:
@@ -329,8 +269,6 @@ class ScanOperator(Operator):
     def is_finished(self) -> bool:
         if self._closed:
             return True
-        if not self.ingest_cfg.enabled:
-            return self._source is None and not self.splits
         if self._staged is not None or self._hold_back is not None:
             return False
         if self._prefetcher is None:
@@ -911,7 +849,7 @@ def _maybe_compact_device(batch: ColumnBatch) -> ColumnBatch:
     """Shrink a sparsely-live device batch to bucket(live) lanes, for a
     caller whose next step is super-linear in lanes: SortOperator's device
     sort, and the aggregation's sorting reductions (group_ids_codes,
-    group_ids_auto, the global DISTINCT route through grouped_reduce) --
+    group_ids, the global DISTINCT route through grouped_reduce) --
     HashAggregationOperator._compute asks AFTER it has chosen its path.  A
     selective filter or join keeps its batch's fat static shape (the
     sync-free contract of join_exec.run_unique); paying ONE live-count sync
@@ -2025,11 +1963,7 @@ class HashAggregationOperator(BufferedInputMixin, Operator):
                 perm, gid, num_groups, presence, keys_out = (
                     K.group_ids_codes(key_cols, live))
             else:
-                # TRINO_TPU_HASH_IMPL routes between the lexsort path and
-                # the Pallas open-addressing path; both honor the same
-                # (perm, gid, num_groups) contract, so everything downstream
-                # (grouped_reduce, group_keys_out) is implementation-blind
-                perm, gid, num_groups = K.group_ids_auto(keys, live)
+                perm, gid, num_groups = K.group_ids(keys, live)
                 if num_groups == 0:  # every row dead (fully filtered input)
                     return self._empty_result(nk)
                 keys_out = K.group_keys_out(perm, gid, num_groups, keys)

@@ -1,11 +1,12 @@
 """Asynchronous scan ingest: split prefetch, batch coalescing, device staging.
 
-The synchronous scan path serializes three things that have no business
-serializing: host-side split decoding (connector ``get_next_batch``),
-host->device transfer, and device compute.  This module supplies the three
-pieces ScanOperator composes to overlap them — the ingest-side counterpart
-of Trino's split pipeline (ScanFilterAndProjectOperator's lazy pages +
-MergePages coalescing; reference: operator/ScanFilterAndProjectOperator.java:68,
+A scan that reads one split at a time serializes three things that have no
+business serializing: host-side split decoding (connector
+``get_next_batch``), host->device transfer, and device compute.  This
+module supplies the three pieces ScanOperator composes to overlap them —
+the ingest-side counterpart of Trino's split pipeline
+(ScanFilterAndProjectOperator's lazy pages + MergePages coalescing;
+reference: operator/ScanFilterAndProjectOperator.java:68,
 operator/project/MergePages.java:38, split prefetch via
 ConnectorSplitSource.getNextBatch futures):
 
@@ -27,8 +28,7 @@ ConnectorSplitSource.getNextBatch futures):
   compute instead of in front of it.
 
 Every knob reads from the environment once per source (see
-:class:`IngestConfig`); ``TRINO_TPU_PREFETCH=0`` disables the whole pipeline
-and ScanOperator falls back to the bit-for-bit synchronous path.
+:class:`IngestConfig`).
 """
 
 from __future__ import annotations
@@ -68,42 +68,30 @@ def _env_int(name: str, default: int) -> int:
 class IngestConfig:
     """Scan-ingest knobs (one env read per scan, so tests can flip them)."""
 
-    enabled: bool = True            # TRINO_TPU_PREFETCH
     threads: int = 2                # TRINO_TPU_PREFETCH_THREADS
     queue_depth: int = 8            # TRINO_TPU_PREFETCH_QUEUE_DEPTH (batches)
     queue_bytes: int = 256 << 20    # TRINO_TPU_PREFETCH_QUEUE_BYTES
     coalesce_rows: int = 1 << 16    # TRINO_TPU_COALESCE_TARGET_ROWS
-    stage_device: bool = True       # TRINO_TPU_STAGE_DEVICE
 
     @staticmethod
     def default_threads() -> int:
         """Prefetch decode threads auto-tuned from the host: cpu_count - 1
-        (one core stays with the consumer/dispatch thread), capped at 4 —
-        the decode is memory-bandwidth-bound past that.  A single-core host
-        gets 0: an extra thread there only adds GIL contention, so the scan
-        runs synchronously instead."""
-        return min(4, max(0, (os.cpu_count() or 1) - 1))
+        (one core stays with the consumer/dispatch thread), at least 1,
+        capped at 4 — the decode is memory-bandwidth-bound past that."""
+        return min(4, max(1, (os.cpu_count() or 1) - 1))
 
     @staticmethod
     def from_env() -> "IngestConfig":
         threads = _env_int("TRINO_TPU_PREFETCH_THREADS", -1)
-        explicit_on = os.environ.get("TRINO_TPU_PREFETCH") == "1"
         if threads < 0:  # unset: auto-tune from the host core count
             threads = IngestConfig.default_threads()
-            if explicit_on:  # explicit opt-in overrides the auto-disable
-                threads = max(1, threads)
         return IngestConfig(
-            # threads == 0 (explicit, or auto on single-core) disables the
-            # async path entirely rather than spawning useless workers
-            enabled=(os.environ.get("TRINO_TPU_PREFETCH", "1") != "0"
-                     and threads > 0),
             threads=max(1, threads),
             queue_depth=max(1, _env_int("TRINO_TPU_PREFETCH_QUEUE_DEPTH", 8)),
             queue_bytes=max(1, _env_int(
                 "TRINO_TPU_PREFETCH_QUEUE_BYTES", 256 << 20)),
             coalesce_rows=max(1, _env_int(
                 "TRINO_TPU_COALESCE_TARGET_ROWS", 1 << 16)),
-            stage_device=os.environ.get("TRINO_TPU_STAGE_DEVICE", "1") != "0",
         )
 
 
@@ -112,7 +100,7 @@ class PrefetchingPageSource:
 
     Worker threads claim splits in order and append decoded batches to a
     per-split buffer; the consumer drains buffers strictly in split order, so
-    downstream row order matches the synchronous scan exactly.  Backpressure:
+    downstream row order matches reading the splits one after another.  Backpressure:
     producers park while the queue is over its byte or depth budget, except
     the producer of the consumer's current split while that split's buffer is
     empty (a starved consumer can always make progress — no deadlock with any
@@ -129,7 +117,6 @@ class PrefetchingPageSource:
         self.constraint = constraint
         self.cfg = config if config is not None else IngestConfig.from_env()
         self.stats = stats if stats is not None else ScanIngestStats()
-        self.stats.prefetch_enabled = True
         self.limit_rows = limit_rows
         self._cv = threading.Condition()
         self._buffers: list[deque] = [deque() for _ in self.splits]
@@ -154,8 +141,7 @@ class PrefetchingPageSource:
     # -- producer side -----------------------------------------------------
     def _open_source(self, split):
         # kwarg only when constrained: wrapper connectors with the bare
-        # (split, columns) signature keep working (same contract as the
-        # synchronous scan)
+        # (split, columns) signature keep working
         if self.constraint is not None:
             return self.connector.create_page_source(
                 split, self.columns, constraint=self.constraint)
@@ -406,33 +392,6 @@ def encode_column(i: int, c: Column, lazy_channels,
         return Column.lazy(c.type, len(c), thunk, c.dictionary,
                            nbytes_hint=c.nbytes)
     return None
-
-
-def encode_scan_batch(batch: ColumnBatch, lazy_channels,
-                      enc_stats: Optional[EncodingStats] = None
-                      ) -> ColumnBatch:
-    """Compressed-execution pass for the synchronous scan path (no async
-    ingest, so batches never reach DeviceStager).  Host batches only —
-    device-pinned batches (live mask set) pass through untouched."""
-    if (not batch.columns or batch.live is not None
-            or not isinstance(batch.columns[0].data, np.ndarray)):
-        return batch
-    any_rle = False
-    changed = False
-    cols = []
-    for i, c in enumerate(batch.columns):
-        enc = encode_column(i, c, lazy_channels, enc_stats)
-        if enc is not None:
-            any_rle = any_rle or enc.encoding == "RLE"
-            changed = True
-            cols.append(enc)
-        else:
-            cols.append(c)
-    if not changed:
-        return batch
-    if any_rle and enc_stats is not None:
-        enc_stats.rle_batches += 1
-    return ColumnBatch(batch.names, cols, batch.live)
 
 
 class DeviceStager:

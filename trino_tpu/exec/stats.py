@@ -34,7 +34,6 @@ class ScanIngestStats:
     queue_depth_max: int = 0
     queue_depth_sum: int = 0
     queue_samples: int = 0
-    prefetch_enabled: bool = False
     first_batch_t: float | None = None
     last_batch_t: float | None = None
 
@@ -77,7 +76,6 @@ class ScanIngestStats:
         self.queue_depth_max = max(self.queue_depth_max, other.queue_depth_max)
         self.queue_depth_sum += other.queue_depth_sum
         self.queue_samples += other.queue_samples
-        self.prefetch_enabled = self.prefetch_enabled or other.prefetch_enabled
         # overall window spans the earliest first batch to the latest last
         for t in (other.first_batch_t,):
             if t is not None and (self.first_batch_t is None or t < self.first_batch_t):
@@ -87,9 +85,8 @@ class ScanIngestStats:
                 self.last_batch_t = t
 
     def text(self) -> str:
-        mode = "prefetch" if self.prefetch_enabled else "sync"
         return (
-            f"scan[{mode}]: {self.scan_bytes / 1e9:.3f} GB "
+            f"scan[prefetch]: {self.scan_bytes / 1e9:.3f} GB "
             f"({self.scan_rows} rows, {self.scan_batches} batches -> "
             f"{self.coalesced_batches} coalesced) @ {self.gbps:.2f} GB/s, "
             f"queue depth avg {self.queue_depth_avg:.1f} max {self.queue_depth_max}, "

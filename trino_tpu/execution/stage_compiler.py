@@ -30,8 +30,7 @@ never overflow: its capacity is ``n_tasks * cap`` which bounds the distinct
 groups that can arrive.
 
 ``TRINO_TPU_FUSED_STAGE={auto,1,0}``: 0 restores today's per-operator +
-collective-exchange path bit-for-bit (same knob pattern as
-TRINO_TPU_HASH_IMPL).
+collective-exchange path bit-for-bit.
 """
 
 from __future__ import annotations

@@ -152,7 +152,6 @@ def annotate_scan_span(span: "Span", ingest) -> None:
     span.set("trino.scan.consumer-wait-ms",
              round(ingest.consumer_wait_s * 1e3, 1))
     span.set("trino.scan.stage-ms", round(ingest.stage_s * 1e3, 1))
-    span.set("trino.scan.prefetch", ingest.prefetch_enabled)
 
 
 @dataclass

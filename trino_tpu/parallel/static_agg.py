@@ -11,14 +11,6 @@ table stats / NDV estimates, mirroring how Trino sizes hash tables from
 Overflow contract: if the true group count exceeds ``cap``, ``num_groups``
 in the result exceeds ``cap`` — the caller must check and re-run with a
 bigger cap (the recompile-bucket strategy of SURVEY §7).
-
-When ``TRINO_TPU_HASH_IMPL`` selects the Pallas open-addressing path, group
-ids come straight from the hash-insert kernel: no lexsort, and the row count
-stays a device scalar.  Slot ORDER then differs from the sort route (first
-occurrence vs key order) — callers already must not rely on slot order, and
-``combine_partials`` re-groups anyway.  One semantic divergence: the sort
-route's raw ``!=`` comparison makes every NaN its own group, while the hash
-route canonicalizes NaNs into one group (SQL semantics).
 """
 
 from __future__ import annotations
@@ -29,7 +21,6 @@ import jax
 import jax.numpy as jnp
 
 from .. import ops as _ops  # noqa: F401  (enables jax x64)
-from ..exec import kernels as _K
 
 __all__ = ["AggSpec", "StaticAggResult", "static_grouped_agg", "combine_partials"]
 
@@ -115,20 +106,8 @@ def static_grouped_agg(
     style — SURVEY §7 shift 2): masked-out rows join group slot ``cap`` + are
     dropped by reduction identity values.
     """
-    n = keys[0].shape[0]
-    if _K.hash_kernels_selected(n):
-        # hash route: the insert kernel hands every ORIGINAL row its dense
-        # group id, so perm stays identity and the segment scatters below
-        # work unsorted; the count stays a device scalar (still zero syncs)
-        row_gid, num_groups = _K.hash_row_gids(
-            list(zip(keys, key_valids)), live=row_mask)
-        S = _K.bucket(2 * max(n, 1))
-        perm = jnp.arange(n)
-        live = row_mask if row_mask is not None else jnp.ones(n, jnp.bool_)
-        gid = jnp.where(row_gid < S, jnp.minimum(row_gid, cap - 1), cap)
-    else:
-        perm, live, gid, num_groups = _sort_gids(keys, key_valids, cap,
-                                                 row_mask)
+    perm, live, gid, num_groups = _sort_gids(keys, key_valids, cap,
+                                             row_mask)
 
     out_keys, out_kvalids = [], []
     for k, v in zip(keys, key_valids):

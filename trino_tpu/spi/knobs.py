@@ -147,12 +147,6 @@ _DECLARATIONS = (
          "Front-tier retry-and-rehash budget: how long a routed request "
          "keeps probing live coordinators while the owner is mid-failover "
          "before reporting the query still QUEUED."),
-    Knob("TRINO_TPU_HASH_IMPL", "enum", "auto",
-         "Grouping/join hash index implementation.",
-         choices=("auto", "pallas", "sort")),
-    Knob("TRINO_TPU_HASH_INTERPRET", "bool", "0",
-         "Run the Pallas hash kernels in interpret mode (CPU-only "
-         "environments and kernel debugging)."),
     Knob("TRINO_TPU_HBO", "enum", "auto",
          "History-based optimization: the cost model prefers journaled "
          "per-fingerprint observed stats (rows, build bytes, partial-agg "
@@ -194,17 +188,13 @@ _DECLARATIONS = (
          "per lookup)."),
     Knob("TRINO_TPU_PLAN_CACHE_ENTRIES", "int", "256",
          "Plan-cache LRU capacity (entries)."),
-    Knob("TRINO_TPU_PREFETCH", "bool", "1",
-         "Async scan ingest (ordered multi-split prefetch); 0 is the "
-         "bit-for-bit synchronous legacy path, 1 forces it on even on "
-         "single-core hosts."),
     Knob("TRINO_TPU_PREFETCH_QUEUE_BYTES", "int", "268435456",
          "Prefetch queue byte bound (backpressure)."),
     Knob("TRINO_TPU_PREFETCH_QUEUE_DEPTH", "int", "8",
          "Prefetch queue depth in coalesced batches."),
     Knob("TRINO_TPU_PREFETCH_THREADS", "int", "-1",
-         "Prefetch decode threads; -1 auto-tunes from host cores "
-         "(cpu_count-1 capped at 4; 0 on single-core hosts)."),
+         "Prefetch decode threads, at least 1; -1 auto-tunes from host "
+         "cores (cpu_count-1, at least 1, capped at 4)."),
     Knob("TRINO_TPU_PROFILE", "enum", "default",
          "Flight-recorder level: default is a clock read + tuple store "
          "with zero hot syncs; full brackets operators with "
@@ -263,15 +253,9 @@ _DECLARATIONS = (
     Knob("TRINO_TPU_SPOOL_TTL_S", "float", "3600",
          "Retention TTL for unreleased spool roots (crashed or abandoned "
          "queries); the boot sweep reclaims roots idle past this."),
-    Knob("TRINO_TPU_STAGE_DEVICE", "bool", "1",
-         "Double-buffered device staging of coalesced scan batches; 0 "
-         "leaves batches on host until the operator touches them."),
     Knob("TRINO_TPU_TEST_BOOT_FAIL", "bool", "0",
          "Test-only: worker processes exit at boot to exercise the boot "
          "timeout path."),
-    Knob("TRINO_TPU_TPCH_VECTOR_DECODE", "bool", "1",
-         "Vectorized TPC-H string decode via vocab/code tables; 0 keeps "
-         "the legacy per-row decode for bench baselines."),
 )
 
 KNOBS: dict = {k.name: k for k in _DECLARATIONS}
